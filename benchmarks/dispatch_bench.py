@@ -212,7 +212,7 @@ def _calibration(adj: SparseCOO, width: int = 16, repeats: int = 9) -> dict:
     kernel, and prove a restarted process replays zero measurements."""
     rng = np.random.default_rng(1)
     y = jnp.asarray(rng.normal(size=(adj.shape[0], width)).astype(np.float32))
-    base = runtime_fallback(compat.backend_kind())
+    base = runtime_fallback()
     cache = SharedPlanCache()
     eng_static = DynasparseEngine(base, tile_m=32, tile_n=8, literal=True,
                                   cache=cache, calibration="off")

@@ -19,6 +19,9 @@ def main() -> None:
                     help="comma list: v,vi,vii,viii,overheads,kernels")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.fast:
         import benchmarks.common as common
         common.DSETS = [d for d in common.DSETS if d not in ("NE", "RE")]

@@ -1,52 +1,39 @@
-"""Version shims over the jax API surface that moved between releases.
+"""Thin wrappers over the jax API surface the SPMD code and the calibration
+subsystem use, so call sites name one place.
 
-The pinned CI environment runs jax 0.4.37, where:
-
-- ``jax.make_mesh`` exists but does not take ``axis_types`` (and
-  ``jax.sharding.AxisType`` does not exist at all);
-- ``jax.shard_map`` is still ``jax.experimental.shard_map.shard_map`` and
-  spells its replication check ``check_rep`` instead of ``check_vma``.
-
-Everything SPMD in this repo goes through these two wrappers so the same
-code runs on 0.4.37 and on current jax without feature gates in the tests.
-``backend_kind`` is the compat-visible device-kind probe the calibration
-subsystem keys its measurements on.
+- ``make_mesh`` builds meshes with explicit ``Auto`` axis types;
+- ``shard_map`` is ``jax.shard_map`` with its ``check_vma`` validation
+  spelled ``check``;
+- ``backend_kind`` / ``device_kind`` are the device probes the calibration
+  subsystem keys its measurements on and the fallback hardware model is
+  chosen by (``repro.core.perfmodel.runtime_fallback``).
 """
 from __future__ import annotations
 
 import jax
 
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
 
 def backend_kind() -> str:
-    """The active jax backend kind ("cpu", "tpu", "gpu").
-
-    The calibration cache key's device-kind component: measurements taken on
-    one backend must never be replayed on another, and the fallback
-    ``HardwareModel`` for an uncalibrated engine is chosen from this value
-    (``repro.core.perfmodel.runtime_fallback``)."""
+    """The active jax backend ("cpu", "tpu", "gpu")."""
     return jax.default_backend()
 
 
+def device_kind() -> str:
+    """``device_kind`` of the first device ("TPU v5 lite" on TPU v5e,
+    "cpu" on the host backend): measurements taken on one chip generation
+    must never be replayed on another."""
+    return jax.devices()[0].device_kind
+
+
 def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` / ``jax.experimental.shard_map.shard_map``.
-
-    ``check`` maps to ``check_vma`` (new) / ``check_rep`` (old) — both
-    toggle the same replication-mismatch validation.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    """``jax.shard_map``; ``check`` toggles its ``check_vma`` replication-
+    mismatch validation."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

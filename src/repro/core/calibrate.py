@@ -88,9 +88,9 @@ class CalibratedModel(HardwareModel):
 
 def calibration_key(base: HardwareModel, block: int, dtype: str) -> tuple:
     """(device kind, block, dtype, base name) — the persistence key.  The
-    device kind comes first: measurements taken on one backend must never
-    be replayed on another."""
-    return (compat.backend_kind(), int(block), str(dtype), base.name)
+    device kind ("TPU v5 lite", "cpu", ...) comes first: measurements taken
+    on one chip generation must never be replayed on another."""
+    return (compat.device_kind(), int(block), str(dtype), base.name)
 
 
 # ------------------------------------------------------------ measurement
@@ -286,7 +286,7 @@ def calibrate(base: HardwareModel, *, block: int = 8,
         np_dtype, membw, repeats)
 
     return CalibratedModel(
-        name=(f"{base.name}+calib[{compat.backend_kind()}"
+        name=(f"{base.name}+calib[{compat.device_kind()}"
               f",b{block},{dtype}]"),
         f_dense=base.f_dense,
         dense_macs_per_cycle=1.0 / (c1_g * base.f_dense),

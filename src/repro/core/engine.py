@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Literal
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -429,7 +430,7 @@ class DynasparseEngine:
                 plan.part, plan.stq, plan.dtq, entry.stripes, plan.placement,
                 block=self.block, eps=self.eps, fingerprint=digest,
                 operand_sharding=self.operand_sharding,
-                faults=self.faults))
+                faults=self.faults, mesh=self.mesh))
 
     def activation_dispatch_for(
             self, plan: KernelPlan, x, *, capacity=None,
@@ -524,6 +525,13 @@ class DynasparseEngine:
                     return _shard_exec.execute_sharded(
                         sd, xd, y, mesh=self.mesh, interpret=interpret,
                         stats=self.cache.stats, faults=self.faults)
+                # a kernel the mesh does not shard runs on the mesh's first
+                # device: a Mosaic kernel cannot be partitioned automatically,
+                # so operands a sharded kernel left spread out are gathered
+                home = self.mesh.devices.flat[0]
+                y = jax.device_put(y, home)
+                if not isinstance(x, SparseCOO):
+                    x = jax.device_put(jnp.asarray(x), home)
             pair = self.compiled_operands(plan, x)
             if pair is not None:
                 d, xd = pair

@@ -14,8 +14,8 @@ Two parameter sets ship:
   dispatch bubble are hand-tuned guesses, which is why the model is marked
   ``fallback=True``: engines constructed with it route through
   ``repro.core.calibrate`` on first plan (when calibration is enabled) so
-  STQ/DTQ decisions track measured kernel timings on the backend
-  ``repro.compat.backend_kind()`` reports, not the guesses.  ``VCK5000``
+  STQ/DTQ decisions track measured kernel timings on the device
+  ``repro.compat.device_kind()`` reports, not the guesses.  ``VCK5000``
   stays analytical by design — it reproduces the paper's tables.
 
 Closed forms (Table I):
@@ -105,18 +105,36 @@ TPUV5E = HardwareModel(
 )
 
 
-def runtime_fallback(backend: str) -> HardwareModel:
-    """Uncalibrated fallback model for a jax backend kind (the value
-    ``repro.compat.backend_kind()`` reports: "tpu", "cpu", "gpu", ...).
+# Uncalibrated fallback model per device, keyed by ``jax.Device.device_kind``
+# (what ``repro.compat.device_kind()`` reports).  TPU v5e reports
+# "TPU v5 lite"; its constants are the published v5e peaks above (Google
+# Cloud documentation, "TPU v5e").  The "cpu" entry serves tests and CPU
+# runs: the same closed forms under the CPU's own name, so a model fitted
+# there is attributed to the CPU and never mistaken for a chip's.
+FALLBACK_MODELS: dict[str, HardwareModel] = {
+    "TPU v5 lite": TPUV5E,
+    "cpu": dataclasses.replace(TPUV5E, name="cpu-fallback"),
+}
+
+
+def runtime_fallback(device_kind: str | None = None) -> HardwareModel:
+    """Uncalibrated fallback model for a device kind (default: the kind of
+    ``jax.devices()[0]``).
 
     Every returned model carries ``fallback=True`` — the constants are
-    starting guesses the calibration subsystem is expected to replace.  The
-    non-TPU entries reuse the TPU closed forms with the name rebound so a
-    ``CalibratedModel`` fitted on that backend is attributed honestly.
+    starting guesses the calibration subsystem is expected to replace.  A
+    device kind missing from :data:`FALLBACK_MODELS` raises: another chip
+    generation must not borrow v5e's constants.
     """
-    if backend == "tpu":
-        return TPUV5E
-    return dataclasses.replace(TPUV5E, name=f"{backend}-fallback")
+    if device_kind is None:
+        from repro import compat
+        device_kind = compat.device_kind()
+    try:
+        return FALLBACK_MODELS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware model for device kind {device_kind!r} (known: "
+            f"{sorted(FALLBACK_MODELS)})") from None
 
 
 @dataclasses.dataclass(frozen=True)

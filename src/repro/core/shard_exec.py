@@ -64,6 +64,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
@@ -167,7 +168,8 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
                            *, block: int, eps: float = 0.0,
                            fingerprint: str = "",
                            operand_sharding: str = "halo",
-                           faults: object = None) -> ShardedDispatch | None:
+                           faults: object = None,
+                           mesh=None) -> ShardedDispatch | None:
     """Lower a device-placed plan into a :class:`ShardedDispatch`.
 
     Same O(nnz blocks) vectorized-numpy cost as
@@ -175,7 +177,17 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
     assignment, mesh geometry, operand-sharding mode); ``None`` when the
     canvas geometry cannot take the in-place index maps (caller falls back
     to the eager path, which is placement-agnostic and already correct).
+    With ``mesh``, every per-device array is uploaded split along its
+    leading device axis (``P("data")``), so each device holds only its own
+    band's descriptors and pool and the program never reshards them.
     """
+    if mesh is None:
+        put = jnp.asarray
+    else:
+        data = NamedSharding(mesh, P("data"))
+
+        def put(v):
+            return jax.device_put(np.asarray(v), data)
     if operand_sharding not in OPERAND_SHARDINGS:
         raise ValueError(f"operand_sharding must be one of "
                          f"{OPERAND_SHARDINGS}, got {operand_sharding!r}")
@@ -260,8 +272,7 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
         has_gemm=n_gemm > 0, has_spdmm=n_sp > 0, has_spmm=n_mm > 0,
         eps=eps)
 
-    arrays: dict[str, jax.Array] = {
-        k: jnp.asarray(v) for k, v in hx_arrays.items()}
+    arrays: dict[str, jax.Array] = {k: put(v) for k, v in hx_arrays.items()}
 
     if n_gemm:
         rows = np.full((nd, n_gemm), nrt_l - 1, dtype=np.int32)
@@ -269,8 +280,8 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
         for d, g in enumerate(per_gemm):
             rows[d, :len(g)] = [t.i for t in g]
             cols[d, :len(g)] = [t.j for t in g]
-        arrays["gemm_rows"] = jnp.asarray(rows)
-        arrays["gemm_cols"] = jnp.asarray(cols)
+        arrays["gemm_rows"] = put(rows)
+        arrays["gemm_cols"] = put(cols)
 
     def _stack_section(per_dev, n_entries, names, pad_cols):
         """Pad each device's (pool, entry-arrays) to common shapes and
@@ -289,9 +300,9 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
                 columns[k].append(np.concatenate(
                     [c, np.full(pad_n, pad_cols[k](pool_len),
                                 dtype=np.int32)]))
-        out = {"pool": jnp.asarray(np.stack(pools))}
+        out = {"pool": put(np.stack(pools))}
         for k, name in enumerate(names):
-            out[name] = jnp.asarray(np.stack(columns[k]).astype(np.int32))
+            out[name] = put(np.stack(columns[k]).astype(np.int32))
         return out
 
     if n_sp:
@@ -447,7 +458,12 @@ def execute_sharded(sd: ShardedDispatch, x, y, *, mesh, interpret: bool,
     if faults is not None:
         faults.probe("shard_exec",
                      detail=f"nd:{sd.n_devices}:{sd.operand_sharding}")
-    y = jnp.asarray(y)
+    # operands may sit on one device (an unsharded kernel produced them):
+    # hand them to the mesh, whose devices the descriptor arrays span
+    on_mesh = NamedSharding(mesh, P())
+    y = jax.device_put(jnp.asarray(y), on_mesh)
+    if x is not None:
+        x = jax.device_put(jnp.asarray(x), on_mesh)
     key = _shard_signature(sd, x, y, mesh, interpret)
     with _dispatch._TRACE_LOCK:
         hit = key in _dispatch._TRACE_SEEN

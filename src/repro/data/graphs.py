@@ -34,7 +34,7 @@ class DatasetStats:
 
 # Table IV, verbatim (Reddit edge count "11x10^7").
 DATASETS: dict[str, DatasetStats] = {
-    "CO": DatasetStats("CO", 2708, 5429, 2708, 7, 0.0014, 0.0127, 16),
+    "CO": DatasetStats("CO", 2708, 5429, 1433, 7, 0.0014, 0.0127, 16),
     "CI": DatasetStats("CI", 3327, 4732, 3703, 6, 0.0008, 0.0085, 16),
     "PU": DatasetStats("PU", 19717, 44338, 500, 3, 0.0002, 0.10, 16),
     "FL": DatasetStats("FL", 89250, 899756, 500, 7, 0.0001, 0.46, 128),

@@ -26,8 +26,8 @@ def _gemm_kernel(x_ref, y_ref, z_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        x_ref[...], y_ref[...], preferred_element_type=jnp.float32
-    )
+        x_ref[...], y_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -80,8 +80,8 @@ def _gemm_batch_scatter_kernel(row_ref, col_ref, x_ref, y_ref, zin_ref, z_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        x_ref[0], y_ref[0], preferred_element_type=jnp.float32
-    )
+        x_ref[0], y_ref[0], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -130,7 +130,7 @@ def gemm_batch_scatter(
                 pl.BlockSpec((1, bk, n), lambda i, kk, rows, cols: (i, kk, 0)),
                 # canvas input, aliased to the output buffer: the kernel
                 # never reads it, so it stays in HBM (no per-step DMA)
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pltpu.HBM),
             ],
             out_specs=pl.BlockSpec(
                 (m, n), lambda i, kk, rows, cols: (rows[i], cols[i])
@@ -151,8 +151,8 @@ def _gemm_batch_kernel(x_ref, y_ref, z_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        x_ref[0], y_ref[0], preferred_element_type=jnp.float32
-    )
+        x_ref[0], y_ref[0], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(k == n_k - 1)
     def _store():

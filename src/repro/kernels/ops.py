@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handles shape padding to block multiples, dtype policy (f32 accumulation) and
-the interpret-mode fallback (this container is CPU-only; the kernels target
-TPU, and ``interpret=True`` executes the kernel body on CPU for validation).
+Handles shape padding to block multiples, operand layouts the TPU block rule
+accepts, splitting of long fused entry lists across launches (SMEM), dtype
+policy (f32 accumulation) and interpret mode: the kernels target TPU, and on
+any other backend ``interpret=True`` executes the kernel body on the host for
+validation.
 """
 from __future__ import annotations
 
@@ -130,25 +132,63 @@ def spdmm(a: BlockCSR, y, *, bn: int = 128, interpret: bool | None = None,
     return out[:m, :n]
 
 
+# One launch's scalar-prefetch operands live in SMEM, 1 MiB on TPU v5e (the
+# compiler refuses more).  The fused sparse kernels prefetch five int32
+# descriptor arrays, 20 bytes per entry, so about 52k entries is the most a
+# single launch can take; longer entry lists run as consecutive launches of
+# this many entries on the same aliased canvas (bit-identical to one launch,
+# see ``repro.kernels.spdmm.resume_partial``).
+MAX_ENTRIES_PER_LAUNCH = 32768
+
+
+def _launch_chunks(n: int):
+    """``[lo, hi)`` entry ranges of the launches a fused list of ``n``
+    entries is split into."""
+    step = MAX_ENTRIES_PER_LAUNCH
+    return [(lo, min(n, lo + step)) for lo in range(0, max(n, 1), step)]
+
+
+def _stripe_major(z, n_stripes: int, width: int):
+    """``(rows, n_stripes * width)`` → ``(n_stripes, rows, width)``."""
+    return z.reshape(z.shape[0], n_stripes, width).transpose(1, 0, 2)
+
+
+def _row_major(z3):
+    """Inverse of :func:`_stripe_major`."""
+    n_stripes, rows, width = z3.shape
+    return z3.transpose(1, 0, 2).reshape(rows, n_stripes * width)
+
+
 def spdmm_fused(a_blocks, y, a_ids, y_rows, out_rows, out_cols, first, *,
                 block_size: int, bn: int, m_pad: int,
                 interpret: bool | None = None, out_dtype=jnp.float32,
                 z=None):
     """Fused multi-task SpDMM over a concatenated stored-block pool; see
     :func:`repro.kernels.spdmm.spdmm_fused`.  ``y`` must already be laid out
-    with ``bn``-padded col-stripes.  ``z`` (optional) is an in-place canvas
-    aliased to the output: uncovered blocks keep their ``z`` content."""
+    with ``bn``-padded col-stripes; it and the output are handled stripe-
+    major inside.  ``z`` (optional) is an in-place canvas aliased to the
+    output: uncovered blocks keep their ``z`` content.  Entry lists longer
+    than :data:`MAX_ENTRIES_PER_LAUNCH` run as several launches on one
+    canvas."""
     interpret = default_interpret() if interpret is None else interpret
-    _count_call()
-    return _spdmm.spdmm_fused(
-        jnp.asarray(a_blocks), jnp.asarray(y),
-        jnp.asarray(a_ids, dtype=jnp.int32),
-        jnp.asarray(y_rows, dtype=jnp.int32),
-        jnp.asarray(out_rows, dtype=jnp.int32),
-        jnp.asarray(out_cols, dtype=jnp.int32),
-        jnp.asarray(first, dtype=jnp.int32),
-        block_size=block_size, bn=bn, m_pad=m_pad, interpret=interpret,
-        out_dtype=out_dtype, n_entries=len(a_ids), z=z)
+    k_pad, n_pad = y.shape
+    assert n_pad % bn == 0, (y.shape, bn)
+    n_stripes = n_pad // bn
+    ids = [jnp.asarray(v, dtype=jnp.int32)
+           for v in (a_ids, y_rows, out_rows, out_cols, first)]
+    n = len(a_ids)
+    z3 = None if z is None else _stripe_major(jnp.asarray(z), n_stripes, bn)
+    if z3 is None and n > MAX_ENTRIES_PER_LAUNCH:
+        z3 = jnp.zeros((n_stripes, m_pad, bn), out_dtype)
+    y3 = _stripe_major(jnp.asarray(y), n_stripes, bn)
+    a_blocks = jnp.asarray(a_blocks)
+    for lo, hi in _launch_chunks(n):
+        _count_call()
+        z3 = _spdmm.spdmm_fused(
+            a_blocks, y3, *(v[lo:hi] for v in ids),
+            block_size=block_size, m_pad=m_pad, interpret=interpret,
+            out_dtype=out_dtype, n_entries=hi - lo, z=z3)
+    return _row_major(z3)
 
 
 def blockize(y, block: int):
@@ -264,18 +304,30 @@ def spmm_fused(a_blocks, y_blocks, a_ids, y_ids, out_rows, out_cols, first, *,
                interpret: bool | None = None, out_dtype=jnp.float32, z=None):
     """Fused multi-task SpMM over concatenated block pools; see
     :func:`repro.kernels.spmm.spmm_fused`.  ``z`` (optional) is an in-place
-    canvas aliased to the output: uncovered blocks keep their ``z`` content."""
+    canvas aliased to the output: uncovered blocks keep their ``z`` content.
+    Triple lists longer than :data:`MAX_ENTRIES_PER_LAUNCH` run as several
+    launches on one canvas."""
     interpret = default_interpret() if interpret is None else interpret
-    _count_call()
-    return _spmm.spmm_fused(
-        a_blocks, y_blocks, a_ids, y_ids, out_rows, out_cols, first,
-        block_size=block_size, m_pad=m_pad, n_pad=n_pad, interpret=interpret,
-        out_dtype=out_dtype, z=z)
+    B = block_size
+    ids = [jnp.asarray(v, dtype=jnp.int32)
+           for v in (a_ids, y_ids, out_rows, out_cols, first)]
+    n = len(a_ids)
+    z3 = None if z is None else _stripe_major(jnp.asarray(z), n_pad // B, B)
+    if z3 is None and n > MAX_ENTRIES_PER_LAUNCH:
+        z3 = jnp.zeros((n_pad // B, m_pad, B), out_dtype)
+    a_blocks, y_blocks = jnp.asarray(a_blocks), jnp.asarray(y_blocks)
+    for lo, hi in _launch_chunks(n):
+        _count_call()
+        z3 = _spmm.spmm_fused(
+            a_blocks, y_blocks, *(v[lo:hi] for v in ids),
+            block_size=B, m_pad=m_pad, n_pad=n_pad, interpret=interpret,
+            out_dtype=out_dtype, n_triples=hi - lo, z=z3)
+    return _row_major(z3)
 
 
 __all__ = [
     "BlockCSR", "pack_blockcsr", "pack_activation_stripes", "blockize",
     "gemm", "gemm_batch", "gemm_batch_scatter",
     "spdmm", "spdmm_fused", "spmm", "spmm_fused", "default_interpret",
-    "pallas_call_count", "reset_pallas_call_count",
+    "pallas_call_count", "reset_pallas_call_count", "MAX_ENTRIES_PER_LAUNCH",
 ]

@@ -35,8 +35,8 @@ def _spdmm_kernel(row_ref, col_ref, first_ref, a_ref, y_ref, z_ref):
 
     # BlockSpec (None, B, B) squeezes the stored-block axis: a_ref is (B, B)
     z_ref[...] += jnp.dot(
-        a_ref[...], y_ref[...], preferred_element_type=jnp.float32
-    ).astype(z_ref.dtype)
+        a_ref[...], y_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST).astype(z_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret", "out_dtype"))
@@ -86,26 +86,42 @@ def _spdmm_fused_kernel(aid_ref, yrow_ref, orow_ref, ocol_ref, first_ref,
                         a_ref, y_ref, z_ref):
     del aid_ref, yrow_ref, orow_ref, ocol_ref
     t = pl.program_id(0)
+    z = z_ref.at[0]               # (B, bn) view of the (1, B, bn) block
 
     @pl.when(first_ref[t] == 1)
     def _init():
-        z_ref[...] = jnp.zeros_like(z_ref)
+        z[...] = jnp.zeros_like(z)
 
-    z_ref[...] += jnp.dot(
-        a_ref[...], y_ref[...], preferred_element_type=jnp.float32
-    ).astype(z_ref.dtype)
+    z[...] += jnp.dot(
+        a_ref[...], y_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST).astype(z.dtype)
+
+
+def resume_partial(first_ref, zin_ref, z_ref):
+    """Open a launch in the middle of an output block's run: when the first
+    entry of this launch continues a run (``first == 0``), the partial sum
+    the previous launch of the same entry list wrote into the aliased
+    canvas (``zin_ref``, fetched block by block alongside the output) is
+    loaded into the resident output block before accumulating.  Long entry
+    lists are split across launches (one launch's scalar-prefetch operands
+    must fit in SMEM), and this keeps the split invisible: the float32
+    partial round-trips through HBM unchanged, so the result is
+    bit-identical to one launch."""
+    @pl.when((pl.program_id(0) == 0) & (first_ref[0] == 0))
+    def _resume():
+        z_ref[...] = zin_ref[...]
 
 
 def _spdmm_fused_inplace_kernel(aid_ref, yrow_ref, orow_ref, ocol_ref,
                                 first_ref, a_ref, y_ref, zin_ref, z_ref):
-    del zin_ref
+    resume_partial(first_ref, zin_ref, z_ref)
     _spdmm_fused_kernel(aid_ref, yrow_ref, orow_ref, ocol_ref, first_ref,
                         a_ref, y_ref, z_ref)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_size", "bn", "m_pad", "interpret", "out_dtype",
+    static_argnames=("block_size", "m_pad", "interpret", "out_dtype",
                      "n_entries"),
 )
 def spdmm_fused(
@@ -118,7 +134,6 @@ def spdmm_fused(
     first: jax.Array,
     *,
     block_size: int,
-    bn: int,
     m_pad: int,
     interpret: bool = False,
     out_dtype=jnp.float32,
@@ -128,40 +143,49 @@ def spdmm_fused(
     """Fused multi-task SpDMM: EVERY SpDMM task of a kernel in one launch.
 
     ``a_blocks`` is the concatenated stored-block pool of all packed row
-    stripes; ``y`` is dense, laid out with each col-stripe padded to ``bn``
-    columns.  Each grid step ``t`` is one (stored block, task) pair: the
+    stripes.  The dense operand and the output are STRIPE-MAJOR: ``y`` is
+    ``(n_stripes, k_pad, bn)`` and the output ``(n_stripes, m_pad, bn)``,
+    one slab per ``bn``-wide column stripe, so every block a grid step
+    touches spans the full minor dimension of its array (the TPU block
+    rule: the last two block dims are multiples of (8, 128) or equal to the
+    array's).  Each grid step ``t`` is one (stored block, task) pair: the
     scalar-prefetched entry arrays steer block ``a_ids[t]`` onto Y block-row
-    ``y_rows[t]`` / col-stripe ``out_cols[t]`` and accumulate into output
+    ``y_rows[t]`` of stripe ``out_cols[t]`` and accumulate into output
     block ``(out_rows[t], out_cols[t])``.  Entries are sorted by output block
     so revisits are consecutive (VMEM residency); ``first`` zero-initializes
     each run.
 
-    Without ``z``, the output is a fresh ``(m_pad, n_pad)`` buffer whose
-    blocks covered by no entry are undefined (the caller must not read
-    them).  With ``z`` — the scheduler's in-place assembly — the canvas is
-    aliased to the output, so covered blocks are written in place and every
-    other block keeps its ``z`` content (e.g. tiles already written by the
-    batched GEMM of the same kernel).
+    Without ``z``, the output is a fresh buffer whose blocks covered by no
+    entry are undefined (the caller must not read them).  With ``z`` — the
+    scheduler's in-place assembly — the canvas is aliased to the output, so
+    covered blocks are written in place and every other block keeps its
+    ``z`` content (e.g. tiles already written by the batched GEMM of the
+    same kernel), and the launch may open mid-run (:func:`resume_partial`).
     """
     B = block_size
-    k_pad, n_pad = y.shape
-    assert k_pad % B == 0 and n_pad % bn == 0, (y.shape, B, bn)
+    n_stripes, k_pad, bn = y.shape
+    assert k_pad % B == 0, (y.shape, B)
 
     in_specs = [
         pl.BlockSpec((None, B, B),
                      lambda t, aid, yrow, orow, ocol, first: (aid[t], 0, 0)),
-        pl.BlockSpec((B, bn),
-                     lambda t, aid, yrow, orow, ocol, first: (yrow[t], ocol[t])),
+        pl.BlockSpec((None, B, bn),
+                     lambda t, aid, yrow, orow, ocol, first:
+                     (ocol[t], yrow[t], 0)),
     ]
+    out_spec = pl.BlockSpec(
+        (1, B, bn),
+        lambda t, aid, yrow, orow, ocol, first: (ocol[t], orow[t], 0))
     operands = [a_ids, y_rows, out_rows, out_cols, first, a_blocks, y]
     kernel = _spdmm_fused_kernel
-    out_shape = jax.ShapeDtypeStruct((m_pad, n_pad), out_dtype)
+    out_shape = jax.ShapeDtypeStruct((n_stripes, m_pad, bn), out_dtype)
     aliases = {}
     if z is not None:
-        assert z.shape == (m_pad, n_pad), (z.shape, m_pad, n_pad)
-        # canvas input, aliased to the output buffer: the kernel never
-        # reads it, so it stays in HBM (no per-step DMA)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        assert z.shape == (n_stripes, m_pad, bn), (z.shape, m_pad, y.shape)
+        # canvas input, aliased to the output buffer: fetched once per
+        # output block (the pipeline skips unchanged block indices) and
+        # read only to resume a split run
+        in_specs.append(out_spec)
         operands.append(z)
         kernel = _spdmm_fused_inplace_kernel
         out_shape = jax.ShapeDtypeStruct(z.shape, z.dtype)
@@ -173,9 +197,7 @@ def spdmm_fused(
             num_scalar_prefetch=5,
             grid=(n_entries,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (B, bn), lambda t, aid, yrow, orow, ocol, first: (orow[t], ocol[t])
-            ),
+            out_specs=out_spec,
         ),
         out_shape=out_shape,
         input_output_aliases=aliases,

@@ -24,26 +24,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.formats import BlockCSR, spmm_triples
+from repro.kernels.spdmm import resume_partial
 
 
 def _spmm_kernel(aid_ref, yid_ref, orow_ref, ocol_ref, first_ref,
                  a_ref, y_ref, z_ref):
     del aid_ref, yid_ref, orow_ref, ocol_ref
     t = pl.program_id(0)
+    z = z_ref.at[0]               # (B, B) view of the (1, B, B) block
 
     @pl.when(first_ref[t] == 1)
     def _init():
-        z_ref[...] = jnp.zeros_like(z_ref)
+        z[...] = jnp.zeros_like(z)
 
     # BlockSpec (None, B, B) squeezes the stored-block axis: refs are (B, B)
-    z_ref[...] += jnp.dot(
-        a_ref[...], y_ref[...], preferred_element_type=jnp.float32
-    ).astype(z_ref.dtype)
+    z[...] += jnp.dot(
+        a_ref[...], y_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST).astype(z.dtype)
 
 
 def _spmm_inplace_kernel(aid_ref, yid_ref, orow_ref, ocol_ref, first_ref,
                          a_ref, y_ref, zin_ref, z_ref):
-    del zin_ref
+    resume_partial(first_ref, zin_ref, z_ref)
     _spmm_kernel(aid_ref, yid_ref, orow_ref, ocol_ref, first_ref,
                  a_ref, y_ref, z_ref)
 
@@ -53,23 +55,37 @@ def _spmm_inplace_kernel(aid_ref, yid_ref, orow_ref, ocol_ref, first_ref,
     static_argnames=("m_pad", "n_pad", "block_size", "interpret", "out_dtype",
                      "n_triples"),
 )
-def _spmm_call(a_blocks, y_blocks, a_ids, y_ids, out_rows, out_cols, first,
-               *, m_pad, n_pad, block_size, interpret, out_dtype, n_triples,
-               z=None):
+def spmm_fused(a_blocks, y_blocks, a_ids, y_ids, out_rows, out_cols, first,
+               *, m_pad, n_pad, block_size, interpret=False,
+               out_dtype=jnp.float32, n_triples, z=None):
+    """One launch of the triple-walking kernel over CONCATENATED block pools
+    (all packed A row-stripes / Y col-stripes of a kernel, plus one trailing
+    sentinel zero block each).  The caller offsets block ids into the pools
+    and output coordinates into per-task regions; sorting/coverage
+    obligations are those of :func:`repro.kernels.formats.spmm_triples`.
+
+    The output is BLOCK-COLUMN-MAJOR, ``(n_pad // B, m_pad, B)``: each
+    (B, B) output block then spans the full minor dimension of its array,
+    which the TPU block rule requires of a B-wide block (B < 128).  ``z``
+    (optional) is an in-place canvas in that layout, aliased to the output:
+    triples scatter into it, every block they don't cover keeps its ``z``
+    content, and the launch may open mid-run (``resume_partial``)."""
     B = block_size
     in_specs = [
         pl.BlockSpec((None, B, B), lambda t, aid, yid, orow, ocol, first: (aid[t], 0, 0)),
         pl.BlockSpec((None, B, B), lambda t, aid, yid, orow, ocol, first: (yid[t], 0, 0)),
     ]
+    out_spec = pl.BlockSpec(
+        (1, B, B), lambda t, aid, yid, orow, ocol, first: (ocol[t], orow[t], 0))
     operands = [a_ids, y_ids, out_rows, out_cols, first, a_blocks, y_blocks]
     kernel = _spmm_kernel
-    out_shape = jax.ShapeDtypeStruct((m_pad, n_pad), out_dtype)
+    out_shape = jax.ShapeDtypeStruct((n_pad // B, m_pad, B), out_dtype)
     aliases = {}
     if z is not None:
-        assert z.shape == (m_pad, n_pad), (z.shape, m_pad, n_pad)
-        # canvas input, aliased to the output buffer: the kernel never
-        # reads it, so it stays in HBM (no per-step DMA)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        assert z.shape == (n_pad // B, m_pad, B), (z.shape, m_pad, n_pad)
+        # canvas input, aliased to the output buffer: fetched once per
+        # output block and read only to resume a split run
+        in_specs.append(out_spec)
         operands.append(z)
         kernel = _spmm_inplace_kernel
         out_shape = jax.ShapeDtypeStruct(z.shape, z.dtype)
@@ -81,9 +97,7 @@ def _spmm_call(a_blocks, y_blocks, a_ids, y_ids, out_rows, out_cols, first,
             num_scalar_prefetch=5,
             grid=(n_triples,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (B, B), lambda t, aid, yid, orow, ocol, first: (orow[t], ocol[t])
-            ),
+            out_specs=out_spec,
         ),
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -109,56 +123,11 @@ def spmm(
     zero_y = jnp.zeros((1, B, B), y.blocks.dtype)
     y_blocks = jnp.concatenate([y.blocks, zero_y], axis=0)
 
-    return _spmm_call(
+    m_pad, n_pad = a.n_block_rows * B, y.n_block_cols * B
+    out = spmm_fused(
         a_blocks, y_blocks,
         jnp.asarray(a_ids), jnp.asarray(y_ids),
         jnp.asarray(out_rows), jnp.asarray(out_cols), jnp.asarray(first),
-        m_pad=a.n_block_rows * B,
-        n_pad=y.n_block_cols * B,
-        block_size=B,
-        interpret=interpret,
-        out_dtype=out_dtype,
-        n_triples=len(a_ids),
-    )
-
-
-def spmm_fused(
-    a_blocks: jax.Array,
-    y_blocks: jax.Array,
-    a_ids,
-    y_ids,
-    out_rows,
-    out_cols,
-    first,
-    *,
-    block_size: int,
-    m_pad: int,
-    n_pad: int,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-    z: jax.Array | None = None,
-) -> jax.Array:
-    """Fused multi-task SpMM: a caller-built triple list over CONCATENATED
-    block pools (all packed A row-stripes / Y col-stripes of a kernel, plus
-    one trailing sentinel zero block each) drives a single launch of the
-    triple-walking kernel.  The caller offsets block ids into the pools and
-    output coordinates into per-task regions; sorting/coverage obligations are
-    the same as :func:`repro.kernels.formats.spmm_triples`.
-
-    ``z`` (optional) is an in-place canvas aliased to the output: triples
-    scatter into it and every block they don't cover keeps its ``z`` content
-    (the scheduler's O(1) assembly)."""
-    return _spmm_call(
-        jnp.asarray(a_blocks), jnp.asarray(y_blocks),
-        jnp.asarray(a_ids, dtype=jnp.int32), jnp.asarray(y_ids, dtype=jnp.int32),
-        jnp.asarray(out_rows, dtype=jnp.int32),
-        jnp.asarray(out_cols, dtype=jnp.int32),
-        jnp.asarray(first, dtype=jnp.int32),
-        m_pad=m_pad,
-        n_pad=n_pad,
-        block_size=block_size,
-        interpret=interpret,
-        out_dtype=out_dtype,
-        n_triples=len(a_ids),
-        z=z,
-    )
+        m_pad=m_pad, n_pad=n_pad, block_size=B, interpret=interpret,
+        out_dtype=out_dtype, n_triples=len(a_ids))
+    return out.transpose(1, 0, 2).reshape(m_pad, n_pad)

@@ -1,13 +1,17 @@
 """GNN serving driver: async micro-batched inference over a shared cache.
 
 ``PYTHONPATH=src python -m repro.launch.gnn_serve --dataset CO --model GCN
-[--requests 64] [--max-batch 8] [--scale 0.05] [--cache-file plan.pkl]``
+[--requests 64] [--max-batch 8] [--scale 1.0] [--cache-file plan.pkl]``
 
 Fires a burst of synthetic same-graph requests through the ServingEngine
 and prints a machine-readable stats line: latency percentiles, micro-batch
-sizes, plan-cache hit rate and pallas launches per request.  With
-``--cache-file`` the SharedPlanCache is loaded before serving (restart
-skips re-analysis — observe packs/analyzes stay 0) and saved after.
+sizes, plan-cache hit rate and pallas launches per request.  Every kernel
+runs as a Pallas kernel — compiled for the chip on TPU, in interpret mode
+on any other backend — and the runtime mapping plans against the hardware
+model of the device in use (``perfmodel.runtime_fallback``, calibrated on
+first plan).  With ``--cache-file`` the SharedPlanCache is loaded before
+serving (restart skips re-analysis — observe packs/analyzes stay 0) and
+saved after.
 """
 from __future__ import annotations
 
@@ -16,26 +20,28 @@ import json
 import os
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="CO", help="Table-IV dataset id")
     ap.add_argument("--model", default="GCN")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--max-delay-ms", type=float, default=0.0)
-    ap.add_argument("--scale", type=float, default=0.05,
-                    help="graph scale factor (CPU-budget functional runs)")
+    ap.add_argument("--scale", type=float, default=1.0,
+                    help="graph scale factor (1.0 = Table IV size)")
     ap.add_argument("--drift-threshold", type=float, default=0.25)
-    ap.add_argument("--literal", action="store_true",
-                    help="literal Pallas dispatch (interpret mode on CPU)")
     ap.add_argument("--cache-file", default=None,
                     help="load the shared plan cache before serving, save "
                          "after (serving-restart persistence)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import numpy as np
 
     from repro.core import DynasparseEngine
+    from repro.core.perfmodel import runtime_fallback
     from repro.data.graphs import load_graph
     from repro.kernels import ops
     from repro.models import gnn
@@ -51,7 +57,8 @@ def main() -> None:
     cache = SharedPlanCache()
     if args.cache_file and os.path.exists(args.cache_file):
         print(f"[gnn_serve] loaded cache: {cache.load(args.cache_file)}")
-    engine = DynasparseEngine(literal=args.literal, cache=cache)
+    engine = DynasparseEngine(runtime_fallback(), literal=True,
+                              calibration="auto", cache=cache)
     srv = ServingEngine(
         args.model, params, engine=engine,
         config=ServingConfig(
@@ -78,6 +85,7 @@ def main() -> None:
     stats.update({
         "dataset": args.dataset, "model": args.model,
         "vertices": g.stats.vertices,
+        "hardware_model": engine.runtime_hw().name,
         "cache": cache.stats.as_dict(),
         "cache_bytes": cache.bytes_used,
         "plan_hit_rate": cache.stats.hit_rate,
@@ -88,6 +96,7 @@ def main() -> None:
 
     if args.cache_file:
         print(f"[gnn_serve] saved cache: {cache.save(args.cache_file)}")
+    return stats
 
 
 if __name__ == "__main__":

@@ -6,8 +6,8 @@ Multi-pod:  2 x 16 x 16 = 512 chips, axes ("pod", "data", "model") — the
 it, tensor-parallel stays within a pod.
 
 Functions only — importing this module never touches jax device state.
-Mesh construction goes through ``repro.compat.make_mesh`` so the same code
-runs on jax 0.4.37 (no ``AxisType``) and on current jax.
+Mesh construction goes through ``repro.compat.make_mesh`` (explicit ``Auto``
+axis types).
 """
 from __future__ import annotations
 

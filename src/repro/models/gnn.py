@@ -20,7 +20,9 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
+from repro import compat
 from repro.core import dispatch as _dispatch
 from repro.core import shard_exec as _shard_exec
 from repro.core import sparsity
@@ -295,23 +297,36 @@ def compile_model(model: str, engine: DynasparseEngine, adj, h, params,
     if not compilable[0]:
         return logits, None
 
-    interpret = (ops.default_interpret() if engine.interpret is None
-                 else engine.interpret)
-
     def replay(payload_, hh):
+        # resolved when the program is traced, for the backend it targets
+        interpret = (ops.default_interpret() if engine.interpret is None
+                     else engine.interpret)
         ctr = itertools.count()
         act_diags = []
+
+        def unsharded(f, *args):
+            # a mesh program cannot partition a Mosaic kernel on its own: a
+            # kernel the plan does not shard runs whole on every device
+            if engine.mesh is None:
+                return f(*args)
+            return compat.shard_map(f, mesh=engine.mesh,
+                                    in_specs=tuple(P() for _ in args),
+                                    out_specs=P())(*args)
 
         def mm(x, y, name="kernel"):
             i = next(ctr)
             kind, geom = records[i]
             if kind == "gemm":
-                return ops.gemm(jnp.asarray(x), jnp.asarray(y),
-                                interpret=interpret, out_dtype=jnp.float32)
+                return unsharded(
+                    lambda xx, yy: ops.gemm(xx, yy, interpret=interpret,
+                                            out_dtype=jnp.float32),
+                    jnp.asarray(x), jnp.asarray(y))
             p = payload_[i]
             if kind == "act":
-                z, diag = _dispatch.apply_activation_dispatch(
-                    geom, p["arrays"], x, y, interpret=interpret)
+                z, diag = unsharded(
+                    lambda arrays, xx, yy: _dispatch.apply_activation_dispatch(
+                        geom, arrays, xx, yy, interpret=interpret),
+                    p["arrays"], jnp.asarray(x), jnp.asarray(y))
                 act_diags.append(diag)
                 return z
             if kind == "shard":

@@ -109,4 +109,8 @@ def test_calibrated_model_is_a_hardware_model():
     assert not VCK5000.fallback
     fb = runtime_fallback("cpu")
     assert fb.fallback and fb.name == "cpu-fallback"
-    assert runtime_fallback("tpu") is TPUV5E
+    assert runtime_fallback("TPU v5 lite") is TPUV5E
+    assert runtime_fallback() is fb           # this host's own kind: "cpu"
+    # another chip generation must not borrow v5e's constants
+    with pytest.raises(ValueError, match="no hardware model"):
+        runtime_fallback("TPU v4")

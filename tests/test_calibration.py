@@ -65,12 +65,15 @@ def test_get_calibrated_caches_and_counts(monkeypatch):
     assert cache.calibration_count() == 1
 
 
-def test_calibration_key_binds_backend_block_dtype():
+def test_calibration_key_binds_backend_block_dtype(monkeypatch):
     base = runtime_fallback("cpu")
     k = calibrate.calibration_key(base, 8, "float32")
-    assert k == (compat.backend_kind(), 8, "float32", base.name)
+    assert k == (compat.device_kind(), 8, "float32", base.name)
     assert k != calibrate.calibration_key(base, 16, "float32")
     assert k != calibrate.calibration_key(VCK5000, 8, "float32")
+    # another chip generation never replays this one's measurements
+    monkeypatch.setattr(compat, "device_kind", lambda: "TPU v4")
+    assert calibrate.calibration_key(base, 8, "float32") != k
 
 
 def test_snapshot_file_roundtrip_and_replay(tmp_path, monkeypatch):

@@ -62,6 +62,6 @@ def test_full_scale_small_datasets_load():
     g = load_graph("CO")
     assert g.stats.vertices == 2708
     assert g.adj.nnz == 5429 + 2708  # edges + self loops
-    assert g.features_dense.shape == (2708, 2708)
+    assert g.features_dense.shape == (2708, 1433)
     # adjacency density ~ Table IV (0.14%)
     assert g.adj.density == pytest.approx(0.0014, rel=0.5)

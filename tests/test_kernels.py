@@ -9,8 +9,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import ops, ref
+from repro.kernels import formats, ops, ref
 from repro.kernels.formats import pack_blockcsr, pack_blockcsr_coo
 
 jax.config.update("jax_enable_x64", False)
@@ -105,6 +106,53 @@ def test_spdmm_dtypes(dtype):
                                atol=TOL[dtype] * 10)
 
 
+# The TPU interpreter starts every VMEM buffer as NaN, as the chip leaves it
+# uninitialized: a launch that failed to resume a split run reads NaN.
+TPU_INTERPRET = pltpu.InterpretParams(uninitialized_memory="nan")
+
+
+def _fused_spdmm_operands(n_stripes, bn, block=8):
+    """One fused SpDMM entry list: every stored block of a random sparse A
+    against ``n_stripes`` column stripes of Y, sorted by output block."""
+    a_dense = _rand(5 * block, 6 * block, np.float32, density=0.3)
+    y = _rand(6 * block, n_stripes * bn, np.float32)
+    a = pack_blockcsr(a_dense, block)
+    nb = a.nnzb
+    rows = np.asarray(a.row_ids)[:nb]
+    cols = np.asarray(a.col_ids)[:nb]
+    first = np.asarray(a.first)[:nb]
+    entries = sorted((int(r), j, i) for j in range(n_stripes)
+                     for i, r in enumerate(rows))
+    e = np.array(entries, dtype=np.int32)
+    ids = (e[:, 2], cols[e[:, 2]], e[:, 0], e[:, 1], first[e[:, 2]])
+    return a, a_dense, y, ids
+
+
+@pytest.mark.parametrize("n_stripes,bn", [(1, 16), (3, 8)])
+@pytest.mark.parametrize("max_entries", [1, 3, 7])
+def test_spdmm_fused_split_launches_bitwise(n_stripes, bn, max_entries,
+                                            monkeypatch):
+    """An entry list split across launches (the per-launch SMEM limit) gives
+    the single launch's bits: runs cut by a launch boundary resume from the
+    canvas.  Column stripes narrower than 128 lanes (bn=8 over a 24-wide Y)
+    exercise the stripe-major layout."""
+    a, a_dense, y, ids = _fused_spdmm_operands(n_stripes, bn)
+    m_pad = a.n_block_rows * 8
+    canvas = jnp.full((m_pad, n_stripes * bn), 7.0, jnp.float32)
+    kw = dict(block_size=8, bn=bn, m_pad=m_pad, interpret=TPU_INTERPRET)
+    one = ops.spdmm_fused(a.blocks, jnp.asarray(y), *ids, z=canvas, **kw)
+    monkeypatch.setattr(ops, "MAX_ENTRIES_PER_LAUNCH", max_entries)
+    launches0 = ops.pallas_call_count()
+    split = ops.spdmm_fused(a.blocks, jnp.asarray(y), *ids, z=canvas, **kw)
+    assert ops.pallas_call_count() - launches0 == -(-len(ids[0])
+                                                    // max_entries)
+    np.testing.assert_array_equal(np.asarray(split), np.asarray(one))
+    np.testing.assert_allclose(np.asarray(one)[:a_dense.shape[0]],
+                               a_dense @ y, rtol=2e-5, atol=2e-4)
+    fresh = ops.spdmm_fused(a.blocks, jnp.asarray(y), *ids, **kw)
+    np.testing.assert_array_equal(np.asarray(fresh), np.asarray(one))
+
+
 # ---------------------------------------------------------------- SpMM
 @pytest.mark.parametrize("da,dy", [(0.0, 0.5), (0.2, 0.2), (0.5, 1.0),
                                    (1.0, 1.0), (1.0, 0.0)])
@@ -119,6 +167,28 @@ def test_spmm_density_sweep(da, dy):
     y = pack_blockcsr(y_dense, block)
     got = ops.spmm(a, y, interpret=True)
     np.testing.assert_allclose(np.asarray(got), a_dense @ y_dense,
+                               rtol=2e-5, atol=2e-4)
+
+
+@pytest.mark.parametrize("max_entries", [1, 5])
+def test_spmm_fused_split_launches_bitwise(max_entries, monkeypatch):
+    """The triple list split across launches matches one launch bitwise,
+    over a Y of several block columns (block-column-major output)."""
+    block = 8
+    a_dense = _rand(4 * block, 5 * block, np.float32, density=0.3)
+    y_dense = _rand(5 * block, 3 * block, np.float32, density=0.3)
+    a = pack_blockcsr(a_dense, block)
+    y = pack_blockcsr(y_dense, block)
+    triples = formats.spmm_triples(a, y)
+    a_blocks = jnp.concatenate([a.blocks, jnp.zeros((1, 8, 8))], axis=0)
+    y_blocks = jnp.concatenate([y.blocks, jnp.zeros((1, 8, 8))], axis=0)
+    kw = dict(block_size=block, m_pad=4 * block, n_pad=3 * block,
+              interpret=TPU_INTERPRET)
+    one = ops.spmm_fused(a_blocks, y_blocks, *triples, **kw)
+    monkeypatch.setattr(ops, "MAX_ENTRIES_PER_LAUNCH", max_entries)
+    split = ops.spmm_fused(a_blocks, y_blocks, *triples, **kw)
+    np.testing.assert_array_equal(np.asarray(split), np.asarray(one))
+    np.testing.assert_allclose(np.asarray(one), a_dense @ y_dense,
                                rtol=2e-5, atol=2e-4)
 
 

@@ -59,6 +59,37 @@ def test_micro_batched_matches_per_request_reference(model):
                                    rtol=1e-3, atol=1e-3)
 
 
+def test_latency_covers_block_until_ready_of_the_result(monkeypatch):
+    """The serving timer stops only after the logits are ready on the
+    device: dispatch returns before the device finishes, so a latency taken
+    without blocking would measure enqueue time."""
+    import time
+
+    import jax
+
+    adj = _rand_graph()
+    params = gnn.init_params("GCN", 12, 8, 5)
+    srv = _serving("GCN", params, max_batch=4, literal=False)
+    srv.register_graph("g", adj)
+    real = jax.block_until_ready
+    blocked = []
+
+    def slow_block(x):
+        time.sleep(0.2)
+        blocked.append(x)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", slow_block)
+    outs = srv.serve([("g", RNG.normal(size=(80, 12)).astype(np.float32))
+                      for _ in range(2)])
+    srv.close()
+    # one block per micro-batch, on the stacked logits the requests share
+    assert len(blocked) == srv.stats.batches == 1
+    assert blocked[0].shape == (80, 4 * outs[0].shape[1])
+    for r in srv.stats.requests:
+        assert r.t_execute >= 0.2 and r.latency >= 0.2
+
+
 def test_coalescing_respects_max_batch_and_records_stats():
     adj = _rand_graph(seed=9)
     params = gnn.init_params("GCN", 12, 8, 5)
