@@ -394,9 +394,10 @@ def apply_dispatch(geom: DispatchGeometry, arrays, x, y, *, interpret: bool):
     if geom.has_gemm and x is None:
         raise ValueError("compiled dispatch: dense-queue tasks need the "
                          "densified x operand (got x=None)")
-    y_f = (_stripe_padded_y(geom, y)
-           if (geom.has_spdmm or geom.has_spmm) else None)
-    y_p = _gemm_y_panel(geom, y) if geom.has_gemm else None
+    with jax.named_scope("layout"):
+        y_f = (_stripe_padded_y(geom, y)
+               if (geom.has_spdmm or geom.has_spmm) else None)
+        y_p = _gemm_y_panel(geom, y) if geom.has_gemm else None
     return apply_prepared(geom, arrays, x, y_f, y_p, interpret=interpret)
 
 
@@ -708,16 +709,19 @@ def apply_activation_dispatch(geom: ActivationGeometry, arrays, x, y, *,
     B, SM, SN = geom.B, geom.SM, geom.SN
     x = jnp.asarray(x)
     y = jnp.asarray(y)
-    (pool, row_m, col_m, first_m, nnzb, real,
-     overflow) = ops.pack_activation_stripes(
-        x, block=B, n_stripes=geom.nrt, slot_rows=geom.R,
-        n_block_cols=geom.ncb,
-        capacity=np.asarray(geom.caps) if geom.caps else geom.cap,
-        eps=geom.eps)
+    with jax.named_scope("pack"):
+        (pool, row_m, col_m, first_m, nnzb, real,
+         overflow) = ops.pack_activation_stripes(
+            x, block=B, n_stripes=geom.nrt, slot_rows=geom.R,
+            n_block_cols=geom.ncb,
+            capacity=np.asarray(geom.caps) if geom.caps else geom.cap,
+            eps=geom.eps)
 
+    @jax.named_scope("dense")
     def _dense():
         return ops.gemm(x, y, interpret=interpret, out_dtype=jnp.float32)
 
+    @jax.named_scope("skip")
     def _skip():
         z = jnp.zeros((geom.m_pad, geom.n_pad), dtype=jnp.float32)
         if geom.has_gemm:

@@ -67,6 +67,7 @@ def gemm(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="gemm",
     )(x, y)
 
 
@@ -140,6 +141,7 @@ def gemm_batch_scatter(
         out_shape=jax.ShapeDtypeStruct(z.shape, z.dtype),
         input_output_aliases={4: 0},    # 2 scalar-prefetch + x + y -> z
         interpret=interpret,
+        name="gemm_batch_scatter",
     )(rows, cols, x, y, z)
 
 
@@ -196,4 +198,5 @@ def gemm_batch(
         out_shape=jax.ShapeDtypeStruct((t, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((m, n), jnp.float32)],
         interpret=interpret,
+        name="gemm_batch",
     )(x, y)

@@ -79,6 +79,7 @@ def spdmm(
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), out_dtype),
         interpret=interpret,
+        name="spdmm",
     )(a.row_ids, a.col_ids, a.first, a.blocks, y)
 
 
@@ -202,4 +203,5 @@ def spdmm_fused(
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="spdmm_fused",
     )(*operands)

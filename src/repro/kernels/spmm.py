@@ -102,6 +102,7 @@ def spmm_fused(a_blocks, y_blocks, a_ids, y_ids, out_rows, out_cols, first,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="spmm_fused",
     )(*operands)
 
 

@@ -5,10 +5,10 @@
 
 Fires a burst of synthetic same-graph requests through the ServingEngine
 and prints a machine-readable stats line: latency percentiles, micro-batch
-sizes, plan-cache hit rate and pallas launches per request.  Every kernel
-runs as a Pallas kernel — compiled for the chip on TPU, in interpret mode
-on any other backend — and the runtime mapping plans against the hardware
-model of the device in use (``perfmodel.runtime_fallback``, calibrated on
+sizes, plan-cache hit rate and the compiled path's dispatch counters.
+Every kernel runs as a Pallas kernel — compiled for the chip on TPU, in
+interpret mode on any other backend — and the runtime mapping plans against
+the hardware model of the device in use (``perfmodel.runtime_fallback``, calibrated on
 first plan).  With ``--cache-file`` the SharedPlanCache is loaded before
 serving (restart skips re-analysis — observe packs/analyzes stay 0) and
 saved after.
@@ -43,7 +43,6 @@ def main(argv: list[str] | None = None) -> dict:
     from repro.core import DynasparseEngine
     from repro.core.perfmodel import runtime_fallback
     from repro.data.graphs import load_graph
-    from repro.kernels import ops
     from repro.models import gnn
     from repro.serving import (ServingConfig, ServingEngine, SharedPlanCache,
                                SketchConfig)
@@ -74,12 +73,10 @@ def main(argv: list[str] | None = None) -> dict:
         noise = rng.normal(0, 0.01, size=h0.shape).astype(np.float32)
         reqs.append((args.dataset, (h0 + noise * (h0 != 0)).astype(np.float32)))
 
-    ops.reset_pallas_call_count()
     try:
-        outs = srv.serve(reqs)
+        srv.serve(reqs)
     finally:
         srv.close()
-    launches = ops.pallas_call_count()
 
     stats = srv.stats.as_dict()
     stats.update({
@@ -89,7 +86,6 @@ def main(argv: list[str] | None = None) -> dict:
         "cache": cache.stats.as_dict(),
         "cache_bytes": cache.bytes_used,
         "plan_hit_rate": cache.stats.hit_rate,
-        "pallas_launches_per_request": launches / max(1, len(outs)),
         "dispatch": srv.dispatch_stats(),
     })
     print("[gnn_serve] " + json.dumps(stats))

@@ -337,7 +337,15 @@ def compile_model(model: str, engine: DynasparseEngine, adj, h, params,
             return _dispatch.apply_dispatch(geom, p["arrays"], p["xd"], y,
                                             interpret=interpret)
 
-        out = APPLY[model](transport(mm), adj, hh, params)
+        kernel_mm = transport(mm)
+
+        def scoped(x, y, name="kernel"):
+            # the kernel's name on every op it lowers to, its transport's
+            # reshapes and transposes among them
+            with jax.named_scope(name):
+                return kernel_mm(x, y, name=name)
+
+        out = APPLY[model](scoped, adj, hh, params)
         return out, act_diags
 
     tn = engine.tile_n or min(128, int(h.shape[1]))

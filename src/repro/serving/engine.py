@@ -55,6 +55,7 @@ from __future__ import annotations
 import asyncio
 import collections
 import concurrent.futures
+import contextlib
 import dataclasses
 import threading
 import time
@@ -71,6 +72,7 @@ from repro.models import gnn
 from repro.serving.cache import GraphKey, SharedPlanCache, get_shared_cache
 from repro.serving.faults import DeadlineExceeded, FaultInjector
 from repro.serving.sketch import SketchConfig
+from repro.serving.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -389,6 +391,7 @@ class ServingEngine:
         # dtype) — with pad_to_max_batch that is ONE program per graph
         self._compiled: dict[tuple, gnn.CompiledModel] = {}
         self._next_id = 0
+        self._next_batch = 0          # ids of serving.batch spans
         # ONE dispatch worker: micro-batches compute off the event loop (the
         # loop keeps coalescing the next burst), serialized so the shared
         # DynasparseEngine's report/sketch state is never touched twice at
@@ -488,7 +491,9 @@ class ServingEngine:
         stats = RequestStats(request_id=self._next_id, graph_id=graph_id,
                              queue_depth=len(q))
         self._next_id += 1
-        req = _Request(features=jnp.asarray(features),
+        with span("serving.enqueue", request=stats.request_id):
+            features = jnp.asarray(features)
+        req = _Request(features=features,
                        future=loop.create_future(), stats=stats,
                        t_enqueue=time.perf_counter())
         q.append(req)
@@ -671,19 +676,29 @@ class ServingEngine:
         ``max_retries`` backoff retries (transient faults recover), then is
         quarantined: ITS future carries the error, nobody else's.
         """
-        t0 = time.perf_counter()
-        try:
-            if self.faults is not None:
-                self.faults.probe("dispatch", detail=graph_id)
-                for r in batch:
-                    # ';' terminates the id so match="req:1;" can never
-                    # poison request 11 as well
-                    self.faults.probe(
-                        "request", detail=f"req:{r.stats.request_id};")
-            self._execute_batch(graph_id, batch, t0)
-            return
-        except Exception as exc:
-            err = exc
+        b = self._next_batch          # only the dispatch worker gets here
+        self._next_batch += 1
+        # the batch's span covers [t0, t1] of its RequestStats.t_execute:
+        # _execute_batch closes it at t1; on a failure, leaving the block
+        # closes it
+        with contextlib.ExitStack() as batch_span:
+            batch_span.enter_context(span(
+                "serving.batch", batch=b, k=len(batch), attempt=attempt,
+                requests=" ".join(str(r.stats.request_id) for r in batch)))
+            t0 = time.perf_counter()
+            try:
+                if self.faults is not None:
+                    self.faults.probe("dispatch", detail=graph_id)
+                    for r in batch:
+                        # ';' terminates the id so match="req:1;" can never
+                        # poison request 11 as well
+                        self.faults.probe(
+                            "request", detail=f"req:{r.stats.request_id};")
+                self._execute_batch(graph_id, batch, t0, b,
+                                    batch_span.close)
+                return
+            except Exception as exc:
+                err = exc
         if len(batch) > 1:
             with self._stats_lock:
                 self.stats.bisections += 1
@@ -703,8 +718,10 @@ class ServingEngine:
         self._fail_batch(batch, t0, err)
 
     def _execute_batch(self, graph_id: str, batch: list[_Request],
-                       t0: float) -> None:
-        """Serve one micro-batch: stack → pad → one engine pass → split.
+                       t0: float, b: int, end_span) -> None:
+        """Serve one micro-batch ``b``: stack → pad → one engine pass →
+        split, each step in a span of its own (``repro.serving.spans``);
+        ``end_span()`` closes the batch's span once its logits are ready.
 
         Runs on the single dispatch worker thread; futures are resolved
         back on their loop.  Raises on failure — the ladder above decides
@@ -719,21 +736,24 @@ class ServingEngine:
         widths = [r.features.shape[1] for r in batch]
         if len(set(widths)) != 1:   # model zoo fixes the fan-in per model
             raise ValueError(f"micro-batch mixes feature widths {widths}")
-        h = (batch[0].features if k == 1
-             else jnp.concatenate([r.features for r in batch], axis=1))
-        kp = k
-        if self.config.pad_to_max_batch and k < self.config.max_batch:
-            # single-plan serving: pad the stacked width to max_batch so the
-            # engine sees one kernel geometry per graph across all traffic.
-            # The padding REPLICATES the batch's own feature columns
-            # (cycling through its requests) rather than zero-filling: zero
-            # columns would register as density drift against full batches
-            # and thrash the replanner, and would bias the first plan's
-            # column densities.  Each request's output block depends only on
-            # its own columns, so replication leaves results exact.
-            kp = self.config.max_batch
-            h = jnp.concatenate(
-                [h] + [batch[i % k].features for i in range(kp - k)], axis=1)
+        with span("serving.stack", batch=b):
+            h = (batch[0].features if k == 1
+                 else jnp.concatenate([r.features for r in batch], axis=1))
+            kp = k
+            if self.config.pad_to_max_batch and k < self.config.max_batch:
+                # single-plan serving: pad the stacked width to max_batch so
+                # the engine sees one kernel geometry per graph across all
+                # traffic.  The padding REPLICATES the batch's own feature
+                # columns (cycling through its requests) rather than
+                # zero-filling: zero columns would register as density drift
+                # against full batches and thrash the replanner, and would
+                # bias the first plan's column densities.  Each request's
+                # output block depends only on its own columns, so
+                # replication leaves results exact.
+                kp = self.config.max_batch
+                h = jnp.concatenate(
+                    [h] + [batch[i % k].features for i in range(kp - k)],
+                    axis=1)
 
         saved = (self.engine.drift_threshold, self.engine.sketch_rows)
         compiled = False
@@ -748,10 +768,13 @@ class ServingEngine:
             cm = (self._compiled.get(cm_key)
                   if self.config.compile_models else None)
             thr = self.config.sketch.threshold
-            if (cm is not None and thr is not None and not breaker_open
-                    and cm.drifted(
+            drifted = False
+            if cm is not None and thr is not None and not breaker_open:
+                with span("serving.drift", batch=b):
+                    drifted = cm.drifted(
                         h, thr, max_rows=self.config.sketch.max_rows,
-                        eps=self.engine.eps)):
+                        eps=self.engine.eps)
+            if drifted:
                 if self._breaker_event(graph_id):
                     # churn breaker tripped: serve this (and the cooldown's)
                     # traffic on the last-good program instead of entering
@@ -766,13 +789,15 @@ class ServingEngine:
                     cm = None
             if cm is not None:
                 try:
-                    logits = cm(h)
+                    with span("serving.call", batch=b):
+                        logits = cm(h)
                     report = cm.fresh_report()
                     compiled = True
                     if cm.last_activation:
+                        with span("serving.activation", batch=b):
+                            summary = _activation_summary(cm.last_activation)
                         with self._stats_lock:
-                            self.stats.record_activation(
-                                _activation_summary(cm.last_activation))
+                            self.stats.record_activation(summary)
                 except Exception:
                     # degraded mode: compiled call failed → serve THIS batch
                     # on the eager batched path (program kept — see above)
@@ -782,45 +807,52 @@ class ServingEngine:
                         batched_mm(self.engine), adj, h, self.params)
                     report = self.engine.report
             else:
-                self.engine.reset()
-                if self.config.compile_models:
-                    logits, built = gnn.compile_model(
-                        self.model, self.engine, adj, h, self.params,
-                        transport=stacked_transport,
-                        activation_skip=self.config.activation_skip,
-                        activation_slack=self.config.activation_slack,
-                        activation_per_stripe=(
-                            self.config.activation_per_stripe))
-                    if built is not None:
-                        self._compiled[cm_key] = built
-                        while len(self._compiled) > self.config.max_compiled:
-                            self._compiled.pop(next(iter(self._compiled)))
-                else:
-                    logits = gnn.APPLY[self.model](batched_mm(self.engine),
-                                                   adj, h, self.params)
-                report = self.engine.report
+                with span("serving.replan", batch=b):
+                    self.engine.reset()
+                    if self.config.compile_models:
+                        logits, built = gnn.compile_model(
+                            self.model, self.engine, adj, h, self.params,
+                            transport=stacked_transport,
+                            activation_skip=self.config.activation_skip,
+                            activation_slack=self.config.activation_slack,
+                            activation_per_stripe=(
+                                self.config.activation_per_stripe))
+                        if built is not None:
+                            self._compiled[cm_key] = built
+                            while (len(self._compiled)
+                                   > self.config.max_compiled):
+                                self._compiled.pop(next(iter(self._compiled)))
+                    else:
+                        logits = gnn.APPLY[self.model](
+                            batched_mm(self.engine), adj, h, self.params)
+                    report = self.engine.report
         finally:
             self.engine.drift_threshold, self.engine.sketch_rows = saved
         # the dispatch returns before the device finishes: wait for the
         # logits so t1 (and every latency derived from it) covers the compute
-        logits = jax.block_until_ready(logits)
+        with span("serving.wait", batch=b):
+            logits = jax.block_until_ready(logits)
         t1 = time.perf_counter()
-        out_w = logits.shape[1] // kp
-        with self._stats_lock:
-            self.stats.batches += 1
-            self.stats.compiled_batches += int(compiled)
-            self.stats.degraded_batches += int(degraded)
-            self.stats.batch_reports.append(report)
-        # heartbeat BEFORE resolving any future: serve() returns the moment
-        # the last future resolves, and dispatch_stats()["health"] must
-        # already show this batch's step by then (racing the worker's
-        # epilogue against the caller reads as a missed heartbeat)
-        self._monitor.heartbeat("dispatch-0", step_time=t1 - t0)
-        share = report.attributed(k)
-        for idx, r in enumerate(batch):
-            z = logits[:, idx * out_w:(idx + 1) * out_w]
-            self._record_request(r, t0=t0, t1=t1, batch_size=k, report=share)
-            self._resolve(r.future, result=z)
+        end_span()
+        with span("serving.split", batch=b):
+            out_w = logits.shape[1] // kp
+            with self._stats_lock:
+                self.stats.batches += 1
+                self.stats.compiled_batches += int(compiled)
+                self.stats.degraded_batches += int(degraded)
+                self.stats.batch_reports.append(report)
+            # heartbeat BEFORE resolving any future: serve() returns the
+            # moment the last future resolves, and dispatch_stats()["health"]
+            # must already show this batch's step by then (racing the
+            # worker's epilogue against the caller reads as a missed
+            # heartbeat)
+            self._monitor.heartbeat("dispatch-0", step_time=t1 - t0)
+            share = report.attributed(k)
+            for idx, r in enumerate(batch):
+                z = logits[:, idx * out_w:(idx + 1) * out_w]
+                self._record_request(r, t0=t0, t1=t1, batch_size=k,
+                                     report=share)
+                self._resolve(r.future, result=z)
 
     # ------------------------------------------------------ sync interface
     def serve(self, requests: Iterable[tuple[str, object]],
