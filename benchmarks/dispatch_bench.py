@@ -115,7 +115,7 @@ def _kernel_level(adj: SparseCOO, width: int = 16, repeats: int = 5) -> dict:
     return {
         "descriptor_build_s": build_s,
         "n_spdmm_entries": d.n_entries,
-        "n_spmm_triples": d.n_triples,
+        "n_spmm_steps": d.n_spmm_steps,
         "eager_execute_s": eager_s,
         "compiled_execute_s": compiled_s,
         "speedup_eager_over_compiled": eager_s / max(compiled_s, 1e-12),
@@ -403,7 +403,7 @@ def _multidev(adj: SparseCOO, width: int = 16, repeats: int = 5) -> dict:
             per_dev += int(sd.arrays[k].shape[-1])
     d_global = dispatch_mod.build_dispatch(plan.part, plan.stq, plan.dtq,
                                            entry.stripes, block=eng.block)
-    global_desc = d_global.n_entries + d_global.n_triples
+    global_desc = d_global.sparse_steps
     if "gemm_rows" in d_global.arrays:
         global_desc += int(d_global.arrays["gemm_rows"].shape[-1])
 

@@ -6,33 +6,40 @@ Alg. 4); GraphAGILE goes further and compiles the whole layer sequence into a
 static instruction stream ahead of execution.  This module is that final step
 for the TPU runtime: a planned kernel is lowered into a
 :class:`CompiledDispatch` — the sorted fused-kernel descriptor arrays (SpDMM
-entry list, SpMM triple list, batched-GEMM tile coordinates), the pooled
+and SpMM entry lists, batched-GEMM tile coordinates), the pooled
 BlockCSR block payloads, and the padded-canvas geometry — built once with
 vectorized numpy (no per-nonzero-block Python loops) and kept device-resident
 in the :class:`~repro.core.plancache.PlanCache`.
 
 Steady-state execution then goes through :func:`execute_dispatch`: ONE jitted
 end-to-end program per (geometry, operand signature) that chains
-pad → gemm_batch_scatter → spdmm_fused → spmm_fused → slice with the
-descriptors as device arrays, so a plan-cache hit costs O(1) dict lookups on
-the host instead of O(nnz blocks) of descriptor rebuilding.
+pad → gemm_batch_scatter → spdmm_fused (SpDMM section) → spdmm_fused (SpMM
+section) → slice with the descriptors as device arrays, so a plan-cache hit
+costs O(1) dict lookups on the host instead of O(nnz blocks) of descriptor
+rebuilding.
 
 Semantics vs the eager batched path (`scheduler._execute_batched`):
 
 - GEMM and SpDMM lower exactly the same operations in the same order —
   bit-identical by construction.
 - SpMM descriptors must be Y-structure-independent to be cacheable (the eager
-  path packs the dense operand's col-stripes per call), so the compiled triple
-  list pairs every stored A block with EVERY logical Y block of the task's
-  col-stripe.  The extra pairs multiply real A blocks into exactly-zero Y
-  blocks, and ``x + (±0) == x`` bitwise for every value the accumulator can
-  take (it is initialized to +0 and can never become -0), so the result is
-  still bit-identical.  With ``eps != 0`` an eps-thresholded pack *drops*
-  small-but-nonzero Y blocks the pairing would keep, so the executor applies
-  the eps mask INSIDE the traced program instead: Y blocks whose magnitudes
-  are all ``<= eps`` are zeroed on device before the kernel, turning their
-  pairs into the same exact bitwise no-ops — the pairing stays structure-
-  independent and eps-thresholded SpMM plans compile like any other.
+  path packs the dense operand's col-stripes per call), so the compiled SpMM
+  section does not intersect Y's structure at all: it is a STRIPE WALK in the
+  SpDMM entry format, one grid step per (stored A block, task column stripe)
+  — each step multiplies the A block into its Y block-row across the whole
+  ``SN``-wide stripe.  These are the multiply-adds of pairing every stored A
+  block with every 8x8 Y block of the stripe (zero Y blocks contribute
+  ``x + (±0) == x``, bitwise, to an accumulator initialized to +0), in the
+  same A-block order per output block, but in ``nnzb`` grid steps per task
+  where the 8-wide pairing took ``nnzb * SN / B``.  One (B, B)@(B, SN) dot
+  rounds like ``SN / B`` dots of width B only up to about one ulp, so the
+  result is bit-identical to the eager run of the same plan with its SpMM
+  tasks relabelled SpDMM, and equal to the structure-intersecting eager SpMM
+  within float32 rounding.  With ``eps != 0`` an eps-thresholded pack
+  *drops* small-but-nonzero Y blocks the stripe walk would read, so the
+  executor zeroes the Y blocks whose magnitudes are all ``<= eps`` on device
+  before the SpMM section (the SpDMM section reads the unmasked operand,
+  hence its own launch) — eps-thresholded SpMM plans compile like any other.
 
 Activation-side kernels (dense X — the intermediate feature matrices) get the
 same treatment through :class:`ActivationDispatch`: the descriptor arrays are
@@ -134,13 +141,20 @@ class CompiledDispatch:
 
     @property
     def n_entries(self) -> int:
+        """Grid steps of the SpDMM section (one per entry)."""
         a = self.arrays.get("sp_a_ids")
         return 0 if a is None else int(a.shape[0])
 
     @property
-    def n_triples(self) -> int:
+    def n_spmm_steps(self) -> int:
+        """Grid steps of the SpMM section's stripe walk."""
         a = self.arrays.get("mm_a_ids")
         return 0 if a is None else int(a.shape[0])
+
+    @property
+    def sparse_steps(self) -> int:
+        """Grid steps of both sparse sections."""
+        return self.n_entries + self.n_spmm_steps
 
 
 def plan_digest(plan, block: int) -> str:
@@ -194,6 +208,11 @@ def _stripe_pool(tasks, stripes) -> tuple[dict[int, int], jax.Array]:
     return offsets, jnp.concatenate(pool, axis=0)
 
 
+# the per-entry descriptor arrays of a fused sparse section, in the order
+# :func:`spdmm_entry_arrays` returns them and the fused kernels take them
+ENTRY_FIELDS = ("a_ids", "y_rows", "out_rows", "out_cols", "first")
+
+
 def spdmm_entry_arrays(tasks, stripes: dict[int, "BlockCSR"],
                        offsets: dict[int, int], R: int):
     """Vectorized fused-SpDMM entry list over all tasks of one kernel.
@@ -227,44 +246,6 @@ def spdmm_entry_arrays(tasks, stripes: dict[int, "BlockCSR"],
             firsts[order].astype(np.int32))
 
 
-def _spmm_dense_y_triples(tasks, part, stripes, offsets, R: int, C: int,
-                          n_y_block_cols: int):
-    """Vectorized fused-SpMM triple list with a Y-structure-INDEPENDENT
-    pairing: every stored A block of a task's row-stripe is paired with every
-    logical Y block of the task's col-stripe (``y_id = ib * Ctot + cb`` into
-    the row-major block pool :func:`repro.kernels.ops.blockize` builds from
-    the dense operand at run time).  Zero Y blocks contribute exact bitwise
-    no-ops, so the result matches the structure-intersecting eager pairing —
-    see the module docstring for the eps caveat.
-    """
-    out_rows, out_cols, a_ids, y_ids = [], [], [], []
-    for task in tasks:
-        s = stripes[task.i]
-        nb = s.nnzb
-        nbj = -(-part.col_extent(task.j) // stripes[task.i].block_size)
-        rid = np.asarray(s.row_ids)[:nb].astype(np.int64)
-        cid = np.asarray(s.col_ids)[:nb].astype(np.int64)
-        kb = np.tile(np.arange(nbj, dtype=np.int64), nb)
-        out_rows.append(np.repeat(task.i * R + rid, nbj))
-        out_cols.append(task.j * C + kb)
-        a_ids.append(np.repeat(offsets[task.i] + np.arange(nb, dtype=np.int64),
-                               nbj))
-        y_ids.append(np.repeat(cid, nbj) * n_y_block_cols + task.j * C + kb)
-    out_rows = np.concatenate(out_rows)
-    out_cols = np.concatenate(out_cols)
-    a_ids = np.concatenate(a_ids)
-    y_ids = np.concatenate(y_ids)
-    order = np.lexsort((y_ids, a_ids, out_cols, out_rows))
-    out_rows, out_cols = out_rows[order], out_cols[order]
-    first = np.ones(len(out_rows), dtype=np.int32)
-    if len(first) > 1:
-        same = ((out_rows[1:] == out_rows[:-1])
-                & (out_cols[1:] == out_cols[:-1]))
-        first[1:][same] = 0
-    return (a_ids[order].astype(np.int32), y_ids[order].astype(np.int32),
-            out_rows.astype(np.int32), out_cols.astype(np.int32), first)
-
-
 def build_dispatch(part, stq, dtq, stripes: dict[int, "BlockCSR"],
                    *, block: int, eps: float = 0.0,
                    fingerprint: str = "",
@@ -285,7 +266,7 @@ def build_dispatch(part, stq, dtq, stripes: dict[int, "BlockCSR"],
         return None
     SM, SN = slots
     B = block
-    R, C = SM // B, SN // B
+    R = SM // B
     geom = DispatchGeometry(
         M=part.M, K=part.K, N=part.N, tm=part.tile_m, tn=part.tile_n,
         SM=SM, SN=SN, B=B, nrt=part.n_row_tiles, nct=part.n_col_tiles,
@@ -301,31 +282,17 @@ def build_dispatch(part, stq, dtq, stripes: dict[int, "BlockCSR"],
         arrays["gemm_cols"] = jnp.asarray(
             np.array([t.j for t in dtq], dtype=np.int32))
 
-    spdmm_tasks = [t for t in stq if t.primitive != "SpMM"]
-    spmm_tasks = [t for t in stq if t.primitive == "SpMM"]
-
-    if spdmm_tasks:
-        offsets, pool = _stripe_pool(spdmm_tasks, stripes)
-        a_ids, y_rows, out_rows, out_cols, first = spdmm_entry_arrays(
-            spdmm_tasks, stripes, offsets, R)
-        arrays["sp_pool"] = pool
-        arrays["sp_a_ids"] = jnp.asarray(a_ids)
-        arrays["sp_y_rows"] = jnp.asarray(y_rows)
-        arrays["sp_out_rows"] = jnp.asarray(out_rows)
-        arrays["sp_out_cols"] = jnp.asarray(out_cols)
-        arrays["sp_first"] = jnp.asarray(first)
-
-    if spmm_tasks:
-        offsets, pool = _stripe_pool(spmm_tasks, stripes)
-        a_ids, y_ids, out_rows, out_cols, first = _spmm_dense_y_triples(
-            spmm_tasks, part, stripes, offsets, R, C,
-            n_y_block_cols=geom.nct * C)
-        arrays["mm_pool"] = pool
-        arrays["mm_a_ids"] = jnp.asarray(a_ids)
-        arrays["mm_y_ids"] = jnp.asarray(y_ids)
-        arrays["mm_out_rows"] = jnp.asarray(out_rows)
-        arrays["mm_out_cols"] = jnp.asarray(out_cols)
-        arrays["mm_first"] = jnp.asarray(first)
+    # both sparse sections in the SpDMM entry format: the SpMM section is
+    # the stripe walk (module docstring), a launch of its own
+    for prefix, tasks in (("sp", [t for t in stq if t.primitive != "SpMM"]),
+                          ("mm", [t for t in stq if t.primitive == "SpMM"])):
+        if not tasks:
+            continue
+        offsets, pool = _stripe_pool(tasks, stripes)
+        arrays[f"{prefix}_pool"] = pool
+        for name, v in zip(ENTRY_FIELDS,
+                           spdmm_entry_arrays(tasks, stripes, offsets, R)):
+            arrays[f"{prefix}_{name}"] = jnp.asarray(v)
 
     return CompiledDispatch(geom=geom, arrays=arrays, fingerprint=fingerprint)
 
@@ -344,17 +311,19 @@ def _stripe_padded_y(geom, y):
                    ).reshape(ncb * B, geom.nct * geom.SN)
 
 
-def _masked_y_blocks(geom, y_f):
-    """Blockized dense operand with the eps mask applied on device: blocks
-    whose magnitudes are all ``<= eps`` are zeroed, so the structure-
-    independent pairing contributes exact bitwise no-ops for exactly the
-    blocks an eps-thresholded eager pack would have dropped."""
-    y_blocks = ops.blockize(y_f, geom.B)
-    if geom.eps != 0.0:
-        keep = block_nonzero_mask(y_blocks, geom.eps, axis=(-2, -1), xp=jnp)
-        y_blocks = jnp.where(keep[:, None, None], y_blocks,
-                             jnp.zeros((), y_blocks.dtype))
-    return y_blocks
+def _eps_masked_y(geom, y_f):
+    """Stripe-padded dense operand with the eps mask applied on device:
+    blocks whose magnitudes are all ``<= eps`` are zeroed, so the
+    structure-independent SpMM lowerings contribute exact bitwise no-ops
+    for exactly the blocks an eps-thresholded eager pack would have
+    dropped.  The operand itself when ``eps == 0``."""
+    if geom.eps == 0.0:
+        return y_f
+    B = geom.B
+    yb = y_f.reshape(y_f.shape[0] // B, B, y_f.shape[1] // B, B)
+    keep = block_nonzero_mask(yb, geom.eps, axis=(1, 3), xp=jnp)
+    return jnp.where(keep[:, None, :, None], yb,
+                     jnp.zeros((), y_f.dtype)).reshape(y_f.shape)
 
 
 def _gemm_y_panel(geom, y):
@@ -387,7 +356,7 @@ def _gemm_scatter(geom, arrays, x, y, z, *, interpret: bool):
 
 def apply_dispatch(geom: DispatchGeometry, arrays, x, y, *, interpret: bool):
     """Traceable end-to-end executor body: pad → batched GEMM scatter →
-    fused SpDMM → fused SpMM → slice, on ONE aliased canvas.  ``x`` (the
+    fused SpDMM → SpMM stripe walk → slice, on ONE aliased canvas.  ``x`` (the
     densified operand) may be ``None`` when the plan has no dense-queue
     tasks.  Inlines into larger jitted programs (`models.gnn.compile_model`).
     """
@@ -423,12 +392,11 @@ def apply_prepared(geom: DispatchGeometry, arrays, x, y_f, y_p,
             block_size=B, bn=SN, m_pad=M_pad, interpret=interpret, z=z)
 
     if geom.has_spmm:
-        y_blocks = _masked_y_blocks(geom, y_f)
-        z = ops.spmm_fused(
-            arrays["mm_pool"], y_blocks, arrays["mm_a_ids"],
-            arrays["mm_y_ids"], arrays["mm_out_rows"], arrays["mm_out_cols"],
-            arrays["mm_first"], block_size=B, m_pad=M_pad, n_pad=N_pad,
-            interpret=interpret, z=z)
+        z = ops.spdmm_fused(
+            arrays["mm_pool"], _eps_masked_y(geom, y_f), arrays["mm_a_ids"],
+            arrays["mm_y_rows"], arrays["mm_out_rows"], arrays["mm_out_cols"],
+            arrays["mm_first"], block_size=B, bn=SN, m_pad=M_pad,
+            interpret=interpret, z=z, name="spmm_stripe")
 
     return z[:geom.M, :geom.N]
 
@@ -737,7 +705,7 @@ def apply_activation_dispatch(geom: ActivationGeometry, arrays, x, y, *,
                 block_size=B, bn=SN, m_pad=geom.m_pad, interpret=interpret,
                 z=z)
         if geom.has_spmm:
-            y_blocks = _masked_y_blocks(geom, y_f)
+            y_blocks = ops.blockize(_eps_masked_y(geom, y_f), B)
             a_ids = arrays["amm_a_ids"]
             y_ids = col_m[a_ids] * (geom.nct * geom.C) + arrays["amm_y_cols"]
             z = ops.spmm_fused(

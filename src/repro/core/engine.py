@@ -387,7 +387,8 @@ class DynasparseEngine:
         non-batched engines, uncacheable (dense X) operands, or canvas-
         misaligned geometry.  eps-thresholded SpMM plans compile too — the
         executor masks sub-eps Y blocks inside the traced program, so the
-        pairing stays Y-structure-independent (``repro.core.dispatch``)."""
+        SpMM stripe walk stays Y-structure-independent
+        (``repro.core.dispatch``)."""
         if not (self.literal and self.batched):
             return None
         if self.mesh is not None:

@@ -22,24 +22,24 @@ padding needed to equalize per-device entry counts:
 - GEMM pads address output tile ``(nrt_local - 1, 0)`` — the gathered X slab
   for the ghost tile is all zeros, so the scatter overwrites the ghost tile
   with zeros;
-- SpDMM / SpMM pads reference an appended all-zero pool block with
-  ``first = 0`` at the ghost tile's first block-row, so they ACCUMULATE
-  ``0 · Y`` into an already-zero canvas block (the kernels' ``first == 1``
-  zero-init / ``first == 0`` accumulate semantics make this an exact bitwise
-  no-op — the same sentinel-zero-block idiom ``kernels/spmm.py`` uses for its
-  own padding triples).
+- SpDMM / SpMM pads (both sections use the SpDMM entry format) reference an
+  appended all-zero pool block with ``first = 0`` at the ghost tile's first
+  block-row, so they ACCUMULATE ``0 · Y`` into an already-zero canvas block
+  (the kernels' ``first == 1`` zero-init / ``first == 0`` accumulate
+  semantics make this an exact bitwise no-op — the same sentinel-zero-block
+  idiom ``kernels/spmm.py`` uses for its own padding triples).
 
 Owned-operand sharding with halo exchange (``operand_sharding="halo"``)
 -----------------------------------------------------------------------
 By default the dense operand Y no longer enters the program replicated.
 Lowering runs a per-band COLUMN-SUPPORT analysis over the descriptors it
-just built (SpDMM entries name their Y block-rows directly; SpMM triples
-encode them in ``y_ids``; GEMM bands read everything → replicated
-fallback), emits one :class:`repro.core.halo.ColumnSupport` per device, and
-compiles a static ring-exchange schedule (:func:`repro.core.halo.
-build_exchange`).  Y is split by block-row OWNERSHIP outside the program
-(each shard's ``in_spec P("data")`` slab holds only its owned rows), the
-``shard_map`` body first runs ``nd - 1`` ``ppermute`` rounds copying halo
+just built (SpDMM and SpMM entries name their Y block-rows directly; GEMM
+bands read everything → replicated fallback), emits one
+:class:`repro.core.halo.ColumnSupport` per device, and compiles a static
+ring-exchange schedule (:func:`repro.core.halo.build_exchange`).  Y is
+split by block-row OWNERSHIP outside the program (each shard's
+``in_spec P("data")`` slab holds only its owned rows), the ``shard_map``
+body first runs ``nd - 1`` ``ppermute`` rounds copying halo
 blocks into a local ``(L + 1)`` slot owned+halo buffer, and the SpDMM/SpMM
 descriptors — rewritten at lowering time from global block-rows to local
 buffer slots — feed the very same fused kernels.  Per-device dense-operand
@@ -107,6 +107,13 @@ class ShardedDispatch:
     def needs_x(self) -> bool:
         return self.geom.has_gemm
 
+    @property
+    def sparse_steps(self) -> int:
+        """Grid steps of both sparse sections on each device (every shard
+        walks the same padded entry count)."""
+        return sum(int(self.arrays[k].shape[-1])
+                   for k in ("sp_a_ids", "mm_a_ids") if k in self.arrays)
+
 
 def _pool_dtype(stripes):
     for s in stripes.values():
@@ -119,11 +126,11 @@ def _band_tasks(tasks, placement, d):
     return [dataclasses.replace(t, i=t.i - lo) for t in tasks if lo <= t.i < hi]
 
 
-def _column_supports(per_gemm, per_spdmm, per_spmm, own_starts, ncb, nyc):
+def _column_supports(per_gemm, per_spdmm, per_spmm, own_starts, ncb):
     """Per-device :class:`~repro.core.halo.ColumnSupport` from the lowered
-    descriptor arrays: SpDMM entries carry Y block-rows in ``y_rows``, SpMM
-    triples carry ``block_row * nyc + block_col`` in ``y_ids``, and a band
-    with real GEMM tasks reads the whole operand (replicated fallback)."""
+    descriptor arrays: SpDMM and SpMM entries carry Y block-rows in
+    ``y_rows``, and a band with real GEMM tasks reads the whole operand
+    (replicated fallback)."""
     nd = len(own_starts) - 1
     supports = []
     for d in range(nd):
@@ -132,12 +139,9 @@ def _column_supports(per_gemm, per_spdmm, per_spmm, own_starts, ncb, nyc):
             read = set(range(ncb))
         else:
             read = set()
-            e = per_spdmm[d][1]
-            if e is not None:
-                read.update(int(g) for g in np.unique(e[1]))
-            e = per_spmm[d][1]
-            if e is not None:
-                read.update(int(g) for g in np.unique(e[1] // nyc))
+            for _, e in (per_spdmm[d], per_spmm[d]):
+                if e is not None:
+                    read.update(int(g) for g in np.unique(e[1]))
         own = range(own_starts[d], own_starts[d + 1])
         supports.append(_halo.ColumnSupport(
             own_start=own_starts[d], own_stop=own_starts[d + 1],
@@ -145,22 +149,17 @@ def _column_supports(per_gemm, per_spdmm, per_spmm, own_starts, ncb, nyc):
     return tuple(supports)
 
 
-def _localize_entries(supports, per_spdmm, per_spmm, ncb, nyc):
+def _localize_entries(supports, per_spdmm, per_spmm, ncb):
     """Rewrite Y indices from GLOBAL block-rows to LOCAL owned+halo buffer
     slots, per device.  Entry order (hence accumulation order) untouched."""
     sp_out, mm_out = [], []
-    for cs, (sp_pool, sp_e), (mm_pool, mm_e) in zip(
-            supports, per_spdmm, per_spmm):
+    for cs, sp, mm in zip(supports, per_spdmm, per_spmm):
         lut = np.zeros(ncb, np.int64)
         for slot, g in enumerate(cs.local_blocks()):
             lut[g] = slot
-        if sp_e is not None:
-            sp_e = (sp_e[0], lut[sp_e[1]], sp_e[2], sp_e[3], sp_e[4])
-        if mm_e is not None:
-            mm_e = (mm_e[0], lut[mm_e[1] // nyc] * nyc + mm_e[1] % nyc,
-                    mm_e[2], mm_e[3], mm_e[4])
-        sp_out.append((sp_pool, sp_e))
-        mm_out.append((mm_pool, mm_e))
+        for (pool, e), out in ((sp, sp_out), (mm, mm_out)):
+            out.append((pool, None if e is None
+                        else (e[0], lut[e[1]], *e[2:])))
     return sp_out, mm_out
 
 
@@ -198,7 +197,7 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
         return None
     SM, SN = slots
     B = block
-    R, C = SM // B, SN // B
+    R = SM // B
     nd = placement.n_devices
     bs = placement.band_starts
     max_band = max(placement.band_sizes()) if nd else 0
@@ -233,9 +232,8 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
         if mm:
             offsets, pool = _dispatch._stripe_pool(mm, local_stripes)
             per_spmm.append((np.asarray(pool),
-                             _dispatch._spmm_dense_y_triples(
-                                 mm, part, local_stripes, offsets, R, C,
-                                 n_y_block_cols=part.n_col_tiles * C)))
+                             _dispatch.spdmm_entry_arrays(
+                                 mm, local_stripes, offsets, R)))
         else:
             per_spmm.append((np.zeros((0, B, B), _pool_dtype(stripes)),
                              None))
@@ -243,7 +241,6 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
     n_gemm = max((len(g) for g in per_gemm), default=0)
 
     ncb = -(-part.K // B)
-    nyc = part.n_col_tiles * C                 # Y pool blocks per block-row
     supports: tuple = ()
     hg = None
     hx_arrays: dict[str, np.ndarray] = {}
@@ -251,9 +248,9 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
         own_starts = _halo.ownership_starts(part.M, part.K, part.tile_m,
                                             bs, B)
         supports = _column_supports(per_gemm, per_spdmm, per_spmm,
-                                    own_starts, ncb, nyc)
+                                    own_starts, ncb)
         per_spdmm, per_spmm = _localize_entries(supports, per_spdmm,
-                                                per_spmm, ncb, nyc)
+                                                per_spmm, ncb)
         hg, own_dst, hx_src, hx_dst, gather = _halo.build_exchange(
             supports, own_starts, gather=n_gemm > 0)
         hx_arrays = {"hx_own_dst": own_dst, "hx_src": hx_src,
@@ -305,28 +302,20 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
             out[name] = put(np.stack(columns[k]).astype(np.int32))
         return out
 
-    if n_sp:
+    for prefix, per_dev, n_entries in (("sp", per_spdmm, n_sp),
+                                       ("mm", per_spmm, n_mm)):
+        if not n_entries:
+            continue
         sec = _stack_section(
-            per_spdmm, n_sp,
-            ("a_ids", "y_rows", "out_rows", "out_cols", "first"),
+            per_dev, n_entries, _dispatch.ENTRY_FIELDS,
             # pads: zero-sentinel A block × Y row 0 → ghost block, first=0
             # (in halo mode Y row 0 is local slot 0 — any resident block
             # works: a zero A block accumulates an exact bitwise no-op)
             (lambda pl: pl - 1, lambda pl: 0, lambda pl: ghost_row,
              lambda pl: 0, lambda pl: 0))
-        arrays["sp_pool"] = sec["pool"]
-        for name in ("a_ids", "y_rows", "out_rows", "out_cols", "first"):
-            arrays[f"sp_{name}"] = sec[name]
-
-    if n_mm:
-        sec = _stack_section(
-            per_spmm, n_mm,
-            ("a_ids", "y_ids", "out_rows", "out_cols", "first"),
-            (lambda pl: pl - 1, lambda pl: 0, lambda pl: ghost_row,
-             lambda pl: 0, lambda pl: 0))
-        arrays["mm_pool"] = sec["pool"]
-        for name in ("a_ids", "y_ids", "out_rows", "out_cols", "first"):
-            arrays[f"mm_{name}"] = sec[name]
+        arrays[f"{prefix}_pool"] = sec["pool"]
+        for name in _dispatch.ENTRY_FIELDS:
+            arrays[f"{prefix}_{name}"] = sec[name]
 
     width = part.n_col_tiles * SN
     if operand_sharding == "halo":

@@ -162,7 +162,7 @@ def _row_major(z3):
 def spdmm_fused(a_blocks, y, a_ids, y_rows, out_rows, out_cols, first, *,
                 block_size: int, bn: int, m_pad: int,
                 interpret: bool | None = None, out_dtype=jnp.float32,
-                z=None):
+                z=None, name: str = "spdmm_fused"):
     """Fused multi-task SpDMM over a concatenated stored-block pool; see
     :func:`repro.kernels.spdmm.spdmm_fused`.  ``y`` must already be laid out
     with ``bn``-padded col-stripes; it and the output are handled stripe-
@@ -187,7 +187,7 @@ def spdmm_fused(a_blocks, y, a_ids, y_rows, out_rows, out_cols, first, *,
         z3 = _spdmm.spdmm_fused(
             a_blocks, y3, *(v[lo:hi] for v in ids),
             block_size=block_size, m_pad=m_pad, interpret=interpret,
-            out_dtype=out_dtype, n_entries=hi - lo, z=z3)
+            out_dtype=out_dtype, n_entries=hi - lo, z=z3, name=name)
     return _row_major(z3)
 
 
@@ -195,9 +195,9 @@ def blockize(y, block: int):
     """Dense ``(R*B, C*B)`` matrix → ``(R*C, B, B)`` block pool in row-major
     block order (``pool[r*C + c] == y[r*B:(r+1)*B, c*B:(c+1)*B]``).
 
-    The compiled-dispatch SpMM path derives its Y operand pool from the dense
+    The activation route's SpMM derives its Y operand pool from the dense
     matrix at run time (a reshape/transpose, no host packing), addressed by
-    plan-time ``y_id = row_block * C + col_block`` descriptors."""
+    ``y_id = row_block * C + col_block`` descriptors."""
     m, n = y.shape
     assert m % block == 0 and n % block == 0, (y.shape, block)
     r, c = m // block, n // block

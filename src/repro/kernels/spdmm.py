@@ -123,7 +123,7 @@ def _spdmm_fused_inplace_kernel(aid_ref, yrow_ref, orow_ref, ocol_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("block_size", "m_pad", "interpret", "out_dtype",
-                     "n_entries"),
+                     "n_entries", "name"),
 )
 def spdmm_fused(
     a_blocks: jax.Array,
@@ -140,6 +140,7 @@ def spdmm_fused(
     out_dtype=jnp.float32,
     n_entries: int,
     z: jax.Array | None = None,
+    name: str = "spdmm_fused",
 ) -> jax.Array:
     """Fused multi-task SpDMM: EVERY SpDMM task of a kernel in one launch.
 
@@ -162,6 +163,7 @@ def spdmm_fused(
     covered blocks are written in place and every other block keeps its
     ``z`` content (e.g. tiles already written by the batched GEMM of the
     same kernel), and the launch may open mid-run (:func:`resume_partial`).
+    ``name`` is the launch's name in a profile.
     """
     B = block_size
     n_stripes, k_pad, bn = y.shape
@@ -203,5 +205,5 @@ def spdmm_fused(
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-        name="spdmm_fused",
+        name=name,
     )(*operands)

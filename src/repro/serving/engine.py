@@ -415,6 +415,8 @@ class ServingEngine:
             # per-device dense-operand memory accounting of the sharded
             # dispatches (owned / halo / replicated-fallback bytes)
             "operand_bytes": self.engine.cache.sharded_operand_bytes(),
+            # grid steps of the compiled dispatches' sparse sections
+            "sparse_steps": self.engine.cache.sparse_steps(),
             "dispatch_builds": s.dispatch_builds,
             "dispatch_hits": s.dispatch_hits,
             "act_builds": s.act_builds,

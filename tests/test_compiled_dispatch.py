@@ -3,20 +3,29 @@
 A planned kernel is lowered ONCE into a device-resident CompiledDispatch
 (sorted descriptor arrays + pooled blocks, vectorized numpy build) and every
 later execute is a single jitted call.  These tests pin the load-bearing
-properties: bit-identity against BOTH existing paths (eager batched and
-per-task) across ragged/mixed-primitive geometries, zero host descriptor
-work in steady state, honest cache accounting/eviction, the decline gates
-(eps-thresholded SpMM, misaligned canvas), and the whole-model compiler.
+properties: bit-identity against the exact eager reference of the lowering
+(``spmm_reference``: GEMM and SpDMM as planned, SpMM tasks as the SpDMM
+stripe walk) and agreement with BOTH existing paths (eager batched and
+per-task, whose SpMM intersects Y's structure) across ragged/mixed-primitive
+geometries, the stripe walk's grid-step count, zero host descriptor work in
+steady state, honest cache accounting/eviction, the decline gates
+(misaligned canvas), and the whole-model compiler.
 """
+import dataclasses
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
 from repro.core import DynasparseEngine, SparseCOO
 from repro.core import dispatch as dispatch_mod
+from repro.core.partition import choose_tile, make_tasks
 from repro.core.plancache import PlanCache
 from repro.core.scheduler import execute_plan
+from repro.data.graphs import load_graph
+from repro.kernels.formats import pack_blockcsr_coo
 from repro.models import gnn
+from spmm_reference import eps_masked, stripe_walk_reference
 
 RNG = np.random.default_rng(31)
 
@@ -41,24 +50,33 @@ def _mixed_ragged_operands(seed=1, M=90, K=64, N=44):
 
 
 def _all_paths(eng, xd, yd):
-    """(compiled, eager batched, per-task) results of one planned kernel."""
+    """(compiled, exact reference, eager batched, per-task) results of one
+    planned kernel."""
     x = _coo_of(xd)
     plan = eng.plan(x, jnp.asarray(yd))
     z_c = eng.execute(plan, x, jnp.asarray(yd))
+    z_r = stripe_walk_reference(plan, xd, yd)
     z_b = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd, batched=True)
     z_p = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd, batched=False)
-    return plan, np.asarray(z_c), np.asarray(z_b), np.asarray(z_p)
+    return plan, np.asarray(z_c), z_r, np.asarray(z_b), np.asarray(z_p)
+
+
+def _assert_matches(z_c, z_r, z_b, z_p):
+    """Bitwise the exact reference; the structure-intersecting eager paths
+    within float32 rounding."""
+    np.testing.assert_array_equal(z_c, z_r)
+    np.testing.assert_allclose(z_c, z_b, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(z_c, z_p, rtol=1e-4, atol=1e-4)
 
 
 def test_compiled_mixed_primitives_ragged_bitwise():
     xd, yd = _mixed_ragged_operands()
     eng = DynasparseEngine(tile_m=32, tile_n=24, literal=True)
-    plan, z_c, z_b, z_p = _all_paths(eng, xd, yd)
+    plan, z_c, z_r, z_b, z_p = _all_paths(eng, xd, yd)
     prims = {t.primitive for t in plan.stq} | {t.primitive for t in plan.dtq}
     assert prims == {"SpDMM", "SpMM", "GEMM"}, prims
     assert eng.cache.stats.dispatch_builds == 1   # compiled path was taken
-    np.testing.assert_array_equal(z_c, z_b)
-    np.testing.assert_array_equal(z_c, z_p)
+    _assert_matches(z_c, z_r, z_b, z_p)
     np.testing.assert_allclose(z_c, xd @ yd, rtol=1e-4, atol=1e-4)
 
 
@@ -76,9 +94,8 @@ def test_compiled_bit_identity_across_geometries(tm, tn, mkn, seed):
     yd = (rng.normal(size=(K, N)) *
           (rng.uniform(size=(K, N)) < 0.5)).astype(np.float32)
     eng = DynasparseEngine(tile_m=tm, tile_n=tn, literal=True)
-    _, z_c, z_b, z_p = _all_paths(eng, xd, yd)
-    np.testing.assert_array_equal(z_c, z_b)
-    np.testing.assert_array_equal(z_c, z_p)
+    _, z_c, z_r, z_b, z_p = _all_paths(eng, xd, yd)
+    _assert_matches(z_c, z_r, z_b, z_p)
     np.testing.assert_allclose(z_c, xd @ yd, rtol=1e-4, atol=1e-4)
 
 
@@ -112,10 +129,10 @@ def test_steady_state_builds_nothing_and_hits_trace():
 @pytest.mark.parametrize("eps", [1e-7, 0.2])
 def test_eps_spmm_compiles_bit_identically(eps):
     """Regression (ISSUE 5): eps != 0 with SpMM tasks used to DECLINE
-    compilation and silently stay eager.  The eps-aware masked pairing
-    (sub-eps Y blocks zeroed inside the traced program) lifts the gate:
-    such plans now compile and the compiled result is bit-identical to
-    both eager paths under the same eps."""
+    compilation and silently stay eager.  The eps mask (sub-eps Y blocks
+    zeroed inside the traced program) lifts the gate: such plans compile,
+    and the result is bit-identical to the exact reference on the
+    eps-masked Y and agrees with both eager paths under the same eps."""
     xd, yd = _mixed_ragged_operands(seed=4)
     x = _coo_of(xd)
     eng = DynasparseEngine(tile_m=32, tile_n=24, literal=True, eps=eps)
@@ -129,11 +146,85 @@ def test_eps_spmm_compiles_bit_identically(eps):
                        batched=True, eps=eps)
     z_p = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
                        batched=False, eps=eps)
-    np.testing.assert_array_equal(np.asarray(z_c), np.asarray(z_b))
-    np.testing.assert_array_equal(np.asarray(z_c), np.asarray(z_p))
+    _assert_matches(np.asarray(z_c),
+                    stripe_walk_reference(plan, xd, yd, eps=eps),
+                    np.asarray(z_b), np.asarray(z_p))
     if eps <= 1e-6:     # tolerance below the operands' magnitude floor:
         np.testing.assert_allclose(np.asarray(z_c), xd @ yd,   # == dense
                                    rtol=1e-4, atol=1e-4)
+
+
+def _all_spmm(plan):
+    """``plan`` with every task sent to the sparse queue as SpMM."""
+    return dataclasses.replace(
+        plan, dtq=[],
+        stq=[dataclasses.replace(t, primitive="SpMM", queue="STQ")
+             for t in plan.stq + plan.dtq])
+
+
+def test_spmm_stripe_walk_steps_at_co_l1_agg_geometry():
+    """GIN's ``l1-agg`` on CO: the adjacency times eight stacked requests of
+    1,433 features (N = 11,464) in 384 x 1536 tiles, every task on SpMM.
+    The compiled section walks one grid step per (stored block, column
+    stripe) — 5,499 x 8 — where the 8-wide pairing took one per (stored
+    block, 8x8 Y block), 5,499 x 1,433.  Descriptors only: no kernel runs."""
+    g = load_graph("CO")
+    M = g.adj.shape[0]
+    N = 8 * g.stats.features
+    tm, tn = choose_tile(M, N)
+    assert (M, N, tm, tn) == (2708, 11464, 384, 1536)
+    part = make_tasks("l1-agg", M, M, N, np.ones(-(-M // tm)),
+                      np.ones(-(-N // tn)), tm, tn)
+    stq = [dataclasses.replace(t, primitive="SpMM", queue="STQ")
+           for t in part.tasks]
+    rows, cols, vals = (np.asarray(v)
+                        for v in (g.adj.rows, g.adj.cols, g.adj.vals))
+    stripes = {}
+    for i in range(part.n_row_tiles):
+        sel = (rows >= i * tm) & (rows < (i + 1) * tm)
+        stripes[i] = pack_blockcsr_coo((part.row_extent(i), M),
+                                       rows[sel] - i * tm, cols[sel],
+                                       vals[sel], 8)
+    d = dispatch_mod.build_dispatch(part, stq, [], stripes, block=8)
+    nnzb = sum(s.nnzb for s in stripes.values())
+    assert nnzb == 5499
+    assert d.geom.has_spmm and not d.geom.has_spdmm
+    assert d.n_spmm_steps == d.sparse_steps == nnzb * part.n_col_tiles
+    assert d.n_spmm_steps == 43_992
+    y_block_cols = sum(-(-part.col_extent(j) // 8)
+                       for j in range(part.n_col_tiles))
+    assert nnzb * y_block_cols == 7_880_067      # the 8-wide pairing's steps
+
+
+def test_eps_mask_drops_sub_eps_y_blocks_in_stripe_walk():
+    """eps != 0 on stripes 32 wide, every task SpMM: Y blocks whose
+    magnitudes are all <= eps are zeroed before the stripe walk, as the
+    eager pack drops them.  Bitwise the exact reference on the masked Y,
+    the eager SpMM within float32 rounding, and measurably not the product
+    with the unmasked Y."""
+    eps = 0.2
+    rng = np.random.default_rng(12)
+    M, K, N = 48, 40, 64
+    xd = (rng.normal(size=(M, K)) *
+          (rng.uniform(size=(M, K)) < 0.3)).astype(np.float32)
+    yd = rng.normal(size=(K, N)).astype(np.float32)
+    yd[:, 16:24] *= rng.uniform(size=(K, 1)) < 0.5
+    yd[8:16, 32:40] = 0.15               # nonzero blocks under eps
+    yd[24:32, 8:16] = -0.1
+    x = _coo_of(xd)
+    eng = DynasparseEngine(tile_m=16, tile_n=32, literal=True, eps=eps)
+    plan = _all_spmm(eng.plan(x, jnp.asarray(yd)))
+    z_c = np.asarray(eng.execute(plan, x, jnp.asarray(yd)))
+    assert eng.cache.stats.dispatch_builds == 1
+    np.testing.assert_array_equal(
+        z_c, stripe_walk_reference(plan, xd, yd, eps=eps))
+    z_b = np.asarray(execute_plan(plan.part, plan.stq, [], xd, yd,
+                                  batched=True, eps=eps))
+    np.testing.assert_allclose(z_c, z_b, rtol=1e-4, atol=1e-4)
+    xm = eps_masked(xd, eps)
+    np.testing.assert_allclose(z_c, xm @ eps_masked(yd, eps),
+                               rtol=1e-4, atol=1e-4)
+    assert np.abs(z_c - xm @ yd).max() > 1e-2
 
 
 def test_misaligned_geometry_declines_compiled_but_matches():

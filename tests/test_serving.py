@@ -367,6 +367,12 @@ def test_compiled_serving_steady_state_stats_and_results():
     assert ds["trace_cache_hits"] > 0
     # every compiled batch after the first reused the whole-model trace
     assert ds["trace_cache_hits"] >= srv.stats.compiled_batches - 1
+    # the sparse sections' grid steps of the program's adjacency kernels
+    assert ds["sparse_steps"] == sum(
+        int(p["arrays"][k].shape[0])
+        for cm in srv._compiled.values() for p in cm.payload
+        if p and "xd" in p
+        for k in ("sp_a_ids", "mm_a_ids") if k in p["arrays"]) > 0
     for h, z in zip(batches, outs):
         ref = gnn.run_reference("GCN", adj, jnp.asarray(h), params)
         np.testing.assert_allclose(np.asarray(z), np.asarray(ref),

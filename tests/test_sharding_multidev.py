@@ -146,6 +146,7 @@ _GNN_SHARD_SCRIPT = textwrap.dedent("""
     from repro.core.primitives import SparseCOO
     from repro.launch.mesh import make_data_mesh
     from repro.serving.cache import SharedPlanCache
+    from spmm_reference import stripe_walk_reference
 
     MESHES = {nd: make_data_mesh(nd) for nd in (1, 4, 8)}
 
@@ -164,7 +165,8 @@ _GNN_SHARD_SCRIPT = textwrap.dedent("""
             y = np.where(r.random((n, w)) < zero_frac, 0.0, y)
         return y.astype(np.float32)
 
-    out = {"cases": 0, "exec_mismatch": 0, "mesh1_mismatch": 0,
+    out = {"cases": 0, "exec_mismatch": 0, "exec_spmm_far": 0,
+           "mesh1_mismatch": 0,
            "invariant_mismatch": 0, "saw_mixed": 0, "saw_spmm": 0,
            "saw_nondivisible": 0, "saw_ragged": 0,
            "halo_mismatch": 0, "saw_halo_exchange": 0, "saw_empty_halo": 0,
@@ -222,15 +224,21 @@ _GNN_SHARD_SCRIPT = textwrap.dedent("""
                     out["halo_mismatch"] += 1
             # core property: the sharded compiled executor is bit-identical
             # to the single-device EAGER executor on the SAME placed plan
+            # (its SpMM tasks as the compiled stripe walk: spmm_reference)
             key, entry = eng._packed_structure(plan, adj)
             xd = (eng._ensure_dense(key, entry, adj)
                   if plan.dtq else None)
-            z_e = np.asarray(_scheduler.execute_plan(
+            z_e = stripe_walk_reference(
+                plan, xd, y, eps=eps, block=eng.block,
+                interpret=eng.interpret, packed=entry.stripes)
+            if not (z == z_e).all():
+                out["exec_mismatch"] += 1
+            z_s = np.asarray(_scheduler.execute_plan(
                 plan.part, plan.stq, plan.dtq, xd, y, block=eng.block,
                 interpret=eng.interpret, batched=True,
                 packed=entry.stripes, eps=eps))
-            if not (z == z_e).all():
-                out["exec_mismatch"] += 1
+            if not np.allclose(z, z_s, rtol=1e-4, atol=1e-4):
+                out["exec_spmm_far"] += 1
             if nd == 1 and not (z == z_ref).all():
                 out["mesh1_mismatch"] += 1
             if invariant and not (z == z_ref).all():
@@ -272,6 +280,37 @@ _GNN_SHARD_SCRIPT = textwrap.dedent("""
 
     check(64, 8, 8, 8, 0, "sparse_only", "greedy", 0.0, 0.0, 42,
           adj=diag_graph(64, 8, 42), oracle=True, diag=True)
+
+    # the compiled SpMM stripe walk across a mesh: every task forced to
+    # SpMM on stripes 32 wide, where one wide dot rounds unlike four 8-wide
+    # ones; halo-sharded == the single-device compiled result, bitwise
+    import dataclasses as _dc
+
+    def all_spmm(plan):
+        return _dc.replace(plan, dtq=[], stq=[
+            _dc.replace(t, primitive="SpMM", queue="STQ")
+            for t in plan.stq + plan.dtq])
+
+    out["stripe_cases"] = 0
+    out["stripe_mismatch"] = 0
+    out["stripe_halo_exchange"] = 0
+    adj_s = graph(100, 900, 91)
+    y_s = dense_y(100, 64, 91, 0.5)
+    for eps in (0.0, 0.5):
+        one = DynasparseEngine(tile_m=16, tile_n=32, literal=True, eps=eps)
+        plan1 = all_spmm(one.plan(adj_s, y_s))
+        z_1 = np.asarray(one.execute(plan1, adj_s, y_s))
+        assert one.dispatch_for(plan1, adj_s).n_spmm_steps > 0
+        for nd in (4, 8):
+            eng = DynasparseEngine(tile_m=16, tile_n=32, literal=True,
+                                   eps=eps, mesh=MESHES[nd])
+            plan = all_spmm(eng.plan(adj_s, y_s))
+            z = np.asarray(eng.execute(plan, adj_s, y_s))
+            sd = eng.sharded_dispatch_for(plan, adj_s)
+            assert sd.geom.has_spmm and not sd.geom.has_spdmm
+            out["stripe_halo_exchange"] += int(sd.halo.max_take > 0)
+            out["stripe_cases"] += 1
+            out["stripe_mismatch"] += int(not (z == z_1).all())
 
     # heterogeneous per-device cost models: a 2x slower device must get a
     # SMALLER row-band than under the homogeneous default, and the result
@@ -344,9 +383,10 @@ _GNN_SHARD_SCRIPT = textwrap.dedent("""
 @pytest.fixture(scope="module")
 def gnn_shard_results(tmp_path_factory):
     snap = str(tmp_path_factory.mktemp("shard_snap") / "snapshot.pkl")
+    here = os.path.dirname(os.path.abspath(__file__))
     env = dict(os.environ,
-               PYTHONPATH=os.path.abspath(
-                   os.path.join(os.path.dirname(__file__), "..", "src")),
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(here, "..", "src"), here]),
                SHARD_SNAP_PATH=snap)
     proc = subprocess.run([sys.executable, "-c", _GNN_SHARD_SCRIPT], env=env,
                           capture_output=True, text=True, timeout=900)
@@ -358,10 +398,24 @@ def gnn_shard_results(tmp_path_factory):
 
 def test_sharded_executor_bit_identity(gnn_shard_results):
     """Sharded compiled execute == single-device eager execute of the SAME
-    placed plan, bitwise, on meshes of 1/4/8 forced host devices."""
+    placed plan, bitwise, on meshes of 1/4/8 forced host devices (SpMM
+    tasks as the stripe walk the compiled path lowers them to), and the
+    structure-intersecting eager SpMM within float32 rounding."""
     r, _ = gnn_shard_results
     assert r["cases"] >= 8
     assert r["exec_mismatch"] == 0
+    assert r["exec_spmm_far"] == 0
+
+
+def test_halo_spmm_stripe_walk_matches_single_device(gnn_shard_results):
+    """The compiled SpMM section, halo-sharded over 4 and 8 devices, is
+    bit-identical to the single-device compiled result on stripes wide
+    enough for the stripe walk's rounding to differ from the eager SpMM,
+    with eps 0 and 0.5, and the halo exchange really moved blocks."""
+    r, _ = gnn_shard_results
+    assert r["stripe_cases"] == 4
+    assert r["stripe_mismatch"] == 0
+    assert r["stripe_halo_exchange"] > 0
 
 
 def test_mesh_size_one_is_degenerate_case(gnn_shard_results):

@@ -116,6 +116,25 @@ def test_spmm_fused_compiles(one_chip):
                     ((339 * 7 + 1, B, B), F32), *ids, ((8 * SM_ADJ, 56), F32))
 
 
+def test_spmm_stripe_walk_compiles(one_chip):
+    """GIN's l1-agg as the compiled SpMM section lowers it: the stripe walk
+    over 8 column stripes 1,536 wide (eight stacked requests of 1,433
+    features), one step per stored block and stripe, in two launches under
+    its own name."""
+    n = ADJ_BLOCKS * 8
+    width = 8 * 1536
+
+    def f(pool, y, a, r, o, c, first, z):
+        return ops.spdmm_fused(pool, y, a, r, o, c, first, block_size=B,
+                               bn=1536, m_pad=8 * SM_ADJ, interpret=False,
+                               z=z, name="spmm_stripe")
+    ids = [((n,), I32)] * 5
+    compiled = _compile_kernel(one_chip, f, ((ADJ_BLOCKS, B, B), F32),
+                               ((2712, width), F32), *ids,
+                               ((8 * SM_ADJ, width), F32))
+    assert compiled.as_text().count("spmm_stripe") >= 2
+
+
 @pytest.mark.parametrize("tiles,sm,k,n", [
     (8, SM_ACT, K_FEAT, 16),   # l1-update routed to the dense queue
     (4, SM_ACT, 16, 8),        # l2-update's dense half
