@@ -47,6 +47,13 @@ from repro.kernels.formats import pack_blockcsr_coo
 
 Mode = Literal["dynamic", "sparse_only", "dense_only"]
 
+# HBM bytes of one stored block of a block-skip pool on a TPU: the (B, B)
+# float32 block, B = 8, sits in one (8, 128) tile, 16x the bytes it holds.
+# The route holds two such arrays at once (the packed payload and the pool
+# a kernel reads): compiled for a v5e, GCN-CO's 506,880 capacity slots take
+# 4.16 GB of temporaries.
+_POOL_BYTES_PER_BLOCK = 2 * 8 * 128 * 4
+
 
 @dataclasses.dataclass
 class EngineReport:
@@ -231,6 +238,18 @@ class DynasparseEngine:
                     interpret=self.interpret)
             self._hw_runtime = hw
         return self._hw_runtime
+
+    def device(self):
+        """The device an unsharded kernel runs on."""
+        return (jax.devices()[0] if self.mesh is None
+                else self.mesh.devices.flat[0])
+
+    def block_pool_fits(self, n_blocks: int) -> bool:
+        """Whether a block-skip pool of ``n_blocks`` stored blocks fits in
+        half of the device's memory (``_POOL_BYTES_PER_BLOCK`` each).  A
+        device that reports no limit (the CPU) takes any pool."""
+        limit = (self.device().memory_stats() or {}).get("bytes_limit")
+        return not limit or n_blocks * _POOL_BYTES_PER_BLOCK <= limit // 2
 
     def _geometry(self, M: int, N: int) -> tuple[int, int]:
         tm, tn = self.tile_m, self.tile_n
@@ -442,7 +461,8 @@ class DynasparseEngine:
         the kernel should stay dense: non-literal/non-batched engines,
         sparse X (that is :meth:`dispatch_for`'s job), plans whose Analyzer
         routed every task to the dense engine (dense wins — a plain GEMM is
-        the whole kernel), or canvas-misaligned geometry.
+        the whole kernel), canvas-misaligned geometry, or a budget whose
+        pool does not fit the device (:meth:`block_pool_fits`).
 
         ``capacity`` fixes the stored-block budget (an int for a uniform
         budget, or a per-stripe vector); by default it is measured from
@@ -469,6 +489,10 @@ class DynasparseEngine:
                 return None
         cap_key = (tuple(int(c) for c in np.asarray(capacity).ravel())
                    if np.ndim(capacity) else int(capacity))
+        slots = (sum(cap_key) if isinstance(cap_key, tuple)
+                 else cap_key * plan.part.n_row_tiles)
+        if not self.block_pool_fits(slots):
+            return None
         digest = _dispatch.plan_digest(plan, self.block)
         return self.cache.activation_dispatch(
             (digest, cap_key, self.eps),
@@ -538,6 +562,14 @@ class DynasparseEngine:
                 d, xd = pair
                 return _dispatch.execute_dispatch(
                     d, xd, y, interpret=interpret, stats=self.cache.stats)
+            if not isinstance(x, SparseCOO) and plan.stq and (
+                    not self.block_pool_fits(
+                        -(-plan.part.M // self.block)
+                        * -(-plan.part.K // self.block))):
+                # a dense operand whose blocks would not fit as a pool runs
+                # as the one dense GEMM its compiled program runs
+                return _ops.gemm(jnp.asarray(x), y, interpret=interpret,
+                                 out_dtype=jnp.float32)
             packed = None
             if isinstance(x, SparseCOO):
                 if plan.struct_key is not None:

@@ -1,4 +1,4 @@
-"""GNN model zoo of the paper: GCN, GraphSAGE(mean), GIN, SGC.
+"""GNN model zoo of the paper: GCN, GraphSAGE, GIN, SGC.
 
 Every model is expressed against an abstract matmul ``mm(x, y, name)`` so the
 same definition runs (a) through the DynasparseEngine (paper's accelerator),
@@ -7,9 +7,10 @@ hidden 16 for CO/CI/PU, 128 for FL/NE/RE.
 
 Kernel ordering follows Dynasparse: aggregation ``Â·X`` and transformation
 ``X·W`` are separate kernels; for GCN/SGC/SAGE we use the FLOPs-optimal
-association (transform-first when in_dim > out_dim) — GIN's ``(1+ε)h + Â·h``
-pins aggregation to the raw features, which is why GIN keeps a higher
-aggregation cost (visible in Table VI).
+association (transform-first when in_dim > out_dim; a transform-first SAGE
+layer runs its root and neighbour transforms as one kernel) — GIN's
+``(1+ε)h + Â·h`` pins aggregation to the raw features, which is why GIN
+keeps a higher aggregation cost (visible in Table VI).
 """
 from __future__ import annotations
 
@@ -77,13 +78,36 @@ def gcn_apply(mm: MM, adj, h, p) -> jax.Array:
     return _transform_then_aggregate(mm, adj, z, p["W2"], "l2")
 
 
+def _sage_layer(mm: MM, adj, h, w_self, w_neigh, tag: str):
+    """One GraphSAGE layer, ``W · CONCAT(h_v, AGG(h_N(v)))`` (Hamilton et
+    al., arXiv:1706.02216, Alg. 1), which by the linearity of aggregation is
+    ``h·W_self + Â·(h·W_neigh)``.
+
+    Where the transform goes first, both halves are ONE transform
+    ``h·[W_self | W_neigh]`` whose neighbour half is then aggregated: the
+    input is read, laid out and packed once.  Each request's ``2d``-wide
+    column block of the (possibly stacked) output is ``[self | neigh]``, so
+    the split is a reshape to ``(N, k, 2, d)``; it and the sum run under the
+    transform's name scope.  Where the aggregation goes first, the root
+    transform stays a kernel of its own (``-self``)."""
+    in_dim, out_dim = w_neigh.shape
+    if in_dim < out_dim:
+        return (mm(h, w_self, name=f"{tag}-self")
+                + _transform_then_aggregate(mm, adj, h, w_neigh, tag))
+    name = f"{tag}-update"
+    z = mm(h, jnp.concatenate([w_self, w_neigh], axis=1), name=name)
+    with jax.named_scope(name):
+        z = z.reshape(z.shape[0], -1, 2, out_dim)
+        z_self = z[:, :, 0].reshape(z.shape[0], -1)
+        z_neigh = z[:, :, 1].reshape(z.shape[0], -1)
+    z_agg = mm(adj, z_neigh, name=f"{tag}-agg")
+    with jax.named_scope(name):
+        return z_self + z_agg
+
+
 def sage_apply(mm: MM, adj, h, p) -> jax.Array:
-    z_self = mm(h, p["Ws1"], name="l1-self")
-    z_neigh = _transform_then_aggregate(mm, adj, h, p["Wn1"], "l1")
-    z = jax.nn.relu(z_self + z_neigh)
-    z2 = mm(z, p["Ws2"], name="l2-self") + _transform_then_aggregate(
-        mm, adj, z, p["Wn2"], "l2")
-    return z2
+    z = jax.nn.relu(_sage_layer(mm, adj, h, p["Ws1"], p["Wn1"], "l1"))
+    return _sage_layer(mm, adj, z, p["Ws2"], p["Wn2"], "l2")
 
 
 def gin_apply(mm: MM, adj, h, p, eps: float = 0.0) -> jax.Array:
@@ -399,4 +423,7 @@ def run_serving(model: str, engine: DynasparseEngine, adj, feature_batches,
 
 
 def run_reference(model: str, adj, h, params):
-    return APPLY[model](reference_mm, adj, h, params)
+    """The plain float32 ``jax.numpy`` oracle, every product at
+    ``Precision.HIGHEST`` (a TPU's default is bfloat16 passes)."""
+    with jax.default_matmul_precision("highest"):
+        return APPLY[model](reference_mm, adj, h, params)

@@ -482,3 +482,59 @@ def test_vectorized_pack_blockcsr_matches_loop(seed):
     for f in ("row_ids", "col_ids", "first", "blocks"):
         np.testing.assert_array_equal(np.asarray(getattr(got, f)),
                                       np.asarray(getattr(ref, f)))
+
+
+class _Device:
+    """A device reporting ``bytes_limit`` of memory."""
+
+    def __init__(self, limit):
+        self.limit = limit
+
+    def memory_stats(self):
+        return {"bytes_limit": self.limit}
+
+
+def test_block_pool_budget_is_half_the_device():
+    eng = DynasparseEngine(tile_m=16, tile_n=8, literal=True)
+    assert eng.block_pool_fits(10**9)        # the CPU reports no limit
+    eng.device = lambda: _Device(16 * 2**30)
+    # GCN-CO's dense features (506,880 capacity slots) fit a 16 GiB chip;
+    # FL's 5.63M slots of l1-update, or 1.43M of l2-update, do not
+    assert eng.block_pool_fits(506_880)
+    assert not eng.block_pool_fits(1_428_000)
+    assert not eng.block_pool_fits(5_628_672)
+
+
+def test_pool_past_the_device_runs_as_one_dense_gemm(monkeypatch):
+    """A dense operand whose block pool would not fit the device takes the
+    dense GEMM route, in the eager pass and in the compiled program, and
+    still matches the reference."""
+    from repro.core import scheduler
+    rng = np.random.default_rng(41)
+    adj = _block_sparse_graph(rng)
+    h = _block_sparse(rng, 80, 12, 0.35)
+    params = gnn.init_params("GCN", 12, 8, 5)
+    ref = gnn.run_reference("GCN", adj, jnp.asarray(h), params)
+    eng = DynasparseEngine(tile_m=16, tile_n=8, literal=True)
+    _, cm = gnn.compile_model("GCN", eng, adj, jnp.asarray(h), params)
+    act = [(name, r.n_stq) for (name, r), p in zip(cm.report.kernels,
+                                                   cm.payload)
+           if p is not None and "xd" not in p]
+    assert act and all(n_stq for _, n_stq in act)
+    small = DynasparseEngine(tile_m=16, tile_n=8, literal=True)
+    small.device = lambda: _Device(2)
+    plain = scheduler.execute_plan
+
+    def dense_only(part, stq, dtq, *args, **kw):
+        assert not stq, "the eager pass packed a pool that cannot fit"
+        return plain(part, stq, dtq, *args, **kw)
+    monkeypatch.setattr(scheduler, "execute_plan", dense_only)
+    warm, cm = gnn.compile_model("GCN", small, adj, jnp.asarray(h), params)
+    assert cm is not None and cm.n_act == 0
+    # the same plans, with their sparse tasks, now lowered as dense GEMMs
+    assert [(name, r.n_stq) for (name, r), p in zip(cm.report.kernels,
+                                                    cm.payload)
+            if p is None and r.n_stq] == act
+    for z in (warm, cm(jnp.asarray(h))):
+        np.testing.assert_allclose(np.asarray(z), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
