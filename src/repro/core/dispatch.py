@@ -40,6 +40,13 @@ Semantics vs the eager batched path (`scheduler._execute_batched`):
   executor zeroes the Y blocks whose magnitudes are all ``<= eps`` on device
   before the SpMM section (the SpDMM section reads the unmasked operand,
   hence its own launch) — eps-thresholded SpMM plans compile like any other.
+- A sparse section whose tasks cover every column tile of each row stripe
+  they touch WALKS WHOLE ROWS (:func:`walks_whole_rows`): one grid step per
+  stored block over the full ``nct * SN``-wide padded output row instead of
+  one per (stored block, column tile).  Each output column still sums the
+  same products in the same A-block order; the choice is static in the
+  geometry (``sp_rows`` / ``mm_rows``) and the eager batched and
+  halo-sharded paths make the same one, so they stay bit-identical.
 
 Activation-side kernels (dense X — the intermediate feature matrices) get the
 same treatment through :class:`ActivationDispatch`: the descriptor arrays are
@@ -105,6 +112,10 @@ class DispatchGeometry:
     # nonzero tolerance applied to the dense operand's blocks inside the
     # traced SpMM (sub-eps Y blocks are zeroed on device — see module doc)
     eps: float = 0.0
+    # the SpDMM / SpMM section walks whole padded output rows
+    # (:func:`walks_whole_rows`) instead of one column stripe at a time
+    sp_rows: bool = False
+    mm_rows: bool = False
 
     @property
     def m_pad(self) -> int:
@@ -155,6 +166,11 @@ class CompiledDispatch:
     def sparse_steps(self) -> int:
         """Grid steps of both sparse sections."""
         return self.n_entries + self.n_spmm_steps
+
+    @property
+    def row_walk_sections(self) -> int:
+        """Sparse sections lowered as whole-row walks."""
+        return int(self.geom.sp_rows) + int(self.geom.mm_rows)
 
 
 def plan_digest(plan, block: int) -> str:
@@ -213,8 +229,25 @@ def _stripe_pool(tasks, stripes) -> tuple[dict[int, int], jax.Array]:
 ENTRY_FIELDS = ("a_ids", "y_rows", "out_rows", "out_cols", "first")
 
 
+def walks_whole_rows(tasks, nct: int, SN: int, B: int) -> bool:
+    """True when a sparse section's tasks can run as a whole-row walk: one
+    grid step per stored block over the full ``nct * SN``-wide padded output
+    row rather than one per (stored block, column tile).  It takes more than
+    one column tile, at least one task, tasks that cover every column tile
+    of each row stripe they touch (so no other section writes those rows),
+    and a row narrow enough for its blocks to sit in VMEM
+    (``ops.MAX_ROW_BLOCK_BYTES``)."""
+    if nct < 2 or B * nct * SN * 4 > ops.MAX_ROW_BLOCK_BYTES:
+        return False
+    cover: dict[int, set[int]] = {}
+    for t in tasks:
+        cover.setdefault(t.i, set()).add(t.j)
+    return bool(cover) and all(len(js) == nct for js in cover.values())
+
+
 def spdmm_entry_arrays(tasks, stripes: dict[int, "BlockCSR"],
-                       offsets: dict[int, int], R: int):
+                       offsets: dict[int, int], R: int, *,
+                       whole_rows: bool = False):
     """Vectorized fused-SpDMM entry list over all tasks of one kernel.
 
     Returns ``(a_ids, y_rows, out_rows, out_cols, first)`` sorted by output
@@ -222,16 +255,20 @@ def spdmm_entry_arrays(tasks, stripes: dict[int, "BlockCSR"],
     the per-block Python loop it replaces (the stripes' own ``first`` flags
     are carried through the sort: within one output block's run the entries
     are one stripe's one block-row in stored order, whose first stored block
-    is flagged 1).
+    is flagged 1).  With ``whole_rows`` (:func:`walks_whole_rows`) each
+    stored block of a touched stripe gets ONE entry with ``out_cols`` 0,
+    for a launch whose ``bn`` spans the whole padded row.
     """
+    units = ([(i, 0) for i in dict.fromkeys(t.i for t in tasks)]
+             if whole_rows else [(t.i, t.j) for t in tasks])
     out_rows, out_cols, a_ids, y_rows, firsts = [], [], [], [], []
-    for task in tasks:
-        s = stripes[task.i]
+    for i, j in units:
+        s = stripes[i]
         nb = s.nnzb
         rid = np.asarray(s.row_ids)[:nb]
-        out_rows.append(task.i * R + rid.astype(np.int64))
-        out_cols.append(np.full(nb, task.j, dtype=np.int64))
-        a_ids.append(offsets[task.i] + np.arange(nb, dtype=np.int64))
+        out_rows.append(i * R + rid.astype(np.int64))
+        out_cols.append(np.full(nb, j, dtype=np.int64))
+        a_ids.append(offsets[i] + np.arange(nb, dtype=np.int64))
         y_rows.append(np.asarray(s.col_ids)[:nb].astype(np.int64))
         firsts.append(np.asarray(s.first)[:nb].astype(np.int64))
     out_rows = np.concatenate(out_rows)
@@ -267,13 +304,17 @@ def build_dispatch(part, stq, dtq, stripes: dict[int, "BlockCSR"],
     SM, SN = slots
     B = block
     R = SM // B
+    nct = part.n_col_tiles
+    sections = {"sp": [t for t in stq if t.primitive != "SpMM"],
+                "mm": [t for t in stq if t.primitive == "SpMM"]}
+    rows = {p: walks_whole_rows(tasks, nct, SN, B)
+            for p, tasks in sections.items()}
     geom = DispatchGeometry(
         M=part.M, K=part.K, N=part.N, tm=part.tile_m, tn=part.tile_n,
-        SM=SM, SN=SN, B=B, nrt=part.n_row_tiles, nct=part.n_col_tiles,
+        SM=SM, SN=SN, B=B, nrt=part.n_row_tiles, nct=nct,
         has_gemm=bool(dtq),
-        has_spdmm=any(t.primitive != "SpMM" for t in stq),
-        has_spmm=any(t.primitive == "SpMM" for t in stq),
-        eps=eps)
+        has_spdmm=bool(sections["sp"]), has_spmm=bool(sections["mm"]),
+        eps=eps, sp_rows=rows["sp"], mm_rows=rows["mm"])
     arrays: dict[str, jax.Array] = {}
 
     if dtq:
@@ -284,14 +325,13 @@ def build_dispatch(part, stq, dtq, stripes: dict[int, "BlockCSR"],
 
     # both sparse sections in the SpDMM entry format: the SpMM section is
     # the stripe walk (module docstring), a launch of its own
-    for prefix, tasks in (("sp", [t for t in stq if t.primitive != "SpMM"]),
-                          ("mm", [t for t in stq if t.primitive == "SpMM"])):
+    for prefix, tasks in sections.items():
         if not tasks:
             continue
         offsets, pool = _stripe_pool(tasks, stripes)
         arrays[f"{prefix}_pool"] = pool
-        for name, v in zip(ENTRY_FIELDS,
-                           spdmm_entry_arrays(tasks, stripes, offsets, R)):
+        for name, v in zip(ENTRY_FIELDS, spdmm_entry_arrays(
+                tasks, stripes, offsets, R, whole_rows=rows[prefix])):
             arrays[f"{prefix}_{name}"] = jnp.asarray(v)
 
     return CompiledDispatch(geom=geom, arrays=arrays, fingerprint=fingerprint)
@@ -385,17 +425,20 @@ def apply_prepared(geom: DispatchGeometry, arrays, x, y_f, y_p,
     if geom.has_gemm:
         z = _gemm_scatter_panel(geom, arrays, x, y_p, z, interpret=interpret)
 
+    # a whole-row section runs as one stripe spanning the padded row
     if geom.has_spdmm:
         z = ops.spdmm_fused(
             arrays["sp_pool"], y_f, arrays["sp_a_ids"], arrays["sp_y_rows"],
             arrays["sp_out_rows"], arrays["sp_out_cols"], arrays["sp_first"],
-            block_size=B, bn=SN, m_pad=M_pad, interpret=interpret, z=z)
+            block_size=B, bn=N_pad if geom.sp_rows else SN, m_pad=M_pad,
+            interpret=interpret, z=z)
 
     if geom.has_spmm:
         z = ops.spdmm_fused(
             arrays["mm_pool"], _eps_masked_y(geom, y_f), arrays["mm_a_ids"],
             arrays["mm_y_rows"], arrays["mm_out_rows"], arrays["mm_out_cols"],
-            arrays["mm_first"], block_size=B, bn=SN, m_pad=M_pad,
+            arrays["mm_first"], block_size=B,
+            bn=N_pad if geom.mm_rows else SN, m_pad=M_pad,
             interpret=interpret, z=z, name="spmm_stripe")
 
     return z[:geom.M, :geom.N]

@@ -413,15 +413,6 @@ class PlanCache:
                 * int(getattr(value, "n_devices", 1)))
         return out
 
-    def sparse_steps(self) -> int:
-        """Grid steps of the sparse sections (SpDMM entries and the SpMM
-        stripe walk) summed over every cached compiled dispatch, sharded
-        ones counted per device.  Surfaced by
-        ``ServingEngine.dispatch_stats()``."""
-        return sum(value.sparse_steps
-                   for (kind, _k), (value, _nb) in list(self._entries.items())
-                   if kind in (self._DISPATCH, self._SHARD))
-
     def activation_dispatch(self, key: tuple, compute: Callable[[], object]):
         """Get-or-compute an
         :class:`~repro.core.dispatch.ActivationDispatch`.  Keyed on (plan

@@ -348,11 +348,15 @@ def _execute_batched(part, stq, dtq, x, y, *, block, interpret, packed=None,
                       ((0, 0), (0, 0), (0, SN - tn))
                       ).reshape(ncb * B, nct * SN)
         offsets, a_pool = _dispatch._stripe_pool(spdmm_tasks, stripes)
+        # the compiled dispatch's choice of walk, so the two stay bitwise
+        rows = _dispatch.walks_whole_rows(spdmm_tasks, nct, SN, B)
         a_ids, y_rows, out_rows, out_cols, first = \
-            _dispatch.spdmm_entry_arrays(spdmm_tasks, stripes, offsets, R)
+            _dispatch.spdmm_entry_arrays(spdmm_tasks, stripes, offsets, R,
+                                         whole_rows=rows)
         z = ops.spdmm_fused(
             a_pool, y_f, a_ids, y_rows, out_rows, out_cols, first,
-            block_size=B, bn=SN, m_pad=M_pad, interpret=interpret, z=z)
+            block_size=B, bn=N_pad if rows else SN, m_pad=M_pad,
+            interpret=interpret, z=z)
 
     # ---------------- STQ / SpMM: one fused triple list
     if spmm_tasks:

@@ -114,6 +114,11 @@ class ShardedDispatch:
         return sum(int(self.arrays[k].shape[-1])
                    for k in ("sp_a_ids", "mm_a_ids") if k in self.arrays)
 
+    @property
+    def row_walk_sections(self) -> int:
+        """Sparse sections lowered as whole-row walks."""
+        return int(self.geom.sp_rows) + int(self.geom.mm_rows)
+
 
 def _pool_dtype(stripes):
     for s in stripes.values():
@@ -208,35 +213,28 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
         sum(part.row_extent(i) for i in placement.stripes_of(d))
         for d in range(nd))
 
+    sp_all = [t for t in stq if t.primitive != "SpMM"]
+    mm_all = [t for t in stq if t.primitive == "SpMM"]
+    # one geometry serves every shard: decided on the whole section, the
+    # whole-row walk holds in every band (each stripe lies in one band)
+    sp_rows = _dispatch.walks_whole_rows(sp_all, part.n_col_tiles, SN, B)
+    mm_rows = _dispatch.walks_whole_rows(mm_all, part.n_col_tiles, SN, B)
+
     per_gemm, per_spdmm, per_spmm = [], [], []
     for d in range(nd):
         lo = bs[d]
         local_stripes = {i - lo: stripes[i] for i in placement.stripes_of(d)
                          if i in stripes}
-        g = _band_tasks(dtq, placement, d)
-        sp = _band_tasks([t for t in stq if t.primitive != "SpMM"],
-                         placement, d)
-        mm = _band_tasks([t for t in stq if t.primitive == "SpMM"],
-                         placement, d)
-        per_gemm.append(g)
-
-        if sp:
-            offsets, pool = _dispatch._stripe_pool(sp, local_stripes)
-            per_spdmm.append((np.asarray(pool),
-                              _dispatch.spdmm_entry_arrays(
-                                  sp, local_stripes, offsets, R)))
-        else:
-            per_spdmm.append((np.zeros((0, B, B), _pool_dtype(stripes)),
-                              None))
-
-        if mm:
-            offsets, pool = _dispatch._stripe_pool(mm, local_stripes)
-            per_spmm.append((np.asarray(pool),
-                             _dispatch.spdmm_entry_arrays(
-                                 mm, local_stripes, offsets, R)))
-        else:
-            per_spmm.append((np.zeros((0, B, B), _pool_dtype(stripes)),
-                             None))
+        per_gemm.append(_band_tasks(dtq, placement, d))
+        for tasks, rows, per in ((sp_all, sp_rows, per_spdmm),
+                                 (mm_all, mm_rows, per_spmm)):
+            band = _band_tasks(tasks, placement, d)
+            if band:
+                offsets, pool = _dispatch._stripe_pool(band, local_stripes)
+                per.append((np.asarray(pool), _dispatch.spdmm_entry_arrays(
+                    band, local_stripes, offsets, R, whole_rows=rows)))
+            else:
+                per.append((np.zeros((0, B, B), _pool_dtype(stripes)), None))
 
     n_gemm = max((len(g) for g in per_gemm), default=0)
 
@@ -267,7 +265,7 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
         M=nrt_l * SM, K=part.K, N=part.N, tm=part.tile_m, tn=part.tile_n,
         SM=SM, SN=SN, B=B, nrt=nrt_l, nct=part.n_col_tiles,
         has_gemm=n_gemm > 0, has_spdmm=n_sp > 0, has_spmm=n_mm > 0,
-        eps=eps)
+        eps=eps, sp_rows=sp_rows, mm_rows=mm_rows)
 
     arrays: dict[str, jax.Array] = {k: put(v) for k, v in hx_arrays.items()}
 

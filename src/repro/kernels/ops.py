@@ -140,6 +140,14 @@ def spdmm(a: BlockCSR, y, *, bn: int = 128, interpret: bool | None = None,
 # see ``repro.kernels.spdmm.resume_partial``).
 MAX_ENTRIES_PER_LAUNCH = 32768
 
+# A fused sparse launch whose ``bn`` spans a whole padded output row holds
+# six (B, bn) blocks in VMEM: the Y block-row, the output block and the
+# aliased canvas block, each double-buffered.  Capping one float32 block at
+# 512 KiB (16,384 columns at B = 8) keeps the six near 3 MiB, well inside a
+# v5e core's 16 MiB of scoped VMEM; wider rows walk one column stripe at a
+# time.
+MAX_ROW_BLOCK_BYTES = 512 * 1024
+
 
 def _launch_chunks(n: int):
     """``[lo, hi)`` entry ranges of the launches a fused list of ``n``
@@ -330,4 +338,5 @@ __all__ = [
     "gemm", "gemm_batch", "gemm_batch_scatter",
     "spdmm", "spdmm_fused", "spmm", "spmm_fused", "default_interpret",
     "pallas_call_count", "reset_pallas_call_count", "MAX_ENTRIES_PER_LAUNCH",
+    "MAX_ROW_BLOCK_BYTES",
 ]

@@ -180,6 +180,10 @@ class CompiledModel:
     n_kernels: int
     n_sparse: int
     n_act: int = 0                # kernels on the capacity block-skip route
+    # grid steps a call walks in its adjacency kernels' sparse sections, and
+    # how many of those sections walk whole output rows
+    sparse_steps: int = 0
+    row_walk_sections: int = 0
     stats: object | None = None   # CacheStats receiving call accounting
     faults: object | None = None  # FaultInjector probed at "compiled"
     calls: int = 0
@@ -277,6 +281,7 @@ def compile_model(model: str, engine: DynasparseEngine, adj, h, params,
     # | ("gemm", None) per kernel
     records: list[tuple[str, object]] = []
     payload: list = []
+    lowered: list = []             # each adjacency kernel's dispatch
     compilable = [True]
     n0 = len(engine.report.kernels)
 
@@ -291,6 +296,7 @@ def compile_model(model: str, engine: DynasparseEngine, adj, h, params,
                     payload.append(None)
                 else:
                     sd, xd = spair
+                    lowered.append(sd)
                     records.append(("shard",
                                     (sd.geom, sd.band_rows, sd.halo)))
                     payload.append({"arrays": dict(sd.arrays), "xd": xd})
@@ -302,6 +308,7 @@ def compile_model(model: str, engine: DynasparseEngine, adj, h, params,
                 payload.append(None)
             else:
                 d, xd = pair
+                lowered.append(d)
                 records.append(("sparse", d.geom))
                 payload.append({"arrays": dict(d.arrays), "xd": xd})
         else:
@@ -383,6 +390,8 @@ def compile_model(model: str, engine: DynasparseEngine, adj, h, params,
         n_kernels=len(records),
         n_sparse=sum(1 for k, _ in records if k in ("sparse", "shard")),
         n_act=sum(1 for k, _ in records if k == "act"),
+        sparse_steps=sum(d.sparse_steps for d in lowered),
+        row_walk_sections=sum(d.row_walk_sections for d in lowered),
         stats=engine.cache.stats, faults=engine.faults)
 
 

@@ -406,6 +406,7 @@ class ServingEngine:
         s = self.engine.cache.stats
         st = self.stats
         n_act = len(st.activation_batches)
+        compiled = list(self._compiled.values())
         return {
             "plans": self.engine.cache.plan_count(),
             "n_devices": self.engine.n_devices,
@@ -415,8 +416,13 @@ class ServingEngine:
             # per-device dense-operand memory accounting of the sharded
             # dispatches (owned / halo / replicated-fallback bytes)
             "operand_bytes": self.engine.cache.sharded_operand_bytes(),
-            # grid steps of the compiled dispatches' sparse sections
-            "sparse_steps": self.engine.cache.sparse_steps(),
+            # grid steps a call walks in the compiled programs' sparse
+            # sections, and the sections that walk whole output rows (read
+            # from the programs: the plan cache may have evicted their
+            # dispatches)
+            "sparse_steps": sum(cm.sparse_steps for cm in compiled),
+            "row_walk_sections": sum(cm.row_walk_sections
+                                     for cm in compiled),
             "dispatch_builds": s.dispatch_builds,
             "dispatch_hits": s.dispatch_hits,
             "act_builds": s.act_builds,
@@ -426,7 +432,7 @@ class ServingEngine:
             "trace_builds": s.trace_builds,
             "trace_cache_hits": s.trace_cache_hits,
             "replans": s.replans,
-            "compiled_models": len(self._compiled),
+            "compiled_models": len(compiled),
             "compiled_batches": st.compiled_batches,
             # sparse-activation route telemetry (running aggregates)
             "act_kernels_last": st.act_kernels_last,

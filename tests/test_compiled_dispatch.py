@@ -22,6 +22,7 @@ from repro.core import dispatch as dispatch_mod
 from repro.core.partition import choose_tile, make_tasks
 from repro.core.plancache import PlanCache
 from repro.core.scheduler import execute_plan
+from repro.data import graphs
 from repro.data.graphs import load_graph
 from repro.kernels.formats import pack_blockcsr_coo
 from repro.models import gnn
@@ -165,9 +166,13 @@ def _all_spmm(plan):
 def test_spmm_stripe_walk_steps_at_co_l1_agg_geometry():
     """GIN's ``l1-agg`` on CO: the adjacency times eight stacked requests of
     1,433 features (N = 11,464) in 384 x 1536 tiles, every task on SpMM.
-    The compiled section walks one grid step per (stored block, column
-    stripe) — 5,499 x 8 — where the 8-wide pairing took one per (stored
-    block, 8x8 Y block), 5,499 x 1,433.  Descriptors only: no kernel runs."""
+    The tasks cover every column tile of every row stripe, so the compiled
+    section walks whole rows: one grid step per stored block, 5,499, each
+    over the 12,288-wide padded row.  With one task moved to the dense
+    queue its row stripe is no longer covered and the section walks one
+    step per (stored block, task column stripe), as the 5,499 x 8 walk did
+    less that task's stripe; the 8-wide pairing took one per (stored block,
+    8x8 Y block), 5,499 x 1,433.  Descriptors only: no kernel runs."""
     g = load_graph("CO")
     M = g.adj.shape[0]
     N = 8 * g.stats.features
@@ -189,11 +194,65 @@ def test_spmm_stripe_walk_steps_at_co_l1_agg_geometry():
     nnzb = sum(s.nnzb for s in stripes.values())
     assert nnzb == 5499
     assert d.geom.has_spmm and not d.geom.has_spdmm
-    assert d.n_spmm_steps == d.sparse_steps == nnzb * part.n_col_tiles
-    assert d.n_spmm_steps == 43_992
+    assert d.geom.mm_rows and not d.geom.sp_rows
+    assert d.row_walk_sections == 1
+    assert d.n_spmm_steps == d.sparse_steps == nnzb == 5_499
+    assert set(np.asarray(d.arrays["mm_out_cols"])) == {0}
+
+    moved = stq[3]
+    assert (moved.i, moved.j) == (0, 3)
+    d_split = dispatch_mod.build_dispatch(
+        part, [t for t in stq if t is not moved],
+        [dataclasses.replace(moved, primitive="GEMM", queue="DTQ")],
+        stripes, block=8)
+    assert not d_split.geom.mm_rows and d_split.row_walk_sections == 0
+    per_stripe = nnzb * part.n_col_tiles - stripes[moved.i].nnzb
+    assert d_split.n_spmm_steps == per_stripe == 43_992 - 746
     y_block_cols = sum(-(-part.col_extent(j) // 8)
                        for j in range(part.n_col_tiles))
     assert nnzb * y_block_cols == 7_880_067      # the 8-wide pairing's steps
+
+
+def test_row_walk_steps_at_fl_l1_agg_geometry():
+    """SAGE's ``l1-agg`` on FL: the adjacency of Flickr's Table IV size (the
+    benchmark's ``sage-fl`` graph: 89,250 vertices, 899,756 edges, graph
+    seed 1) times eight stacked requests of hidden width 128, in 11,264 x
+    128 tiles, every task on SpDMM.  The whole-row walk takes one grid step
+    per stored block, 900,699, where one step per (stored block, column
+    tile) took 7,205,592.  Descriptors only: no pool, no kernel."""
+    rng = np.random.default_rng(1)
+    M = 89250
+    adj = graphs._normalize_adj(M, *graphs._gen_edges(rng, M, 899756))
+    N = 8 * 128
+    tm, tn = choose_tile(M, N)
+    assert (tm, tn) == (11264, 128)
+    part = make_tasks("l1-agg", M, M, N, np.ones(-(-M // tm)),
+                      np.ones(-(-N // tn)), tm, tn)
+    assert (part.n_row_tiles, part.n_col_tiles) == (8, 8)
+    tasks = [dataclasses.replace(t, primitive="SpDMM", queue="STQ")
+             for t in part.tasks]
+    rows, cols, vals = (np.asarray(v)
+                        for v in (adj.rows, adj.cols, adj.vals))
+    starts = np.searchsorted(rows, np.arange(part.n_row_tiles + 1) * tm)
+    stripes, offsets, off = {}, {}, 0
+    for i in range(part.n_row_tiles):
+        lo, hi = starts[i], starts[i + 1]
+        stripes[i] = pack_blockcsr_coo((part.row_extent(i), M),
+                                       rows[lo:hi] - i * tm, cols[lo:hi],
+                                       vals[lo:hi], 8)
+        offsets[i], off = off, off + stripes[i].nnzb
+    assert off == 900_699
+    assert dispatch_mod.walks_whole_rows(tasks, part.n_col_tiles, tn, 8)
+    walks = {whole: dispatch_mod.spdmm_entry_arrays(
+        tasks, stripes, offsets, tm // 8, whole_rows=whole)
+        for whole in (True, False)}
+    assert len(walks[True][0]) == 900_699
+    assert len(walks[False][0]) == 7_205_592
+    # the same stored blocks, once per row rather than once per stripe
+    np.testing.assert_array_equal(
+        np.sort(walks[True][0]), np.arange(900_699))
+    np.testing.assert_array_equal(
+        np.bincount(walks[False][0], minlength=900_699), 8)
 
 
 def test_eps_mask_drops_sub_eps_y_blocks_in_stripe_walk():

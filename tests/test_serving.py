@@ -470,6 +470,49 @@ def test_graph_scale_sparse_only_serving_never_densifies():
     srv.close()
 
 
+@pytest.mark.parametrize("model,tile_n,walks,budget", [
+    ("GIN", 8, True, None), ("GCN", 64, False, None), ("GIN", 8, True, 1)])
+def test_dispatch_stats_count_whole_row_sections(model, tile_n, walks,
+                                                 budget):
+    """``row_walk_sections`` beside ``sparse_steps``: GIN's aggregations
+    over 4 x 12 stacked features in 8-wide column tiles, every task on the
+    sparse queue, walk whole rows; GCN's over 4 x 8 and 4 x 5 columns have
+    one column tile each, so none does.  Both count the compiled program's
+    own kernels, so a plan cache too small to keep their dispatches (a
+    byte budget of 1) reads the same."""
+    adj = _rand_graph(seed=35)
+    params = gnn.init_params(model, 12, 8, 5)
+    cache = SharedPlanCache(max_bytes=budget)
+    eng = DynasparseEngine(tile_m=16, tile_n=tile_n, literal=True,
+                           mode="sparse_only", cache=cache)
+    srv = ServingEngine(model, params, engine=eng,
+                        config=ServingConfig(max_batch=4))
+    srv.register_graph("g", adj)
+    batches = [RNG.normal(size=(80, 12)).astype(np.float32)
+               for _ in range(8)]
+    outs = srv.serve(("g", h) for h in batches)
+    assert srv.stats.compiled_batches >= 1
+    ds = srv.dispatch_stats()
+    assert ds["sparse_steps"] == sum(
+        int(p["arrays"][k].shape[0])
+        for cm in srv._compiled.values() for p in cm.payload
+        if p and "xd" in p
+        for k in ("sp_a_ids", "mm_a_ids") if k in p["arrays"]) > 0
+    assert (ds["row_walk_sections"] > 0) == walks
+    cached = [d for (kind, _k), d in cache.items()
+              if kind == cache._DISPATCH]
+    if budget is None:
+        assert ds["row_walk_sections"] == sum(
+            d.row_walk_sections for d in cached)
+    else:
+        assert len(cached) < 2       # the program outlives its dispatches
+    for h, z in zip(batches, outs):
+        ref = gnn.run_reference(model, adj, jnp.asarray(h), params)
+        np.testing.assert_allclose(np.asarray(z), np.asarray(ref),
+                                   rtol=1e-3, atol=1e-3)
+    srv.close()
+
+
 # ------------------------------------------------------- density drift
 def test_density_drift_triggers_replan_and_matches_reference():
     """Near-dense features swapped mid-stream: the sketch must catch the

@@ -312,6 +312,35 @@ _GNN_SHARD_SCRIPT = textwrap.dedent("""
             out["stripe_cases"] += 1
             out["stripe_mismatch"] += int(not (z == z_1).all())
 
+    # whole-row walks across a mesh: every task on one primitive covers
+    # both 32-wide column stripes of each row stripe, so the one geometry
+    # of every shard walks 64-wide rows; bitwise the single-device result
+    out["row_walk_cases"] = 0
+    out["row_walk_mismatch"] = 0
+    out["row_walk_geoms"] = 0
+    for prim in ("SpDMM", "SpMM"):
+        def as_prim(plan):
+            return _dc.replace(plan, dtq=[], stq=[
+                _dc.replace(t, primitive=prim, queue="STQ")
+                for t in plan.stq + plan.dtq])
+        one = DynasparseEngine(tile_m=16, tile_n=32, literal=True)
+        plan1 = as_prim(one.plan(adj_s, y_s))
+        z_1 = np.asarray(one.execute(plan1, adj_s, y_s))
+        g1 = one.dispatch_for(plan1, adj_s).geom
+        want = (prim == "SpDMM", prim == "SpMM")
+        for nd in (4, 8):
+            eng = DynasparseEngine(tile_m=16, tile_n=32, literal=True,
+                                   mesh=MESHES[nd])
+            plan = as_prim(eng.plan(adj_s, y_s))
+            z = np.asarray(eng.execute(plan, adj_s, y_s))
+            sd = eng.sharded_dispatch_for(plan, adj_s)
+            out["row_walk_geoms"] += int(
+                (g1.sp_rows, g1.mm_rows) == want ==
+                (sd.geom.sp_rows, sd.geom.mm_rows)
+                and sd.row_walk_sections == 1 and sd.halo.max_take > 0)
+            out["row_walk_cases"] += 1
+            out["row_walk_mismatch"] += int(not (z == z_1).all())
+
     # heterogeneous per-device cost models: a 2x slower device must get a
     # SMALLER row-band than under the homogeneous default, and the result
     # stays bitwise-equal (banding only moves work, never changes math for
@@ -416,6 +445,16 @@ def test_halo_spmm_stripe_walk_matches_single_device(gnn_shard_results):
     assert r["stripe_cases"] == 4
     assert r["stripe_mismatch"] == 0
     assert r["stripe_halo_exchange"] > 0
+
+
+def test_halo_row_walk_matches_single_device(gnn_shard_results):
+    """SpDMM and SpMM sections that cover every column tile walk whole rows
+    on every shard of 4- and 8-device meshes, as on one device, exchange
+    halo blocks, and give the single-device compiled result bitwise."""
+    r, _ = gnn_shard_results
+    assert r["row_walk_cases"] == 4
+    assert r["row_walk_geoms"] == 4
+    assert r["row_walk_mismatch"] == 0
 
 
 def test_mesh_size_one_is_degenerate_case(gnn_shard_results):

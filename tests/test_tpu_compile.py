@@ -135,6 +135,29 @@ def test_spmm_stripe_walk_compiles(one_chip):
     assert compiled.as_text().count("spmm_stripe") >= 2
 
 
+@pytest.mark.parametrize("entries,width,m_pad,k_pad,name,launches", [
+    # GIN's l1-agg on CO: 5,499 steps over 12,288-wide rows, the widest a
+    # served kernel walks, at 384 KiB a block
+    (ADJ_BLOCKS, 8 * 1536, 8 * SM_ADJ, 2712, "spmm_stripe", 1),
+    # SAGE's l1-agg on FL: 900,699 steps over 1,024-wide rows
+    (900_699, 8 * 128, 8 * 11264, 89256, "spdmm_fused", 28),
+])
+def test_row_walk_compiles(one_chip, entries, width, m_pad, k_pad, name,
+                           launches):
+    """A sparse section walking whole rows: one stripe as wide as the
+    padded row, so the Y, output and canvas blocks span the arrays' whole
+    minor dimension; split across launches under the section's name."""
+    def f(pool, y, a, r, o, c, first, z):
+        return ops.spdmm_fused(pool, y, a, r, o, c, first, block_size=B,
+                               bn=width, m_pad=m_pad, interpret=False, z=z,
+                               name=name)
+    ids = [((entries,), I32)] * 5
+    compiled = _compile_kernel(one_chip, f, ((entries, B, B), F32),
+                               ((k_pad, width), F32), *ids,
+                               ((m_pad, width), F32))
+    assert compiled.as_text().count("tpu_custom_call") == launches
+
+
 @pytest.mark.parametrize("tiles,sm,k,n", [
     (8, SM_ACT, K_FEAT, 16),   # l1-update routed to the dense queue
     (4, SM_ACT, 16, 8),        # l2-update's dense half
