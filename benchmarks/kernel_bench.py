@@ -3,9 +3,8 @@
 Wall-clock here is CPU interpret-mode (correctness path), NOT a TPU claim —
 the TPU numbers are the perf-model / roofline terms also printed.  This bench
 demonstrates the skip behaviour (SpDMM work scales with block density) and
-the runtime tentpole: per-queue batched dispatch issues O(primitives) pallas
-launches per kernel, and the PlanCache packs/analyzes a static adjacency
-exactly once across layers and repeated inference calls.
+that the PlanCache packs/analyzes a static adjacency exactly once across
+layers and repeated inference calls.
 """
 from __future__ import annotations
 
@@ -17,7 +16,6 @@ import numpy as np
 
 from repro.core import DynasparseEngine, SparseCOO
 from repro.core.perfmodel import TPUV5E, TaskShape, t_dense, t_spdmm
-from repro.core.scheduler import execute_plan
 from repro.kernels import ops
 from repro.kernels.formats import pack_blockcsr
 from repro.models import gnn
@@ -60,7 +58,7 @@ def run(csv: list[str]) -> None:
         csv.append(f"kernel/spdmm_a{alpha:.2f},{t_s * 1e6:.1f},"
                    f"{model_t * 1e9:.1f}")
 
-    _run_dispatch_bench(csv)
+    _run_plan_cache_demo(csv)
 
 
 def _rand_adj(n: int, nnz: int, seed: int = 5) -> SparseCOO:
@@ -74,39 +72,16 @@ def _rand_adj(n: int, nnz: int, seed: int = 5) -> SparseCOO:
         tag="adjacency")
 
 
-def _run_dispatch_bench(csv: list[str]) -> None:
-    """Tentpole demo: batched per-queue dispatch + plan cache on a 2-layer
-    GCN (literal Pallas execution, interpret mode)."""
-    print("\n== Batched dispatch + PlanCache (2-layer GCN, literal) ==")
+def _run_plan_cache_demo(csv: list[str]) -> None:
+    """Plan cache on a 2-layer GCN (literal Pallas execution, interpret
+    mode): the adjacency is packed and analyzed once across layers and
+    repeated requests."""
+    print("\n== PlanCache (2-layer GCN, literal) ==")
     rng = np.random.default_rng(0)
     n, f, hidden = 128, 24, 16
     adj = _rand_adj(n, 4 * n)
     h = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32))
     params = gnn.init_params("GCN", f, hidden, hidden)
-
-    # one aggregation kernel: per-task vs batched launches + wall-clock
-    eng = DynasparseEngine(tile_m=32, tile_n=8, literal=True)
-    plan = eng.plan(adj, h)
-    xd = adj.todense()
-    n_tasks = len(plan.stq) + len(plan.dtq)
-
-    def _wall(batched):
-        ops.reset_pallas_call_count()
-        t0 = time.perf_counter()
-        z = execute_plan(plan.part, plan.stq, plan.dtq, xd, h,
-                         batched=batched)
-        np.asarray(z)
-        return time.perf_counter() - t0, ops.pallas_call_count(), z
-
-    w_b, calls_b, z_b = _wall(True)
-    w_p, calls_p, z_p = _wall(False)
-    err = float(np.max(np.abs(np.asarray(z_b) - np.asarray(z_p))))
-    print(f"execute_plan agg kernel ({n_tasks} tasks): "
-          f"per-task {calls_p} launches / {w_p * 1e3:7.1f} ms | "
-          f"batched {calls_b} launches / {w_b * 1e3:7.1f} ms | "
-          f"max |Δ| {err:.2e}")
-    csv.append(f"dispatch/launches,{calls_p},{calls_b}")
-    csv.append(f"dispatch/wall_ms,{w_p * 1e3:.1f},{w_b * 1e3:.1f}")
 
     # plan cache across layers and repeated requests
     eng = DynasparseEngine(tile_m=32, tile_n=8, literal=True)

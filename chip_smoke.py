@@ -194,8 +194,7 @@ def four_chips(args) -> None:
     import jax
     import numpy as np
 
-    from repro.core import scheduler
-    from repro.core.primitives import SparseCOO
+    from repro.core import DynasparseEngine
     from repro.launch.mesh import make_data_mesh
     from repro.models import gnn
     from repro.serving import ServingConfig, ServingEngine
@@ -225,12 +224,14 @@ def four_chips(args) -> None:
     finally:
         srv.close()
 
+    # the single-device executor: a literal engine without a mesh runs
+    # each plan through its own routes (the compiled dispatch for the
+    # adjacency, which the halo path is held bit-equal to)
+    single = DynasparseEngine(literal=True, block=engine.block,
+                              eps=engine.eps)
+
     def oracle_mm(x, y, name="kernel"):
-        p = plans[name]
-        xd = np.asarray(x.todense()) if isinstance(x, SparseCOO) else x
-        return scheduler.execute_plan(p.part, p.stq, p.dtq, xd, y,
-                                      block=engine.block, batched=True,
-                                      eps=engine.eps)
+        return single.execute(plans[name], x, y)
 
     bitwise = []
     for b in range(0, len(hs), MAX_BATCH):

@@ -18,35 +18,35 @@ section) → slice with the descriptors as device arrays, so a plan-cache hit
 costs O(1) dict lookups on the host instead of O(nnz blocks) of descriptor
 rebuilding.
 
-Semantics vs the eager batched path (`scheduler._execute_batched`):
+Semantics of the sections:
 
-- GEMM and SpDMM lower exactly the same operations in the same order —
-  bit-identical by construction.
-- SpMM descriptors must be Y-structure-independent to be cacheable (the eager
-  path packs the dense operand's col-stripes per call), so the compiled SpMM
-  section does not intersect Y's structure at all: it is a STRIPE WALK in the
-  SpDMM entry format, one grid step per (stored A block, task column stripe)
-  — each step multiplies the A block into its Y block-row across the whole
-  ``SN``-wide stripe.  These are the multiply-adds of pairing every stored A
-  block with every 8x8 Y block of the stripe (zero Y blocks contribute
-  ``x + (±0) == x``, bitwise, to an accumulator initialized to +0), in the
-  same A-block order per output block, but in ``nnzb`` grid steps per task
-  where the 8-wide pairing took ``nnzb * SN / B``.  One (B, B)@(B, SN) dot
-  rounds like ``SN / B`` dots of width B only up to about one ulp, so the
-  result is bit-identical to the eager run of the same plan with its SpMM
-  tasks relabelled SpDMM, and equal to the structure-intersecting eager SpMM
-  within float32 rounding.  With ``eps != 0`` an eps-thresholded pack
-  *drops* small-but-nonzero Y blocks the stripe walk would read, so the
-  executor zeroes the Y blocks whose magnitudes are all ``<= eps`` on device
-  before the SpMM section (the SpDMM section reads the unmasked operand,
-  hence its own launch) — eps-thresholded SpMM plans compile like any other.
+- The dense queue is ONE batched GEMM whose tiles scatter into the padded
+  canvas; the SpDMM section is ONE fused entry list over the pooled stored
+  blocks of A.
+- SpMM descriptors must be Y-structure-independent to be cacheable, so the
+  compiled SpMM section does not intersect Y's structure at all: it is a
+  STRIPE WALK in the SpDMM entry format, one grid step per (stored A block,
+  task column stripe) — each step multiplies the A block into its Y
+  block-row across the whole ``SN``-wide stripe.  These are the
+  multiply-adds of pairing every stored A block with every 8x8 Y block of
+  the stripe (zero Y blocks contribute ``x + (±0) == x``, bitwise, to an
+  accumulator initialized to +0), in the same A-block order per output
+  block, but in ``nnzb`` grid steps per task where the 8-wide pairing took
+  ``nnzb * SN / B``.  One (B, B)@(B, SN) dot rounds like ``SN / B`` dots of
+  width B only up to about one ulp, so the result equals the same tasks run
+  as SpDMM bit for bit, and a structure-intersecting SpMM within float32
+  rounding.  With ``eps != 0`` an eps-thresholded pack *drops*
+  small-but-nonzero Y blocks the stripe walk would read, so the executor
+  zeroes the Y blocks whose magnitudes are all ``<= eps`` on device before
+  the SpMM section (the SpDMM section reads the unmasked operand, hence its
+  own launch) — eps-thresholded SpMM plans compile like any other.
 - A sparse section whose tasks cover every column tile of each row stripe
   they touch WALKS WHOLE ROWS (:func:`walks_whole_rows`): one grid step per
   stored block over the full ``nct * SN``-wide padded output row instead of
   one per (stored block, column tile).  Each output column still sums the
   same products in the same A-block order; the choice is static in the
-  geometry (``sp_rows`` / ``mm_rows``) and the eager batched and
-  halo-sharded paths make the same one, so they stay bit-identical.
+  geometry (``sp_rows`` / ``mm_rows``) and the halo-sharded path makes the
+  same one, so the two stay bit-identical.
 
 Activation-side kernels (dense X — the intermediate feature matrices) get the
 same treatment through :class:`ActivationDispatch`: the descriptor arrays are
@@ -79,16 +79,19 @@ from repro.kernels import ops
 from repro.kernels.formats import BlockCSR, block_nonzero_mask
 
 
-def canvas_slots(part, block: int) -> tuple[int, int] | None:
-    """Slot sizes ``(SM, SN)`` of the padded in-place canvas, or ``None``
-    when the geometry cannot use the in-place index maps (interior tile
-    boundaries not lcm(block, 8)-aligned — the per-task fallback)."""
+def canvas_slots(part, block: int) -> tuple[int, int]:
+    """Slot sizes ``(SM, SN)`` of the padded in-place canvas.  Interior tile
+    boundaries must be lcm(block, 8)-aligned for the in-place index maps, so
+    only a single stripe's slot may pad past its tile; any other geometry
+    raises ``ValueError`` (the engine never plans one)."""
     align = math.lcm(block, 8)
     tm, tn = part.tile_m, part.tile_n
     SM = tm if tm % align == 0 else -(-tm // align) * align
     SN = tn if tn % align == 0 else -(-tn // align) * align
     if (part.n_row_tiles > 1 and SM != tm) or (part.n_col_tiles > 1 and SN != tn):
-        return None
+        raise ValueError(
+            f"tiles ({tm}, {tn}) split the kernel into stripes whose "
+            f"boundaries are not multiples of lcm(block, 8) = {align}")
     return SM, SN
 
 
@@ -286,22 +289,18 @@ def spdmm_entry_arrays(tasks, stripes: dict[int, "BlockCSR"],
 def build_dispatch(part, stq, dtq, stripes: dict[int, "BlockCSR"],
                    *, block: int, eps: float = 0.0,
                    fingerprint: str = "",
-                   faults: object = None) -> CompiledDispatch | None:
+                   faults: object = None) -> CompiledDispatch:
     """Lower a planned kernel into a :class:`CompiledDispatch`.
 
     O(nnz blocks) of VECTORIZED numpy + one device upload, paid once per
-    (structure, assignment, geometry); returns ``None`` when the canvas
-    geometry cannot take the in-place index maps (caller falls back to the
-    per-task path, exactly like the eager batched dispatch).  ``faults`` is
-    the optional fault injector probed at the ``lower`` site — descriptor
-    lowering is an instrumented degradation path.
+    (structure, assignment, geometry); a partition whose canvas cannot take
+    the in-place index maps raises ``ValueError`` (:func:`canvas_slots`).
+    ``faults`` is the optional fault injector probed at the ``lower`` site
+    — descriptor lowering is an instrumented degradation path.
     """
     if faults is not None:
         faults.probe("lower", detail=f"dispatch:{part.name}")
-    slots = canvas_slots(part, block)
-    if slots is None:
-        return None
-    SM, SN = slots
+    SM, SN = canvas_slots(part, block)
     B = block
     R = SM // B
     nct = part.n_col_tiles
@@ -559,18 +558,15 @@ class ActivationDispatch:
 
 
 def activation_capacity(x, part, block: int, *, eps: float = 0.0,
-                        slack: float = 1.5) -> int | None:
+                        slack: float = 1.5) -> int:
     """Stored-block budget per row-stripe from a warmup activation.
 
     Counts, per canvas row-stripe, the slots the device packer will need
     (stored blocks plus one filler per empty block-row, canvas padding rows
     included) and budgets ``max * slack`` so later batches whose sparsity
     wiggles within the drift threshold still fit without a retrace.
-    ``None`` when the canvas geometry cannot take the in-place index maps.
     """
     needs = _stripe_needs(x, part, block, eps=eps)
-    if needs is None:
-        return None
     R, C = _canvas_rc(part, block)
     return min(R * C, max(1, math.ceil(int(needs.max()) * slack)))
 
@@ -584,10 +580,7 @@ def _stripe_needs(x, part, block: int, *, eps: float = 0.0):
     """Per-stripe slot needs of a warmup activation (stored blocks plus one
     filler per empty block-row, canvas padding rows included) — the shared
     counting core of the uniform and per-stripe budget sizers."""
-    slots = canvas_slots(part, block)
-    if slots is None:
-        return None
-    SM, _ = slots
+    SM, _ = canvas_slots(part, block)
     B = block
     S, R, C = part.n_row_tiles, SM // B, -(-part.K // B)
     x = np.asarray(x)
@@ -608,12 +601,9 @@ def activation_budgets(x, part, block: int, *, eps: float = 0.0,
     cuts padded-slot waste proportionally to the skew, and since drift only
     wiggles a fixed support, warmup needs bound later needs per stripe just
     as they do globally.  Returns an int64 array of length
-    ``part.n_row_tiles``, or ``None`` when the canvas geometry cannot take
-    the in-place index maps.
+    ``part.n_row_tiles``.
     """
     needs = _stripe_needs(x, part, block, eps=eps)
-    if needs is None:
-        return None
     R, C = _canvas_rc(part, block)
     return np.clip(np.ceil(needs * slack).astype(np.int64), 1, R * C)
 
@@ -621,7 +611,7 @@ def activation_budgets(x, part, block: int, *, eps: float = 0.0,
 def build_activation_dispatch(part, stq, dtq, *, block: int, capacity,
                               eps: float = 0.0, fingerprint: str = "",
                               faults: object = None
-                              ) -> ActivationDispatch | None:
+                              ) -> ActivationDispatch:
     """Lower an activation-side plan into capacity-slot descriptor arrays.
 
     ``capacity`` is a uniform int budget or a per-stripe vector (see
@@ -632,16 +622,14 @@ def build_activation_dispatch(part, stq, dtq, *, block: int, capacity,
     unit the runtime slot metadata is row-major, so every output block is
     still visited in ONE consecutive run (the TPU output-residency
     obligation) for ANY stored pattern — and within a run the real
-    contributions arrive in the same (block-row, block-col) order the eager
-    host pack emits, so sums are bit-identical.  Returns ``None`` for
-    canvas geometries the in-place index maps cannot take.
+    contributions arrive in the same (block-row, block-col) order a host
+    ``pack_blockcsr`` emits, so sums are bit-identical to its unfused
+    kernels.  A canvas the in-place index maps cannot take raises
+    ``ValueError`` (:func:`canvas_slots`).
     """
     if faults is not None:
         faults.probe("pack", detail=f"act:{part.name}")
-    slots = canvas_slots(part, block)
-    if slots is None:
-        return None
-    SM, SN = slots
+    SM, SN = canvas_slots(part, block)
     B = block
     R, C = SM // B, SN // B
     cap_arr = np.asarray(capacity, dtype=np.int64)

@@ -17,14 +17,16 @@ which splits into two phases:
   subsequent inference request reuse the layer-1 plan (the paper's Alg. 4
   preprocessing amortized across layers, Dynasparse-style).
 
-- ``execute``: compute the result — batched per-queue with the fused Pallas
-  kernels when ``literal=True`` (tests/TPU; one launch per primitive, packed
-  BlockCSR stripes served from the cache), or through the fastest
-  functionally-equivalent path otherwise.
+- ``execute``: compute the result.  A ``literal=True`` engine runs the
+  kernel through :meth:`DynasparseEngine.route` — the same four routes
+  (``shard``, ``sparse``, ``act``, ``gemm``) the whole-model compiled
+  program records, each one jitted call on descriptors served from the
+  cache; otherwise plain ``jax.numpy``.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Literal
 
 import jax
@@ -53,6 +55,10 @@ Mode = Literal["dynamic", "sparse_only", "dense_only"]
 # a kernel reads): compiled for a v5e, GCN-CO's 506,880 capacity slots take
 # 4.16 GB of temporaries.
 _POOL_BYTES_PER_BLOCK = 2 * 8 * 128 * 4
+
+# headroom of an activation kernel's stored-block budget over its warmup
+# need: the eager pass's route, and the compiled program's by default
+ACTIVATION_SLACK = 1.5
 
 
 @dataclasses.dataclass
@@ -124,7 +130,6 @@ class DynasparseEngine:
         block: int = 8,
         interpret: bool | None = None,
         eps: float = 0.0,
-        batched: bool = True,
         cache: PlanCache | None = None,
         drift_threshold: float | None = None,
         sketch_rows: int = 256,
@@ -195,7 +200,6 @@ class DynasparseEngine:
         self.block = block
         self.interpret = interpret
         self.eps = eps
-        self.batched = batched
         self.cache = PlanCache() if cache is None else cache
         # density-drift revalidation of plan hits (the serving subsystem
         # enables this; None keeps the raw first-call amortization)
@@ -206,6 +210,8 @@ class DynasparseEngine:
         # whole-model compiler (models.gnn.compile_model) record each
         # kernel's plan without re-entering the cache/sketch machinery
         self.last_plan: KernelPlan | None = None
+        # the (kind, dispatch) route the most recent literal execute took
+        self.last_route: tuple[str, object] | None = None
 
     @property
     def n_devices(self) -> int:
@@ -252,12 +258,24 @@ class DynasparseEngine:
         return not limit or n_blocks * _POOL_BYTES_PER_BLOCK <= limit // 2
 
     def _geometry(self, M: int, N: int) -> tuple[int, int]:
+        """Tile sizes of an ``(M, ., N)`` kernel.  A tile that splits its
+        dimension into several stripes must be a multiple of
+        ``lcm(block, 8)``: the in-place canvas addresses slots in blocks
+        and 8-lane groups (``dispatch.canvas_slots``)."""
         tm, tn = self.tile_m, self.tile_n
         if tm is None or tn is None:
             ctm, ctn = choose_tile(M, N)
             tm = tm or ctm
             tn = tn or ctn
-        return min(tm, M), min(tn, N)
+        tm, tn = min(tm, M), min(tn, N)
+        align = math.lcm(self.block, 8)
+        for name, t, dim in (("tile_m", tm, M), ("tile_n", tn, N)):
+            if t < dim and t % align:
+                raise ValueError(
+                    f"{name}={t} splits a dimension of {dim} into several "
+                    f"stripes and is not a multiple of lcm(block, 8) = "
+                    f"{align}")
+        return tm, tn
 
     def plan(self, x, y, name: str = "kernel") -> KernelPlan:
         """Preprocessing phase: densities → task grid → Analyzer → simulated
@@ -400,24 +418,13 @@ class DynasparseEngine:
             self.cache.recharge(PlanCache._STRUCT, key)
         return entry.dense
 
-    def dispatch_for(self, plan: KernelPlan, x) -> "_dispatch.CompiledDispatch | None":
-        """The plan's :class:`CompiledDispatch` (cached; lowered on first
-        need), or ``None`` when the kernel is not compilable: non-literal /
-        non-batched engines, uncacheable (dense X) operands, or canvas-
-        misaligned geometry.  eps-thresholded SpMM plans compile too — the
-        executor masks sub-eps Y blocks inside the traced program, so the
-        SpMM stripe walk stays Y-structure-independent
+    def dispatch_for(self, plan: KernelPlan,
+                     x: SparseCOO) -> "_dispatch.CompiledDispatch":
+        """The plan's :class:`CompiledDispatch` for a sparse ``x`` (cached;
+        lowered on first need).  eps-thresholded SpMM plans compile too —
+        the executor masks sub-eps Y blocks inside the traced program, so
+        the SpMM stripe walk stays Y-structure-independent
         (``repro.core.dispatch``)."""
-        if not (self.literal and self.batched):
-            return None
-        if self.mesh is not None:
-            # mesh engines lower through sharded_dispatch_for — even at mesh
-            # size 1, so the degenerate case exercises the shared shard path
-            return None
-        if not isinstance(x, SparseCOO) or plan.struct_key is None:
-            return None
-        if _dispatch.canvas_slots(plan.part, self.block) is None:
-            return None
         _, entry = self._packed_structure(plan, x)
         digest = _dispatch.plan_digest(plan, self.block)
         return self.cache.dispatch(
@@ -429,19 +436,10 @@ class DynasparseEngine:
 
     def sharded_dispatch_for(
             self, plan: KernelPlan,
-            x) -> "_shard_exec.ShardedDispatch | None":
+            x: SparseCOO) -> "_shard_exec.ShardedDispatch":
         """The placed plan's :class:`~repro.core.shard_exec.ShardedDispatch`
-        (cached; lowered on first need), or ``None`` when the kernel is not
-        compilable — same decline conditions as :meth:`dispatch_for`, plus
-        a missing placement (plan made by a non-mesh engine)."""
-        if self.mesh is None or not (self.literal and self.batched):
-            return None
-        if not isinstance(x, SparseCOO) or plan.struct_key is None:
-            return None
-        if plan.placement is None:
-            return None
-        if _dispatch.canvas_slots(plan.part, self.block) is None:
-            return None
+        for a sparse ``x`` on a mesh engine (cached; lowered on first
+        need)."""
         _, entry = self._packed_structure(plan, x)
         digest = _dispatch.plan_digest(plan, self.block)
         return self.cache.sharded_dispatch(
@@ -454,15 +452,15 @@ class DynasparseEngine:
 
     def activation_dispatch_for(
             self, plan: KernelPlan, x, *, capacity=None,
-            slack: float = 1.5,
+            slack: float = ACTIVATION_SLACK,
             per_stripe: bool = True) -> "_dispatch.ActivationDispatch | None":
         """The plan's :class:`ActivationDispatch` — the capacity-padded
         block-skip route for a dense (activation-side) X — or ``None`` when
-        the kernel should stay dense: non-literal/non-batched engines,
-        sparse X (that is :meth:`dispatch_for`'s job), plans whose Analyzer
-        routed every task to the dense engine (dense wins — a plain GEMM is
-        the whole kernel), canvas-misaligned geometry, or a budget whose
-        pool does not fit the device (:meth:`block_pool_fits`).
+        the kernel should stay dense: sparse X (that is
+        :meth:`dispatch_for`'s job), plans whose Analyzer routed every task
+        to the dense engine (dense wins — a plain GEMM is the whole
+        kernel), or a budget whose pool does not fit the device
+        (:meth:`block_pool_fits`).
 
         ``capacity`` fixes the stored-block budget (an int for a uniform
         budget, or a per-stripe vector); by default it is measured from
@@ -474,19 +472,13 @@ class DynasparseEngine:
         the plan digest (geometry + ordered assignment) and the budget, so
         every activation kernel with the same shape and task split shares
         one lowering and one trace."""
-        if not (self.literal and self.batched):
-            return None
         if isinstance(x, SparseCOO) or not plan.stq:
             return None
         if capacity is None:
-            if per_stripe:
-                capacity = _dispatch.activation_budgets(
-                    x, plan.part, self.block, eps=self.eps, slack=slack)
-            else:
-                capacity = _dispatch.activation_capacity(
-                    x, plan.part, self.block, eps=self.eps, slack=slack)
-            if capacity is None:
-                return None
+            measure = (_dispatch.activation_budgets if per_stripe
+                       else _dispatch.activation_capacity)
+            capacity = measure(x, plan.part, self.block, eps=self.eps,
+                               slack=slack)
         cap_key = (tuple(int(c) for c in np.asarray(capacity).ravel())
                    if np.ndim(capacity) else int(capacity))
         slots = (sum(cap_key) if isinstance(cap_key, tuple)
@@ -501,97 +493,79 @@ class DynasparseEngine:
                 capacity=capacity, eps=self.eps, fingerprint=digest,
                 faults=self.faults))
 
-    def compiled_operands(
-            self, plan: KernelPlan,
-            x) -> "tuple[_dispatch.CompiledDispatch, jnp.ndarray | None] | None":
-        """(dispatch, densified-x-or-None) for a plan, or ``None`` when the
-        kernel is not compilable — the whole-model compiler's accessor."""
-        d = self.dispatch_for(plan, x)
-        if d is None:
-            return None
-        xd = None
-        if d.needs_x:
-            key, entry = self._packed_structure(plan, x)
-            xd = self._ensure_dense(key, entry, x)
-        return d, xd
+    def route(self, plan: KernelPlan, x, *, activation_skip: bool = True,
+              slack: float = ACTIVATION_SLACK,
+              per_stripe: bool = True) -> tuple[str, object]:
+        """The route a literal engine runs ``plan``'s kernel through, as
+        ``(kind, dispatch)`` — one of the compiled program's four record
+        kinds (``models.gnn.compile_model``), which the eager pass runs
+        too:
 
-    def sharded_operands(
-            self, plan: KernelPlan,
-            x) -> "tuple[_shard_exec.ShardedDispatch, jnp.ndarray | None] | None":
-        """(sharded dispatch, densified-x-or-None) for a placed plan, or
-        ``None`` when not compilable — the mesh-engine counterpart of
-        :meth:`compiled_operands` used by the whole-model compiler."""
-        sd = self.sharded_dispatch_for(plan, x)
-        if sd is None:
+        - ``"shard"``: sparse ``x`` on a mesh engine
+          (:meth:`sharded_dispatch_for`);
+        - ``"sparse"``: sparse ``x`` (:meth:`dispatch_for`);
+        - ``"act"``: dense ``x`` whose plan has sparse-queue tasks and whose
+          pool fits (:meth:`activation_dispatch_for`; ``slack`` and
+          ``per_stripe`` size its budget), unless ``activation_skip`` is
+          off;
+        - ``"gemm"``: anything else — one dense GEMM, dispatch ``None``."""
+        if isinstance(x, SparseCOO):
+            if self.mesh is not None:
+                return "shard", self.sharded_dispatch_for(plan, x)
+            return "sparse", self.dispatch_for(plan, x)
+        ad = (self.activation_dispatch_for(plan, x, slack=slack,
+                                           per_stripe=per_stripe)
+              if activation_skip else None)
+        return ("gemm", None) if ad is None else ("act", ad)
+
+    def dense_x(self, plan: KernelPlan, x: SparseCOO, d) -> "jnp.ndarray | None":
+        """The densified sparse ``x`` a (sharded) dispatch's dense-queue
+        gather reads, or ``None`` when it has no dense-queue tasks."""
+        if not d.needs_x:
             return None
-        xd = None
-        if sd.needs_x:
-            key, entry = self._packed_structure(plan, x)
-            xd = self._ensure_dense(key, entry, x)
-        return sd, xd
+        key, entry = self._packed_structure(plan, x)
+        return self._ensure_dense(key, entry, x)
 
     def execute(self, plan: KernelPlan, x, y) -> jnp.ndarray:
         """Functional result of a planned kernel (no re-analysis).
 
-        Literal engines prefer the compiled dispatch: descriptor arrays are
-        served from the cache and the whole kernel runs as ONE jitted call —
-        zero per-request host work beyond dict lookups.  Kernels the compiler
-        declines fall back to the eager batched (or per-task) path."""
+        A literal engine runs the kernel through :meth:`route` — the same
+        route, descriptors and kernels the whole-model compiled program
+        records for it, each as one jitted call; the route taken is kept
+        in ``last_route``.  A non-literal engine computes the product with
+        plain ``jax.numpy``."""
         if self.faults is not None:
             self.faults.probe("execute", detail=plan.part.name)
         y = jnp.asarray(y)
-        if self.literal:
-            interpret = (_ops.default_interpret()
-                         if self.interpret is None else self.interpret)
-            if self.mesh is not None:
-                spair = self.sharded_operands(plan, x)
-                if spair is not None:
-                    sd, xd = spair
-                    return _shard_exec.execute_sharded(
-                        sd, xd, y, mesh=self.mesh, interpret=interpret,
-                        stats=self.cache.stats, faults=self.faults)
-                # a kernel the mesh does not shard runs on the mesh's first
-                # device: a Mosaic kernel cannot be partitioned automatically,
-                # so operands a sharded kernel left spread out are gathered
-                home = self.mesh.devices.flat[0]
-                y = jax.device_put(y, home)
-                if not isinstance(x, SparseCOO):
-                    x = jax.device_put(jnp.asarray(x), home)
-            pair = self.compiled_operands(plan, x)
-            if pair is not None:
-                d, xd = pair
-                return _dispatch.execute_dispatch(
-                    d, xd, y, interpret=interpret, stats=self.cache.stats)
-            if not isinstance(x, SparseCOO) and plan.stq and (
-                    not self.block_pool_fits(
-                        -(-plan.part.M // self.block)
-                        * -(-plan.part.K // self.block))):
-                # a dense operand whose blocks would not fit as a pool runs
-                # as the one dense GEMM its compiled program runs
-                return _ops.gemm(jnp.asarray(x), y, interpret=interpret,
-                                 out_dtype=jnp.float32)
-            packed = None
+        if not self.literal:
             if isinstance(x, SparseCOO):
-                if plan.struct_key is not None:
-                    key, entry = self._packed_structure(plan, x)
-                    packed = entry.stripes
-                    # the densified operand is only needed by dense-engine
-                    # tasks (batched GEMM gather) or the per-task path
-                    if plan.dtq or not self.batched:
-                        xd = self._ensure_dense(key, entry, x)
-                    else:
-                        xd = None
-                else:
-                    xd = x.todense()
-            else:
-                xd = x
-            return _scheduler.execute_plan(
-                plan.part, plan.stq, plan.dtq, xd, y,
-                block=self.block, interpret=self.interpret,
-                batched=self.batched, packed=packed, eps=self.eps)
-        if isinstance(x, SparseCOO):
-            return prim.spdmm_exec(x, y)
-        return prim.gemm_exec(jnp.asarray(x), y)
+                return prim.spdmm_exec(x, y)
+            return prim.gemm_exec(jnp.asarray(x), y)
+        interpret = (_ops.default_interpret()
+                     if self.interpret is None else self.interpret)
+        kind, d = self.last_route = self.route(plan, x)
+        if kind == "shard":
+            return _shard_exec.execute_sharded(
+                d, self.dense_x(plan, x, d), y, mesh=self.mesh,
+                interpret=interpret, stats=self.cache.stats,
+                faults=self.faults)
+        if self.mesh is not None:
+            # a kernel the mesh does not shard runs on the mesh's first
+            # device: a Mosaic kernel cannot be partitioned automatically,
+            # so operands a sharded kernel left spread out are gathered
+            home = self.device()
+            y = jax.device_put(y, home)
+            x = jax.device_put(jnp.asarray(x), home)
+        if kind == "sparse":
+            return _dispatch.execute_dispatch(
+                d, self.dense_x(plan, x, d), y, interpret=interpret,
+                stats=self.cache.stats)
+        if kind == "act":
+            z, _ = _dispatch.execute_activation(
+                d, x, y, interpret=interpret, stats=self.cache.stats)
+            return z
+        return _ops.gemm(jnp.asarray(x), y, interpret=interpret,
+                         out_dtype=jnp.float32)
 
     # ------------------------------------------------------------------
     def matmul(self, x, y, name: str = "kernel"):

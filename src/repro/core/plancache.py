@@ -203,7 +203,7 @@ class StructureEntry:
     ``dense`` is lazy: stripes are packed straight from the COO triplets
     (no dense intermediate — required beyond toy scale), and the densified
     operand is only materialized if a plan actually routes tasks of this
-    operand to the dense engine (or the per-task path needs it)."""
+    operand to the dense engine."""
     stripes: dict[int, BlockCSR]      # row-stripe index -> packed BlockCSR
     dense: object | None = None       # densified operand, device-resident
 

@@ -2,12 +2,12 @@
 
 The numerical result of a kernel is primitive-independent (GEMM, SpDMM and
 SpMM all compute Z = X·Y); the primitive choice decides *time* and *data
-movement*.  The engine therefore computes results through the fastest
-functionally-equivalent path for the current backend:
+movement*.  The engine therefore computes results one of two ways:
 
-- TPU / tests: the Pallas kernels via ``scheduler.execute_plan``;
-- CPU at graph scale: a COO segment-sum SpDMM (adjacency is far too large to
-  densify) and plain ``jnp.dot`` for dense operands.
+- a literal engine (TPU / tests): the Pallas kernels of the route the
+  compiled program records (``DynasparseEngine.route``);
+- a non-literal engine: a COO segment-sum SpDMM (adjacency is far too large
+  to densify) and plain ``jnp.dot`` for dense operands — this module.
 
 ``SparseCOO`` is the storage format of the paper's BufferA (Alg. 2).
 """

@@ -1,34 +1,24 @@
-"""Scheduler — Algorithm 4 lines 13-21.
+"""Scheduler — Algorithm 4 lines 13-21, as a simulator.
 
-Two roles:
+``simulate``: event-driven list scheduling of the two task queues onto the
+sparse units (8 ALU arrays on VCK5000) and the single dense engine (AIE
+array / MXU), exactly the paper's idle-unit pop loop.  Returns makespan and
+per-unit busy time — this is the cycle-estimate backend of the benchmark
+harness (the paper's own evaluation methodology: a perf-model-driven
+simulator with a DDR bandwidth bound, §IV-A).  ``simulate_sharded`` does the
+same per device of a placed plan.
 
-1. ``simulate``: event-driven list scheduling of the two task queues onto the
-   sparse units (8 ALU arrays on VCK5000) and the single dense engine (AIE
-   array / MXU), exactly the paper's idle-unit pop loop.  Returns makespan and
-   per-unit busy time — this is the cycle-estimate backend of the benchmark
-   harness (the paper's own evaluation methodology: a perf-model-driven
-   simulator with a DDR bandwidth bound, §IV-A).
-
-2. ``execute_plan``: literal functional execution of a plan — each queue is
-   drained with its real kernel (Pallas GEMM / SpDMM / SpMM) and the output
-   tiles are assembled.  Used by tests to prove plan-execution equivalence
-   and on TPU as the actual dispatch path.
+The queues themselves are drained by the lowered dispatches of
+``repro.core.dispatch`` and ``repro.core.shard_exec``
+(``DynasparseEngine.route``).
 """
 from __future__ import annotations
 
 import dataclasses
 import heapq
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.core import dispatch as _dispatch
-from repro.core.partition import KernelPartition, Task
+from repro.core.partition import Task
 from repro.core.perfmodel import HardwareModel, flops, data_count
-from repro.kernels import ops
-from repro.kernels.formats import (BlockCSR, first_visit_flags,
-                                   pack_blockcsr, pair_block_triples)
 
 
 @dataclasses.dataclass
@@ -165,247 +155,3 @@ def simulate_sharded(
         makespan=max((r.makespan for r in per_dev), default=0.0),
         per_device=tuple(per_dev),
     )
-
-
-def execute_plan(
-    part: KernelPartition,
-    stq: list[Task],
-    dtq: list[Task],
-    x,
-    y,
-    *,
-    block: int = 8,
-    interpret: bool | None = None,
-    batched: bool = True,
-    packed: dict[int, "BlockCSR"] | None = None,
-    eps: float = 0.0,
-) -> jnp.ndarray:
-    """Drain both queues with their REAL kernels and assemble Z.
-
-    ``x``/``y`` are dense host/device matrices.  ``batched=True`` (default)
-    is the paper's whole-queue drain (Alg. 4 lines 13-21): the Dense Task
-    Queue becomes ONE padded ``(n_tasks, tm, tn)`` GEMM launch, and the
-    Sparse Task Queue's SpDMM / SpMM tasks are flattened into one entry /
-    triple list each, driving a single fused kernel launch per primitive —
-    O(primitives) pallas calls per kernel instead of O(tasks).  Each fused
-    kernel's output index map scatters its tasks' tiles directly into ONE
-    shared padded ``(M, N)`` canvas (aliased through the chain of
-    primitives), so assembly is a single slice — no per-task scatter.
-
-    ``packed`` optionally supplies pre-packed BlockCSR row-stripes of ``x``
-    (index -> BlockCSR), the PlanCache's amortized §III-B preprocessing;
-    missing stripes are packed on the fly.  ``batched=False`` keeps the
-    original one-launch-per-task path for equivalence testing.
-
-    ``x`` may be ``None`` on the batched path when ``packed`` covers every
-    stripe the sparse queue touches AND the dense queue is empty — the
-    engine's graph-scale mode, where the operand is never densified.
-    """
-    interpret = ops.default_interpret() if interpret is None else interpret
-    if batched:
-        return _execute_batched(part, stq, dtq, x, y, block=block,
-                                interpret=interpret, packed=packed, eps=eps)
-    return _execute_pertask(part, stq, dtq, x, y, block=block,
-                            interpret=interpret, eps=eps, packed=packed)
-
-
-def _execute_pertask(part, stq, dtq, x, y, *, block, interpret, eps=0.0,
-                     packed=None):
-    x = None if x is None else jnp.asarray(x)
-    y = jnp.asarray(y)
-    z = np.zeros((part.M, part.N), dtype=np.float32)
-    tm, tn = part.tile_m, part.tile_n
-    # device tiles are COLLECTED and pulled back in one transfer at the end:
-    # a per-task np.asarray would force a device sync per launch, serializing
-    # the queue drain on host<->device latency instead of compute
-    pending: list[tuple[slice, slice, jnp.ndarray]] = []
-    # host mirrors of the operands, materialized AT MOST ONCE if packing
-    # needs them (one transfer instead of one sync per task)
-    x_host = None
-    y_host = None
-
-    if dtq and x is None:
-        raise ValueError("execute_plan: dense-queue tasks need the "
-                         "densified x operand (got x=None)")
-    for task in dtq:  # dense engine: MXU GEMM
-        xs = x[task.i * tm:(task.i + 1) * tm, :]
-        ys = y[:, task.j * tn:(task.j + 1) * tn]
-        z_tile = ops.gemm(xs, ys, bm=min(128, -(-xs.shape[0] // 8) * 8),
-                          interpret=interpret, out_dtype=jnp.float32)
-        pending.append((slice(task.i * tm, task.i * tm + xs.shape[0]),
-                        slice(task.j * tn, task.j * tn + ys.shape[1]),
-                        z_tile))
-
-    for task in stq:  # sparse engine: block-skip kernels
-        if packed is not None and task.i in packed:
-            x_bcsr = packed[task.i]
-        elif x is None:
-            raise ValueError(
-                f"execute_plan: row-stripe {task.i} is missing from `packed` "
-                "and no dense x was supplied to pack it from")
-        else:
-            if x_host is None:
-                x_host = np.asarray(x)
-            x_bcsr = pack_blockcsr(
-                x_host[task.i * tm:(task.i + 1) * tm, :], block, eps=eps)
-        mi = part.row_extent(task.i)
-        ys = y[:, task.j * tn:(task.j + 1) * tn]
-        if task.primitive == "SpMM":
-            if y_host is None:
-                y_host = np.asarray(y)
-            y_bcsr = pack_blockcsr(
-                y_host[:, task.j * tn:(task.j + 1) * tn], block, eps=eps)
-            z_tile = ops.spmm(x_bcsr, y_bcsr, interpret=interpret)
-        else:
-            z_tile = ops.spdmm(x_bcsr, ys, bn=min(128, -(-ys.shape[1] // 8) * 8),
-                               interpret=interpret)
-        pending.append((slice(task.i * tm, task.i * tm + mi),
-                        slice(task.j * tn, task.j * tn + ys.shape[1]),
-                        z_tile))
-
-    tiles = jax.device_get([t for _, _, t in pending])
-    for (rs, cs, _), tile in zip(pending, tiles):
-        z[rs, cs] = tile
-    return jnp.asarray(z)
-
-
-def _execute_batched(part, stq, dtq, x, y, *, block, interpret, packed=None,
-                     eps=0.0):
-    """Per-queue fused dispatch with in-place output assembly.
-
-    ONE ``(M_pad, N_pad)`` canvas holds the final padded layout of the
-    partition: row-stripe ``i`` occupies rows ``[i*SM, (i+1)*SM)`` and
-    col-stripe ``j`` columns ``[j*SN, (j+1)*SN)``, where the slot sizes
-    ``SM``/``SN`` equal the tile sizes (padded up only in the single-stripe
-    case).  Each fused kernel scatters its tasks' tiles directly into that
-    canvas through its output index map; the canvas is threaded through the
-    primitives via output aliasing, so blocks a primitive doesn't touch
-    keep what the previous primitive (or the zero init) left there.
-    Assembly is ``canvas[:M, :N]`` — no per-task scatter loops.
-    """
-    tm, tn = part.tile_m, part.tile_n
-    M, K, N = part.M, part.K, part.N
-    nrt, nct = part.n_row_tiles, part.n_col_tiles
-    B = block
-
-    # The in-place index maps address the canvas in units of B-blocks (sparse
-    # kernels) and 8-lane groups (GEMM tiles), so every interior slot
-    # boundary i*SM / j*SN must be a multiple of lcm(B, 8).  The engine's
-    # default geometry satisfies this; constructor-supplied tile sizes that
-    # don't fall back to the equivalent per-task path (packed stripes are
-    # reused there, so a graph-scale x=None call still works).
-    slots = _dispatch.canvas_slots(part, B)
-    if slots is None:
-        return _execute_pertask(part, stq, dtq, x, y, block=B,
-                                interpret=interpret, eps=eps, packed=packed)
-    SM, SN = slots
-
-    R = SM // B                      # block-rows per row-stripe slot
-    C = SN // B                      # block-cols per col-stripe slot
-    M_pad, N_pad = nrt * SM, nct * SN
-    x = None if x is None else jnp.asarray(x)
-    y = jnp.asarray(y)
-    z = jnp.zeros((M_pad, N_pad), dtype=jnp.float32)
-
-    spdmm_tasks = [t for t in stq if t.primitive != "SpMM"]
-    spmm_tasks = [t for t in stq if t.primitive == "SpMM"]
-
-    # pack (or fetch) the BlockCSR row-stripes the sparse queue needs
-    stripes: dict[int, "BlockCSR"] = {}
-    for i in sorted({t.i for t in spdmm_tasks} | {t.i for t in spmm_tasks}):
-        if packed is not None and i in packed:
-            stripes[i] = packed[i]
-        else:
-            if x is None:
-                raise ValueError(
-                    f"execute_plan: row-stripe {i} is missing from `packed` "
-                    "and no dense x was supplied to pack it from")
-            stripes[i] = pack_blockcsr(
-                np.asarray(x[i * tm:(i + 1) * tm, :]), B, eps=eps)
-
-    # ---------------- DTQ: one batched GEMM scattered into the canvas
-    if dtq:
-        if x is None:
-            raise ValueError("execute_plan: dense-queue tasks need the "
-                             "densified x operand (got x=None)")
-        task_is = np.array([t.i for t in dtq], dtype=np.int32)
-        task_js = np.array([t.j for t in dtq], dtype=np.int32)
-        x_p = jnp.pad(x, ((0, M_pad - M), (0, 0)))
-        y_p = jnp.pad(y, ((0, 0), (0, nct * tn - N))).reshape(K, nct, tn)
-        if SN != tn:
-            y_p = jnp.pad(y_p, ((0, 0), (0, 0), (0, SN - tn)))
-        xs = x_p.reshape(nrt, SM, K)[task_is]
-        ys = jnp.moveaxis(y_p, 1, 0)[task_js]
-        z = ops.gemm_batch_scatter(xs, ys, task_is, task_js, z,
-                                   interpret=interpret)
-
-    # ---------------- STQ / SpDMM: one fused entry list
-    if spdmm_tasks:
-        ncb = -(-K // B)
-        # Y with each col-stripe padded to SN columns, K padded to blocks
-        y_pad = jnp.pad(y, ((0, ncb * B - K), (0, nct * tn - N)))
-        y_f = jnp.pad(y_pad.reshape(ncb * B, nct, tn),
-                      ((0, 0), (0, 0), (0, SN - tn))
-                      ).reshape(ncb * B, nct * SN)
-        offsets, a_pool = _dispatch._stripe_pool(spdmm_tasks, stripes)
-        # the compiled dispatch's choice of walk, so the two stay bitwise
-        rows = _dispatch.walks_whole_rows(spdmm_tasks, nct, SN, B)
-        a_ids, y_rows, out_rows, out_cols, first = \
-            _dispatch.spdmm_entry_arrays(spdmm_tasks, stripes, offsets, R,
-                                         whole_rows=rows)
-        z = ops.spdmm_fused(
-            a_pool, y_f, a_ids, y_rows, out_rows, out_cols, first,
-            block_size=B, bn=N_pad if rows else SN, m_pad=M_pad,
-            interpret=interpret, z=z)
-
-    # ---------------- STQ / SpMM: one fused triple list
-    if spmm_tasks:
-        # ONE host pull of Y serves every col-stripe pack of this call — a
-        # per-stripe np.asarray would sync the device once per stripe for
-        # the same matrix the SpDMM section just laid out
-        y_np = np.asarray(y)
-        ystripes = {
-            j: pack_blockcsr(y_np[:, j * tn:(j + 1) * tn], B, eps=eps)
-            for j in sorted({t.j for t in spmm_tasks})}
-        a_off: dict[int, int] = {}
-        y_off: dict[int, int] = {}
-        a_pool, y_pool = [], []
-        off = 0
-        for i in sorted({t.i for t in spmm_tasks}):
-            a_off[i] = off
-            a_pool.append(stripes[i].blocks[: stripes[i].nnzb])
-            off += stripes[i].nnzb
-        a_sent = off
-        off = 0
-        for j in sorted(ystripes):
-            y_off[j] = off
-            y_pool.append(ystripes[j].blocks[: ystripes[j].nnzb])
-            off += ystripes[j].nnzb
-        y_sent = off
-        a_blocks = jnp.concatenate(
-            a_pool + [jnp.zeros((1, B, B), a_pool[0].dtype)], axis=0)
-        y_blocks = jnp.concatenate(
-            y_pool + [jnp.zeros((1, B, B), y_pool[0].dtype)], axis=0)
-
-        trip = []  # (out_row, out_col, a_id, y_id), per-task canvas regions
-        for task in spmm_tasks:
-            trip.extend(pair_block_triples(
-                stripes[task.i], ystripes[task.j],
-                a_sentinel=a_sent, y_sentinel=y_sent,
-                a_offset=a_off[task.i], y_offset=y_off[task.j],
-                base_row=task.i * R, base_col=task.j * C,
-                n_row_blocks=-(-part.row_extent(task.i) // B),
-                n_col_blocks=-(-part.col_extent(task.j) // B)))
-        trip.sort()
-        out_rows = np.array([t[0] for t in trip], dtype=np.int32)
-        out_cols = np.array([t[1] for t in trip], dtype=np.int32)
-        z = ops.spmm_fused(
-            a_blocks, y_blocks,
-            np.array([t[2] for t in trip], dtype=np.int32),
-            np.array([t[3] for t in trip], dtype=np.int32),
-            out_rows, out_cols,
-            first_visit_flags(out_rows, out_cols),
-            block_size=B, m_pad=M_pad, n_pad=N_pad,
-            interpret=interpret, z=z)
-
-    return z[:M, :N]

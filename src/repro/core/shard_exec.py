@@ -173,14 +173,13 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
                            fingerprint: str = "",
                            operand_sharding: str = "halo",
                            faults: object = None,
-                           mesh=None) -> ShardedDispatch | None:
+                           mesh=None) -> ShardedDispatch:
     """Lower a device-placed plan into a :class:`ShardedDispatch`.
 
     Same O(nnz blocks) vectorized-numpy cost as
     :func:`~repro.core.dispatch.build_dispatch`, paid once per (structure,
-    assignment, mesh geometry, operand-sharding mode); ``None`` when the
-    canvas geometry cannot take the in-place index maps (caller falls back
-    to the eager path, which is placement-agnostic and already correct).
+    assignment, mesh geometry, operand-sharding mode), and the same
+    ``ValueError`` for a canvas the in-place index maps cannot take.
     With ``mesh``, every per-device array is uploaded split along its
     leading device axis (``P("data")``), so each device holds only its own
     band's descriptors and pool and the program never reshards them.
@@ -197,10 +196,7 @@ def build_sharded_dispatch(part, stq, dtq, stripes, placement,
                          f"{OPERAND_SHARDINGS}, got {operand_sharding!r}")
     if faults is not None:
         faults.probe("shard_lower", detail=f"shard:{part.name}")
-    slots = _dispatch.canvas_slots(part, block)
-    if slots is None:
-        return None
-    SM, SN = slots
+    SM, SN = _dispatch.canvas_slots(part, block)
     B = block
     R = SM // B
     nd = placement.n_devices

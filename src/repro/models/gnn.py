@@ -27,7 +27,8 @@ from repro import compat
 from repro.core import dispatch as _dispatch
 from repro.core import shard_exec as _shard_exec
 from repro.core import sparsity
-from repro.core.engine import DynasparseEngine, EngineReport
+from repro.core.engine import (ACTIVATION_SLACK, DynasparseEngine,
+                               EngineReport)
 from repro.core.primitives import SparseCOO
 from repro.kernels import ops
 
@@ -241,35 +242,35 @@ class CompiledModel:
 
 def compile_model(model: str, engine: DynasparseEngine, adj, h, params,
                   *, transport=None, activation_skip: bool = True,
-                  activation_slack: float = 1.5,
+                  activation_slack: float = ACTIVATION_SLACK,
                   activation_per_stripe: bool = True):
     """Fuse all layer kernels of (model, graph, feature shape) into a single
     jitted program; returns ``(warmup logits, CompiledModel | None)``.
 
     The warmup is ONE ordinary eager pass through ``engine.matmul`` — it
-    plans, packs and lowers every adjacency kernel into the plan cache (all
-    amortized state a later eager call would also use), while this function
-    records each kernel's :class:`~repro.core.dispatch.CompiledDispatch`.
-    The replay then re-traces the model with every adjacency kernel inlined
-    as its compiled-dispatch body, the whole sequence under ONE ``jax.jit``.
+    plans, packs and lowers every kernel into the plan cache and runs each
+    through :meth:`DynasparseEngine.route`, while this function records the
+    route (``sparse``, ``shard``, ``act`` or ``gemm``) and its descriptors.
+    The replay then re-traces the model with every kernel inlined as its
+    route's body, the whole sequence under ONE ``jax.jit``.
 
-    Activation-side (dense X) kernels choose their route per layer from the
-    recorded warmup pass: when the warmup plan's Analyzer routed tasks to
-    the sparse engine, the kernel is inlined as the capacity-padded
-    block-skip route (:class:`~repro.core.dispatch.ActivationDispatch` —
-    zero blocks of the intermediate features are skipped with FIXED shapes,
-    budgeted at ``activation_slack`` headroom over the warmup's stored
-    blocks — per stripe when ``activation_per_stripe`` (default), so skewed
-    activations don't pad every stripe to the densest one's need; a batch
-    that overflows the budget falls back to a dense GEMM
-    inside the same program, never a retrace).  When the Analyzer sent
-    everything to the dense engine — dense activations win — the kernel
-    stays one dense Pallas GEMM.  ``activation_skip=False`` forces the
-    dense-GEMM route for every activation kernel (PR-4 behaviour).
+    An activation-side (dense X) kernel whose warmup plan routed tasks to
+    the sparse engine is inlined as the capacity-padded block-skip route
+    (:class:`~repro.core.dispatch.ActivationDispatch` — zero blocks of the
+    intermediate features are skipped with FIXED shapes, budgeted at
+    ``activation_slack`` headroom over the warmup's stored blocks — per
+    stripe when ``activation_per_stripe`` (default), so skewed activations
+    don't pad every stripe to the densest one's need; a batch that
+    overflows the budget falls back to a dense GEMM inside the same
+    program, never a retrace).  When the Analyzer sent everything to the
+    dense engine — dense activations win — or the pool would not fit the
+    device, the kernel stays one dense Pallas GEMM.
+    ``activation_skip=False`` forces the dense-GEMM route for every
+    activation kernel of the program; the warmup pass itself runs the
+    engine's default routes.
 
-    ``None`` (second element) when any adjacency kernel has no compiled
-    dispatch — non-literal/non-batched engines, canvas-misaligned geometry
-    — in which case the caller keeps the eager path.
+    ``None`` (second element) for a non-literal engine, which runs plain
+    ``jax.numpy`` and has no kernels to record.
 
     ``transport`` optionally wraps the abstract ``mm`` with a representation
     transform (the serving layer's column-stack/row-unstack transport) and
@@ -277,56 +278,42 @@ def compile_model(model: str, engine: DynasparseEngine, adj, h, params,
     """
     transport = transport if transport is not None else (lambda mm: mm)
     h = jnp.asarray(h)
+    if not engine.literal:
+        return APPLY[model](transport(engine_mm(engine)), adj, h, params), None
+    # the warmup's own routes serve the program unless its activation
+    # options differ from the eager pass's (``DynasparseEngine.route``'s
+    # defaults)
+    options = dict(activation_skip=activation_skip, slack=activation_slack,
+                   per_stripe=activation_per_stripe)
+    same_as_eager = options == dict(activation_skip=True,
+                                    slack=ACTIVATION_SLACK, per_stripe=True)
     # ("sparse", geom) | ("shard", (geom, band_rows, halo)) | ("act", geom)
     # | ("gemm", None) per kernel
     records: list[tuple[str, object]] = []
     payload: list = []
     lowered: list = []             # each adjacency kernel's dispatch
-    compilable = [True]
     n0 = len(engine.report.kernels)
 
     def recording(x, y, name="kernel"):
         z, _ = engine.matmul(x, y, name=name)
-        if isinstance(x, SparseCOO):
-            if engine.mesh is not None:
-                spair = engine.sharded_operands(engine.last_plan, x)
-                if spair is None:
-                    compilable[0] = False
-                    records.append(("gemm", None))
-                    payload.append(None)
-                else:
-                    sd, xd = spair
-                    lowered.append(sd)
-                    records.append(("shard",
-                                    (sd.geom, sd.band_rows, sd.halo)))
-                    payload.append({"arrays": dict(sd.arrays), "xd": xd})
-                return z
-            pair = engine.compiled_operands(engine.last_plan, x)
-            if pair is None:
-                compilable[0] = False
-                records.append(("gemm", None))
-                payload.append(None)
-            else:
-                d, xd = pair
-                lowered.append(d)
-                records.append(("sparse", d.geom))
-                payload.append({"arrays": dict(d.arrays), "xd": xd})
+        kind, d = engine.last_route
+        if kind in ("act", "gemm") and not same_as_eager:
+            kind, d = engine.route(engine.last_plan, x, **options)
+        if kind == "gemm":
+            records.append((kind, None))
+            payload.append(None)
+        elif kind == "act":
+            records.append((kind, d.geom))
+            payload.append({"arrays": dict(d.arrays)})
         else:
-            ad = (engine.activation_dispatch_for(
-                      engine.last_plan, x, slack=activation_slack,
-                      per_stripe=activation_per_stripe)
-                  if activation_skip else None)
-            if ad is None:
-                records.append(("gemm", None))
-                payload.append(None)
-            else:
-                records.append(("act", ad.geom))
-                payload.append({"arrays": dict(ad.arrays)})
+            lowered.append(d)
+            records.append((kind, (d.geom, d.band_rows, d.halo)
+                            if kind == "shard" else d.geom))
+            payload.append({"arrays": dict(d.arrays),
+                            "xd": engine.dense_x(engine.last_plan, x, d)})
         return z
 
     logits = APPLY[model](transport(recording), adj, h, params)
-    if not compilable[0]:
-        return logits, None
 
     def replay(payload_, hh):
         # resolved when the program is traced, for the backend it targets

@@ -32,7 +32,7 @@ GraphAGILE compile-once/serve-many overlay property).
 
 Degraded-mode serving (the failure half of the lifecycle)::
 
-    compiled program fails   ──► eager batched fallback (degraded_batches)
+    compiled program fails   ──► eager fallback (degraded_batches)
     eager batch fails        ──► bisect into halves (bisections) until the
                                  poison request fails ALONE
     single request fails     ──► bounded backoff retries (retries), then
@@ -100,8 +100,8 @@ class ServingConfig:
     # ``models.gnn.compile_model``; every later batch is ONE jitted call with
     # zero host descriptor work.  The input-density sketch invalidates the
     # compiled program on drift (the eager re-run replans, then recompiles).
-    # Engines the compiler declines (non-literal, misaligned geometry,
-    # eps-thresholded SpMM) transparently stay eager.
+    # Non-literal engines, which run plain jnp and have no program to
+    # compile, stay eager.
     compile_models: bool = True
     # Bound on retained compiled programs (insertion-order eviction): the
     # registry pins descriptor/operand arrays outside the byte-accounted
@@ -343,13 +343,12 @@ class ServingEngine:
             shared = cache if cache is not None else get_shared_cache()
             if config.n_devices is not None:
                 from repro.launch.mesh import make_data_mesh
-                # mesh serving implies the literal batched engine — the
-                # sharded path is a compiled-dispatch route; a non-literal
-                # mesh engine would silently fall back to single-device
-                # eager execution
+                # mesh serving implies the literal engine — the sharded
+                # path is a compiled-dispatch route; a non-literal mesh
+                # engine would silently run single-device jnp
                 engine = DynasparseEngine(
                     cache=shared, mesh=make_data_mesh(config.n_devices),
-                    literal=True, batched=True,
+                    literal=True,
                     operand_sharding=config.operand_sharding,
                     faults=config.faults)
             else:
@@ -735,7 +734,7 @@ class ServingEngine:
         back on their loop.  Raises on failure — the ladder above decides
         whether to bisect, retry or quarantine.  One degradation happens
         HERE: a compiled program that fails mid-call falls back to the
-        eager batched path for this batch (``degraded_batches``), keeping
+        eager path for this batch (``degraded_batches``), keeping
         the program for the next batch (a transient executor fault should
         not force a recompile).
         """
@@ -808,7 +807,7 @@ class ServingEngine:
                             self.stats.record_activation(summary)
                 except Exception:
                     # degraded mode: compiled call failed → serve THIS batch
-                    # on the eager batched path (program kept — see above)
+                    # on the eager path (program kept — see above)
                     degraded = True
                     self.engine.reset()
                     logits = gnn.APPLY[self.model](

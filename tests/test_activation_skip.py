@@ -5,9 +5,10 @@ a plain dense GEMM; the capacity-padded BlockCSR route packs the activation
 ON DEVICE into a fixed stored-block budget so compiled programs skip zero
 blocks of intermediate features with fixed shapes.  These tests pin:
 
-- bit-identity of the compiled block-skip route against BOTH eager paths
-  (batched host-packed and per-task) across ragged shapes, primitives, eps
-  values, dtypes, and capacities (exact / slack);
+- bit-identity of the compiled block-skip route against its unfused
+  reference (host-packed, ``spmm_reference``) across ragged
+  shapes, primitives, eps values, dtypes, and capacities (exact / slack),
+  and the engine's eager pass running that same route;
 - the overflow semantics: a batch past the budget takes the dense-GEMM
   fallback INSIDE the same program (bit-identical to the plain dense route),
   never a retrace;
@@ -22,10 +23,10 @@ import pytest
 
 from repro.core import DynasparseEngine, SparseCOO
 from repro.core import dispatch as dispatch_mod
-from repro.core.scheduler import execute_plan
 from repro.kernels import ops
 from repro.kernels.formats import BlockCSR, pack_blockcsr
 from repro.models import gnn
+from spmm_reference import activation_reference
 
 
 def _block_sparse(rng, m, k, block_density, *, block=8, dtype=np.float32):
@@ -38,19 +39,19 @@ def _block_sparse(rng, m, k, block_density, *, block=8, dtype=np.float32):
     return x.astype(dtype)
 
 
+def _reference(eng, plan, xd, yd):
+    return activation_reference(plan, xd, yd, eps=eng.eps, block=eng.block)
+
+
 def _routes(eng, xd, yd, *, capacity=None, slack=1.5):
-    """(plan, act dispatch, compiled z, diag, eager batched z, per-task z)."""
+    """(plan, act dispatch, compiled z, diag, reference z)."""
     plan = eng.plan(xd, jnp.asarray(yd))
     ad = eng.activation_dispatch_for(plan, xd, capacity=capacity, slack=slack)
     if ad is None:
-        return plan, None, None, None, None, None
+        return plan, None, None, None, None
     z_a, diag = dispatch_mod.execute_activation(
         ad, xd, yd, interpret=True, stats=eng.cache.stats)
-    z_b = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                       batched=True, eps=eng.eps)
-    z_p = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                       batched=False, eps=eng.eps)
-    return plan, ad, np.asarray(z_a), diag, np.asarray(z_b), np.asarray(z_p)
+    return plan, ad, np.asarray(z_a), diag, _reference(eng, plan, xd, yd)
 
 
 # ------------------------------------------------------------ kernel level
@@ -69,12 +70,15 @@ def test_activation_route_bit_identical_to_eager_paths(tm, tn, mkn, bd,
     yd = (rng.normal(size=(K, N)) *
           (rng.uniform(size=(K, N)) < 0.5)).astype(np.float32)
     eng = DynasparseEngine(tile_m=tm, tile_n=tn, literal=True, eps=eps)
-    plan, ad, z_a, diag, z_b, z_p = _routes(eng, xd, yd)
+    plan, ad, z_a, diag, z_p = _routes(eng, xd, yd)
     if ad is None:
         pytest.skip("plan routed no sparse tasks")
     assert not bool(diag["overflow"])
-    np.testing.assert_array_equal(z_a, z_b)
     np.testing.assert_array_equal(z_a, z_p)
+    # the eager pass runs the same route on the same budget
+    z_e = np.asarray(eng.execute(plan, xd, jnp.asarray(yd)))
+    assert eng.last_route == ("act", ad)
+    np.testing.assert_array_equal(z_e, z_a)
     if eps == 0.0:
         np.testing.assert_allclose(z_a, xd @ yd, rtol=1e-4, atol=1e-4)
 
@@ -86,11 +90,11 @@ def test_activation_route_skips_blocks():
     xd = _block_sparse(rng, 96, 64, 0.25)
     yd = rng.normal(size=(64, 16)).astype(np.float32)
     eng = DynasparseEngine(tile_m=32, tile_n=8, literal=True)
-    _, ad, z_a, diag, z_b, _ = _routes(eng, xd, yd)
+    _, ad, z_a, diag, z_p = _routes(eng, xd, yd)
     assert ad is not None
     assert int(diag["stored"]) < int(diag["logical"])
     assert int(diag["stored"]) <= int(diag["capacity"])
-    np.testing.assert_array_equal(z_a, z_b)
+    np.testing.assert_array_equal(z_a, z_p)
 
 
 @pytest.mark.parametrize("dtype", [np.float32, "bfloat16"])
@@ -102,10 +106,9 @@ def test_activation_route_dtypes(dtype):
     xd = _block_sparse(rng, 64, 32, 0.4, dtype=dtype)
     yd = rng.normal(size=(32, 16)).astype(np.float32)
     eng = DynasparseEngine(tile_m=16, tile_n=8, literal=True)
-    _, ad, z_a, _, z_b, z_p = _routes(eng, xd, yd)
+    _, ad, z_a, _, z_p = _routes(eng, xd, yd)
     if ad is None:
         pytest.skip("plan routed no sparse tasks")
-    np.testing.assert_array_equal(z_a, z_b)
     np.testing.assert_array_equal(z_a, z_p)
 
 
@@ -124,9 +127,9 @@ def test_capacity_exact_and_overflow_fallback():
                                             slack=1.0)
     assert need is not None and need > 1
 
-    _, ad, z_a, diag, z_b, _ = _routes(eng, xd, yd, capacity=need)
+    _, ad, z_a, diag, z_p = _routes(eng, xd, yd, capacity=need)
     assert ad.geom.cap == need and not bool(diag["overflow"])
-    np.testing.assert_array_equal(z_a, z_b)
+    np.testing.assert_array_equal(z_a, z_p)
 
     ad2 = eng.activation_dispatch_for(plan, xd, capacity=need - 1)
     z_o, diag2 = dispatch_mod.execute_activation(ad2, xd, yd, interpret=True)
@@ -161,9 +164,8 @@ def test_one_trace_serves_varying_sparsity_within_budget():
         z_a, diag = dispatch_mod.execute_activation(
             ad, xd, yd, interpret=True, stats=s)
         assert not bool(diag["overflow"])
-        z_b = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                           batched=True)
-        np.testing.assert_array_equal(np.asarray(z_a), np.asarray(z_b))
+        np.testing.assert_array_equal(np.asarray(z_a),
+                                      _reference(eng, plan, xd, yd))
     assert s.trace_builds == t0 + 1      # ONE trace for all three patterns
     assert s.trace_cache_hits >= 2
     assert s.act_builds == 1
@@ -411,9 +413,8 @@ def test_per_stripe_budgets_cut_waste_bit_identically():
                                                   interpret=True)
     assert not bool(diag_u["overflow"]) and not bool(diag_v["overflow"])
     np.testing.assert_array_equal(np.asarray(z_u), np.asarray(z_v))
-    z_b = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                       batched=True, eps=eng.eps)
-    np.testing.assert_array_equal(np.asarray(z_v), np.asarray(z_b))
+    np.testing.assert_array_equal(np.asarray(z_v),
+                                  _reference(eng, plan, xd, yd))
 
     stored = int(diag_v["stored"])
     waste_u = (int(diag_u["capacity"]) - stored) / max(stored, 1)
@@ -439,9 +440,8 @@ def test_per_stripe_budget_serves_jitter_without_overflow():
         xi = (xd * (rng.uniform(size=xd.shape) < 0.9)).astype(np.float32)
         z, diag = dispatch_mod.execute_activation(ad, xi, yd, interpret=True)
         assert not bool(diag["overflow"]), i
-        z_b = execute_plan(plan.part, plan.stq, plan.dtq, xi, yd,
-                           batched=True, eps=eng.eps)
-        np.testing.assert_array_equal(np.asarray(z), np.asarray(z_b))
+        np.testing.assert_array_equal(np.asarray(z),
+                                      _reference(eng, plan, xi, yd))
         # same dispatch replayed — no rebuilds per batch
         assert eng.cache.stats.act_builds == builds0
 
@@ -509,7 +509,6 @@ def test_pool_past_the_device_runs_as_one_dense_gemm(monkeypatch):
     """A dense operand whose block pool would not fit the device takes the
     dense GEMM route, in the eager pass and in the compiled program, and
     still matches the reference."""
-    from repro.core import scheduler
     rng = np.random.default_rng(41)
     adj = _block_sparse_graph(rng)
     h = _block_sparse(rng, 80, 12, 0.35)
@@ -523,12 +522,10 @@ def test_pool_past_the_device_runs_as_one_dense_gemm(monkeypatch):
     assert act and all(n_stq for _, n_stq in act)
     small = DynasparseEngine(tile_m=16, tile_n=8, literal=True)
     small.device = lambda: _Device(2)
-    plain = scheduler.execute_plan
 
-    def dense_only(part, stq, dtq, *args, **kw):
-        assert not stq, "the eager pass packed a pool that cannot fit"
-        return plain(part, stq, dtq, *args, **kw)
-    monkeypatch.setattr(scheduler, "execute_plan", dense_only)
+    def no_pool(*args, **kw):
+        raise AssertionError("the eager pass packed a pool that cannot fit")
+    monkeypatch.setattr(dispatch_mod, "execute_activation", no_pool)
     warm, cm = gnn.compile_model("GCN", small, adj, jnp.asarray(h), params)
     assert cm is not None and cm.n_act == 0
     # the same plans, with their sparse tasks, now lowered as dense GEMMs

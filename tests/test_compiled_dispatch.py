@@ -3,13 +3,13 @@
 A planned kernel is lowered ONCE into a device-resident CompiledDispatch
 (sorted descriptor arrays + pooled blocks, vectorized numpy build) and every
 later execute is a single jitted call.  These tests pin the load-bearing
-properties: bit-identity against the exact eager reference of the lowering
+properties: bit-identity against the exact unfused reference of the lowering
 (``spmm_reference``: GEMM and SpDMM as planned, SpMM tasks as the SpDMM
-stripe walk) and agreement with BOTH existing paths (eager batched and
-per-task, whose SpMM intersects Y's structure) across ragged/mixed-primitive
-geometries, the stripe walk's grid-step count, zero host descriptor work in
-steady state, honest cache accounting/eviction, the decline gates
-(misaligned canvas), and the whole-model compiler.
+stripe walk) and agreement with the per-task reference (whose SpMM
+intersects Y's structure) across ragged/mixed-primitive geometries, the
+stripe walk's grid-step count, zero host descriptor work in steady state,
+honest cache accounting/eviction, the refusal of misaligned geometry, and
+the whole-model compiler.
 """
 import dataclasses
 
@@ -21,12 +21,12 @@ from repro.core import DynasparseEngine, SparseCOO
 from repro.core import dispatch as dispatch_mod
 from repro.core.partition import choose_tile, make_tasks
 from repro.core.plancache import PlanCache
-from repro.core.scheduler import execute_plan
 from repro.data import graphs
 from repro.data.graphs import load_graph
 from repro.kernels.formats import pack_blockcsr_coo
 from repro.models import gnn
-from spmm_reference import eps_masked, stripe_walk_reference
+from spmm_reference import (eps_masked, pertask_reference,
+                            stripe_walk_reference)
 
 RNG = np.random.default_rng(31)
 
@@ -51,33 +51,31 @@ def _mixed_ragged_operands(seed=1, M=90, K=64, N=44):
 
 
 def _all_paths(eng, xd, yd):
-    """(compiled, exact reference, eager batched, per-task) results of one
+    """(compiled, exact reference, per-task reference) results of one
     planned kernel."""
     x = _coo_of(xd)
     plan = eng.plan(x, jnp.asarray(yd))
     z_c = eng.execute(plan, x, jnp.asarray(yd))
     z_r = stripe_walk_reference(plan, xd, yd)
-    z_b = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd, batched=True)
-    z_p = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd, batched=False)
-    return plan, np.asarray(z_c), z_r, np.asarray(z_b), np.asarray(z_p)
+    z_p = pertask_reference(plan.part, plan.stq, plan.dtq, xd, yd)
+    return plan, np.asarray(z_c), z_r, z_p
 
 
-def _assert_matches(z_c, z_r, z_b, z_p):
-    """Bitwise the exact reference; the structure-intersecting eager paths
-    within float32 rounding."""
+def _assert_matches(z_c, z_r, z_p):
+    """Bitwise the exact reference; the structure-intersecting per-task
+    reference within float32 rounding."""
     np.testing.assert_array_equal(z_c, z_r)
-    np.testing.assert_allclose(z_c, z_b, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(z_c, z_p, rtol=1e-4, atol=1e-4)
 
 
 def test_compiled_mixed_primitives_ragged_bitwise():
     xd, yd = _mixed_ragged_operands()
     eng = DynasparseEngine(tile_m=32, tile_n=24, literal=True)
-    plan, z_c, z_r, z_b, z_p = _all_paths(eng, xd, yd)
+    plan, z_c, z_r, z_p = _all_paths(eng, xd, yd)
     prims = {t.primitive for t in plan.stq} | {t.primitive for t in plan.dtq}
     assert prims == {"SpDMM", "SpMM", "GEMM"}, prims
     assert eng.cache.stats.dispatch_builds == 1   # compiled path was taken
-    _assert_matches(z_c, z_r, z_b, z_p)
+    _assert_matches(z_c, z_r, z_p)
     np.testing.assert_allclose(z_c, xd @ yd, rtol=1e-4, atol=1e-4)
 
 
@@ -95,8 +93,8 @@ def test_compiled_bit_identity_across_geometries(tm, tn, mkn, seed):
     yd = (rng.normal(size=(K, N)) *
           (rng.uniform(size=(K, N)) < 0.5)).astype(np.float32)
     eng = DynasparseEngine(tile_m=tm, tile_n=tn, literal=True)
-    _, z_c, z_r, z_b, z_p = _all_paths(eng, xd, yd)
-    _assert_matches(z_c, z_r, z_b, z_p)
+    _, z_c, z_r, z_p = _all_paths(eng, xd, yd)
+    _assert_matches(z_c, z_r, z_p)
     np.testing.assert_allclose(z_c, xd @ yd, rtol=1e-4, atol=1e-4)
 
 
@@ -133,7 +131,8 @@ def test_eps_spmm_compiles_bit_identically(eps):
     compilation and silently stay eager.  The eps mask (sub-eps Y blocks
     zeroed inside the traced program) lifts the gate: such plans compile,
     and the result is bit-identical to the exact reference on the
-    eps-masked Y and agrees with both eager paths under the same eps."""
+    eps-masked Y and agrees with the per-task reference under the same
+    eps."""
     xd, yd = _mixed_ragged_operands(seed=4)
     x = _coo_of(xd)
     eng = DynasparseEngine(tile_m=32, tile_n=24, literal=True, eps=eps)
@@ -143,13 +142,10 @@ def test_eps_spmm_compiles_bit_identically(eps):
     assert eng.dispatch_for(plan, x) is not None
     z_c = eng.execute(plan, x, jnp.asarray(yd))
     assert eng.cache.stats.dispatch_builds == 1
-    z_b = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                       batched=True, eps=eps)
-    z_p = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                       batched=False, eps=eps)
     _assert_matches(np.asarray(z_c),
                     stripe_walk_reference(plan, xd, yd, eps=eps),
-                    np.asarray(z_b), np.asarray(z_p))
+                    pertask_reference(plan.part, plan.stq, plan.dtq, xd, yd,
+                                      eps=eps))
     if eps <= 1e-6:     # tolerance below the operands' magnitude floor:
         np.testing.assert_allclose(np.asarray(z_c), xd @ yd,   # == dense
                                    rtol=1e-4, atol=1e-4)
@@ -259,8 +255,8 @@ def test_eps_mask_drops_sub_eps_y_blocks_in_stripe_walk():
     """eps != 0 on stripes 32 wide, every task SpMM: Y blocks whose
     magnitudes are all <= eps are zeroed before the stripe walk, as the
     eager pack drops them.  Bitwise the exact reference on the masked Y,
-    the eager SpMM within float32 rounding, and measurably not the product
-    with the unmasked Y."""
+    the per-task SpMM within float32 rounding, and measurably not the
+    product with the unmasked Y."""
     eps = 0.2
     rng = np.random.default_rng(12)
     M, K, N = 48, 40, 64
@@ -277,9 +273,8 @@ def test_eps_mask_drops_sub_eps_y_blocks_in_stripe_walk():
     assert eng.cache.stats.dispatch_builds == 1
     np.testing.assert_array_equal(
         z_c, stripe_walk_reference(plan, xd, yd, eps=eps))
-    z_b = np.asarray(execute_plan(plan.part, plan.stq, [], xd, yd,
-                                  batched=True, eps=eps))
-    np.testing.assert_allclose(z_c, z_b, rtol=1e-4, atol=1e-4)
+    z_p = pertask_reference(plan.part, plan.stq, [], xd, yd, eps=eps)
+    np.testing.assert_allclose(z_c, z_p, rtol=1e-4, atol=1e-4)
     xm = eps_masked(xd, eps)
     np.testing.assert_allclose(z_c, xm @ eps_masked(yd, eps),
                                rtol=1e-4, atol=1e-4)
@@ -288,17 +283,23 @@ def test_eps_mask_drops_sub_eps_y_blocks_in_stripe_walk():
 
 def test_misaligned_geometry_declines_compiled_but_matches():
     """tile_m=12 interior boundaries can't take the in-place index maps:
-    no dispatch is built and execution falls through the existing paths."""
+    the engine refuses the geometry before it plans or lowers anything,
+    and the same kernel at an aligned tile compiles and matches."""
     rng = np.random.default_rng(3)
     xd = (rng.normal(size=(36, 24)) *
           (rng.uniform(size=(36, 24)) < 0.3)).astype(np.float32)
     yd = rng.normal(size=(24, 16)).astype(np.float32)
     x = _coo_of(xd)
     eng = DynasparseEngine(tile_m=12, tile_n=8, literal=True)
-    plan = eng.plan(x, jnp.asarray(yd))
-    assert eng.dispatch_for(plan, x) is None
-    z, _ = eng.matmul(x, jnp.asarray(yd))
+    with pytest.raises(ValueError, match="tile_m=12"):
+        eng.plan(x, jnp.asarray(yd))
+    with pytest.raises(ValueError, match="lcm"):
+        eng.matmul(x, jnp.asarray(yd))
+    assert eng.cache.plan_count() == 0
     assert eng.cache.stats.dispatch_builds == 0
+    aligned = DynasparseEngine(tile_m=16, tile_n=8, literal=True)
+    z, _ = aligned.matmul(x, jnp.asarray(yd))
+    assert aligned.cache.stats.dispatch_builds == 1
     np.testing.assert_allclose(np.asarray(z), xd @ yd, rtol=1e-4, atol=1e-4)
 
 
@@ -388,3 +389,83 @@ def test_compile_model_declines_on_nonliteral_engine():
     ref = gnn.run_reference("SGC", adj, jnp.asarray(h), params)
     np.testing.assert_allclose(np.asarray(warm), np.asarray(ref),
                                rtol=1e-3, atol=1e-3)
+
+
+def _served_operands(rng, n=80, nnz=240, feat=12):
+    """A graph and block-sparse features, so GCN's transform plans sparse
+    tasks on a dense operand (the ``act`` route) beside the adjacency's."""
+    flat = np.sort(rng.choice(n * n, size=nnz, replace=False))
+    adj = SparseCOO((n, n), jnp.asarray((flat // n).astype(np.int32)),
+                    jnp.asarray((flat % n).astype(np.int32)),
+                    jnp.asarray(np.abs(rng.normal(size=nnz)
+                                       ).astype(np.float32)),
+                    tag="adjacency")
+    mask = np.kron(rng.uniform(size=(-(-n // 8), -(-feat // 8))) < 0.35,
+                   np.ones((8, 8)))[:n, :feat]
+    h = (rng.normal(size=(n, feat)) * mask).astype(np.float32)
+    return adj, jnp.asarray(h)
+
+
+@pytest.mark.parametrize("model,n_devices", [
+    ("GCN", None), ("GIN", None), ("GraphSAGE", None), ("GCN", 1)])
+def test_execute_and_compile_model_take_the_same_route(model, n_devices):
+    """Every kernel of the warmup pass runs ``engine.execute`` through
+    the route ``compile_model`` records for it: the same kind (``sparse``
+    or, on a mesh, ``shard``; ``act``; ``gemm``) and the same descriptor
+    arrays, not a second lowering."""
+    from repro.launch.mesh import make_data_mesh
+
+    adj, h = _served_operands(np.random.default_rng(23))
+    params = gnn.init_params(model, 12, 8, 5)
+    mesh = None if n_devices is None else make_data_mesh(n_devices)
+    eng = DynasparseEngine(tile_m=16, tile_n=8, literal=True, mesh=mesh)
+    routes = []
+    execute = eng.execute
+
+    def spy(plan, x, y):
+        z = execute(plan, x, y)
+        routes.append(eng.last_route)
+        return z
+    eng.execute = spy
+    _, cm = gnn.compile_model(model, eng, adj, h, params)
+    assert cm is not None and len(routes) == cm.n_kernels
+    kinds = []
+    for (kind, d), p in zip(routes, cm.payload):
+        kinds.append(kind)
+        if kind == "gemm":
+            assert p is None and d is None
+            continue
+        assert ("xd" in p) == (kind in ("sparse", "shard"))
+        assert all(p["arrays"][k] is v for k, v in d.arrays.items())
+    sparse = "sparse" if mesh is None else "shard"
+    assert set(kinds) <= {sparse, "act", "gemm"} and sparse in kinds
+    assert cm.n_sparse == kinds.count(sparse)
+    assert cm.n_act == kinds.count("act")
+    if model == "GCN":
+        assert "act" in kinds
+
+
+@pytest.mark.parametrize("model", gnn.MODELS)
+def test_warm_batch_logits_equal_next_compiled_batch(model):
+    """The eager warmup batch and the compiled batch after it run the same
+    routes on the same input, with the activation block-skip on: their
+    logits agree bit for bit, request by request."""
+    from repro.serving import ServingConfig, ServingEngine, SharedPlanCache
+
+    rng = np.random.default_rng(29)
+    adj, h = _served_operands(rng)
+    params = gnn.init_params(model, 12, 8, 5)
+    reqs = [("g", h * (1.0 + 0.1 * i)) for i in range(4)]
+    eng = DynasparseEngine(tile_m=16, tile_n=8, literal=True,
+                           cache=SharedPlanCache())
+    with ServingEngine(model, params, engine=eng, config=ServingConfig(
+            max_batch=4, activation_skip=True)) as srv:
+        srv.register_graph("g", adj)
+        warm = srv.serve(reqs)
+        assert srv.stats.compiled_batches == 0
+        compiled = srv.serve(reqs)
+        assert srv.stats.compiled_batches == 1
+    if model == "GCN":
+        assert srv.dispatch_stats()["act_kernels_last"] >= 1
+    for a, b in zip(warm, compiled):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -2,24 +2,37 @@
 
 The fused kernels' output index maps scatter every task's tile directly
 into the final padded ``(M, N)`` canvas of the plan's partition, chained
-across primitives via output aliasing — ``_execute_batched`` assembles with
+across primitives via output aliasing — a compiled dispatch assembles with
 ONE slice, no per-task ``.at[].set`` scatter.  These tests pin the
-load-bearing properties: bit-identical results vs the per-task path (all
-three primitives, ragged edge tiles), zero-retention for tiles no task
-covers, and the per-task fallback for misaligned hand-built geometry.
+load-bearing properties: bit-identical results vs the exact unfused
+references (all three primitives, ragged edge tiles), zero-retention for
+tiles no task covers, and the refusal of misaligned geometry.
 """
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
 from repro.core import DynasparseEngine, SparseCOO
+from repro.core import dispatch as dispatch_mod
 from repro.core.partition import make_tasks
-from repro.core.scheduler import execute_plan
+from repro.core.plancache import StructureEntry
 from repro.core import sparsity
 from repro.kernels import ops
-from spmm_reference import section_reference
+from spmm_reference import (pertask_reference, section_reference,
+                            stripe_walk_reference)
 
 RNG = np.random.default_rng(17)
+
+
+def _run(part, stq, dtq, xd, yd):
+    """The compiled dispatch of a (hand-built) assignment, run once."""
+    stripes = {i: ops.pack_blockcsr(np.asarray(xd)[i * part.tile_m:
+                                                   (i + 1) * part.tile_m], 8)
+               for i in {t.i for t in stq}}
+    d = dispatch_mod.build_dispatch(part, stq, dtq, stripes, block=8)
+    return np.asarray(dispatch_mod.apply_dispatch(
+        d.geom, d.arrays, jnp.asarray(xd) if dtq else None,
+        jnp.asarray(yd), interpret=True))
 
 
 def _mixed_ragged_plan():
@@ -41,24 +54,26 @@ def _mixed_ragged_plan():
 
 
 def test_inplace_mixed_primitives_ragged_bitwise():
-    """Batched in-place assembly == per-task path, bit for bit, on a plan
-    mixing GEMM/SpDMM/SpMM with ragged row and column edge tiles."""
+    """In-place assembly == its exact unfused reference, bit for bit, on a
+    plan mixing GEMM/SpDMM/SpMM with ragged row and column edge tiles; the
+    per-task reference within float32 rounding (its SpMM pairs 8x8 Y
+    blocks where the dispatch walks column stripes)."""
     plan, xd, yd = _mixed_ragged_plan()
     prims = {t.primitive for t in plan.stq} | {t.primitive for t in plan.dtq}
     assert prims == {"SpDMM", "SpMM", "GEMM"}, prims
 
-    z_b = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd, batched=True)
-    z_p = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd, batched=False)
-    np.testing.assert_array_equal(np.asarray(z_b), np.asarray(z_p))
-    np.testing.assert_allclose(np.asarray(z_b), xd @ yd,
-                               rtol=1e-4, atol=1e-4)
+    z_b = _run(plan.part, plan.stq, plan.dtq, xd, yd)
+    np.testing.assert_array_equal(z_b, stripe_walk_reference(plan, xd, yd))
+    z_p = pertask_reference(plan.part, plan.stq, plan.dtq, xd, yd)
+    np.testing.assert_allclose(z_b, z_p, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(z_b, xd @ yd, rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("primitive", ["GEMM", "SpDMM", "SpMM"])
 def test_inplace_single_primitive_ragged_bitwise(primitive):
     """Each fused kernel alone must scatter every tile — including the
-    ragged edge tiles — into the right canvas region, matching the per-task
-    path bit for bit."""
+    ragged edge tiles — into the right canvas region, matching its exact
+    unfused reference bit for bit, in ONE launch."""
     rng = np.random.default_rng(7)
     M, K, N = 40, 32, 20            # tiles 16/8 -> extents 16/16/8, 8/8/4
     xd = (rng.normal(size=(M, K)) *
@@ -76,21 +91,19 @@ def test_inplace_single_primitive_ragged_bitwise(primitive):
     dtq = [t for t in part.tasks if t.queue == "DTQ"]
 
     ops.reset_pallas_call_count()
-    z_b = execute_plan(part, stq, dtq, xd, yd, batched=True)
+    z_b = _run(part, stq, dtq, xd, yd)
     assert ops.pallas_call_count() == 1          # ONE fused launch
-    z_p = execute_plan(part, stq, dtq, xd, yd, batched=False)
-    if primitive == "SpDMM":
+    z_p = pertask_reference(part, stq, dtq, xd, yd)
+    if primitive == "GEMM":
+        np.testing.assert_array_equal(z_b, z_p)
+    else:
         # the tasks cover every column tile, so the section walks whole
         # 24-wide rows: bitwise its unfused reference, and the per-task
-        # path's 8-wide dots within float32 rounding
+        # reference's 8-wide dots within float32 rounding
         z_r = section_reference(part, stq, xd, yd, whole_rows=True)[:M, :N]
-        np.testing.assert_array_equal(np.asarray(z_b), z_r)
-        np.testing.assert_allclose(np.asarray(z_b), np.asarray(z_p),
-                                   rtol=1e-5, atol=1e-5)
-    else:
-        np.testing.assert_array_equal(np.asarray(z_b), np.asarray(z_p))
-    np.testing.assert_allclose(np.asarray(z_b), xd @ yd,
-                               rtol=1e-4, atol=1e-4)
+        np.testing.assert_array_equal(z_b, z_r)
+        np.testing.assert_allclose(z_b, z_p, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(z_b, xd @ yd, rtol=1e-4, atol=1e-4)
 
 
 def test_uncovered_tiles_stay_zero():
@@ -99,8 +112,7 @@ def test_uncovered_tiles_stay_zero():
     plan, xd, yd = _mixed_ragged_plan()
     part = plan.part
     # drain ONLY the sparse queue: every dense-queue tile region must be 0
-    z = execute_plan(part, plan.stq, [], xd, yd, batched=True)
-    z = np.asarray(z)
+    z = _run(part, plan.stq, [], xd, yd)
     tm, tn = part.tile_m, part.tile_n
     for task in plan.dtq:
         mi, dj = part.row_extent(task.i), part.col_extent(task.j)
@@ -108,8 +120,7 @@ def test_uncovered_tiles_stay_zero():
                  task.j * tn: task.j * tn + dj]
         np.testing.assert_array_equal(tile, np.zeros_like(tile))
     # and the sparse-queue tiles are untouched by the omission
-    z_full = np.asarray(execute_plan(part, plan.stq, plan.dtq, xd, yd,
-                                     batched=True))
+    z_full = _run(part, plan.stq, plan.dtq, xd, yd)
     for task in plan.stq:
         mi, dj = part.row_extent(task.i), part.col_extent(task.j)
         np.testing.assert_array_equal(
@@ -121,9 +132,9 @@ def test_uncovered_tiles_stay_zero():
 
 def test_misaligned_tiles_fall_back_and_match():
     """Hand-built geometry whose interior tile boundaries are not
-    lcm(block, 8)-aligned cannot use the in-place index maps; batched
-    execution must transparently fall back to the per-task path and still
-    be correct."""
+    lcm(block, 8)-aligned cannot use the in-place index maps: lowering it
+    raises ValueError, for the compiled and the activation route alike,
+    and an engine refuses such tiles before it plans."""
     rng = np.random.default_rng(3)
     M, K, N = 36, 24, 16
     xd = (rng.normal(size=(M, K)) *
@@ -139,18 +150,33 @@ def test_misaligned_tiles_fall_back_and_match():
     stq = [t for t in part.tasks if t.queue == "STQ"]
     dtq = [t for t in part.tasks if t.queue == "DTQ"]
 
-    z_b = execute_plan(part, stq, dtq, xd, yd, batched=True)
-    z_p = execute_plan(part, stq, dtq, xd, yd, batched=False)
-    np.testing.assert_array_equal(np.asarray(z_b), np.asarray(z_p))
-    np.testing.assert_allclose(np.asarray(z_b), xd @ yd,
-                               rtol=1e-4, atol=1e-4)
+    with pytest.raises(ValueError, match="lcm"):
+        _run(part, stq, dtq, xd, yd)
+    with pytest.raises(ValueError, match="lcm"):
+        dispatch_mod.build_activation_dispatch(part, stq, dtq, block=8,
+                                               capacity=4)
+    with pytest.raises(ValueError, match="tile_m=12"):
+        DynasparseEngine(tile_m=tm, tile_n=tn, literal=True).plan(
+            jnp.asarray(xd), jnp.asarray(yd))
+    # the same queues on an aligned geometry lower and match
+    row_d8 = np.asarray(sparsity.stripe_density(jnp.asarray(xd), 8, axis=0))
+    part8 = make_tasks("k", M, K, N, row_d8, col_d, 8, tn)
+    for t in part8.tasks:
+        t.primitive = "SpDMM" if (t.i + t.j) % 2 else "GEMM"
+        t.queue = "STQ" if t.primitive == "SpDMM" else "DTQ"
+    stq8 = [t for t in part8.tasks if t.queue == "STQ"]
+    dtq8 = [t for t in part8.tasks if t.queue == "DTQ"]
+    z = _run(part8, stq8, dtq8, xd, yd)
+    np.testing.assert_array_equal(z, pertask_reference(part8, stq8, dtq8,
+                                                       xd, yd))
+    np.testing.assert_allclose(z, xd @ yd, rtol=1e-4, atol=1e-4)
 
 
 def test_misaligned_tiles_sparse_only_engine_uses_packed_fallback():
-    """An engine with misaligned tile sizes and an all-sparse plan executes
-    with x=None (graph-scale mode: only packed stripes exist).  The
-    per-task fallback must consume those packed stripes instead of
-    demanding a dense operand."""
+    """An engine with misaligned tile sizes is refused at planning, before
+    it packs or densifies anything; with aligned tiles an all-sparse plan
+    executes from the packed stripes alone (graph-scale mode: the operand
+    is never densified)."""
     rng = np.random.default_rng(9)
     n, nnz = 36, 60
     flat = np.sort(rng.choice(n * n, size=nnz, replace=False))
@@ -163,7 +189,15 @@ def test_misaligned_tiles_sparse_only_engine_uses_packed_fallback():
     y = rng.normal(size=(n, 8)).astype(np.float32)
     eng = DynasparseEngine(tile_m=12, tile_n=8, literal=True,
                            mode="sparse_only")
+    with pytest.raises(ValueError, match="tile_m=12"):
+        eng.matmul(adj, jnp.asarray(y))
+    assert len(eng.cache) == 0
+    eng = DynasparseEngine(tile_m=16, tile_n=8, literal=True,
+                           mode="sparse_only")
     z, _ = eng.matmul(adj, jnp.asarray(y))
+    assert eng.last_route[0] == "sparse" and not eng.last_route[1].needs_x
+    assert all(v.dense is None for v, _ in eng.cache._entries.values()
+               if isinstance(v, StructureEntry))
     np.testing.assert_allclose(np.asarray(z), adj.todense() @ y,
                                rtol=1e-4, atol=1e-4)
 

@@ -1,14 +1,17 @@
-"""Plan/execute split: batched per-queue dispatch equivalence + PlanCache
-behaviour (the paper's amortized Alg. 4 preprocessing)."""
+"""Plan/execute split: the engine's per-queue fused dispatch against the
+per-task reference, and PlanCache behaviour (the paper's amortized Alg. 4
+preprocessing)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import DynasparseEngine, SparseCOO
-from repro.core.scheduler import ScheduleReport, execute_plan
+from repro.core import dispatch as dispatch_mod
+from repro.core.scheduler import ScheduleReport
 from repro.core import sparsity
 from repro.kernels import ops
 from repro.models import gnn
+from spmm_reference import pertask_reference
 
 RNG = np.random.default_rng(99)
 
@@ -27,13 +30,21 @@ def _rand_graph(n=80, nnz=240, seed=5):
 # --------------------------------------------------- batched == per-task
 @pytest.mark.parametrize("model", gnn.MODELS)
 def test_batched_dispatch_matches_pertask_and_reference(model):
+    """The whole model on the engine's routes against the same model with
+    every kernel's plan run task by task (``pertask_reference``)."""
     adj = _rand_graph()
     h = RNG.normal(size=(80, 12)).astype(np.float32)
     params = gnn.init_params(model, 12, 8, 5)
-    eng_b = DynasparseEngine(tile_m=16, tile_n=8, literal=True, batched=True)
-    eng_p = DynasparseEngine(tile_m=16, tile_n=8, literal=True, batched=False)
+    eng_b = DynasparseEngine(tile_m=16, tile_n=8, literal=True)
     z_b, _ = gnn.run_inference(model, eng_b, adj, jnp.asarray(h), params)
-    z_p, _ = gnn.run_inference(model, eng_p, adj, jnp.asarray(h), params)
+    planner = DynasparseEngine(tile_m=16, tile_n=8, literal=True)
+
+    def pertask_mm(x, y, name="kernel"):
+        p = planner.plan(x, y, name=name)
+        xd = x.todense() if isinstance(x, SparseCOO) else x
+        return jnp.asarray(pertask_reference(p.part, p.stq, p.dtq, xd, y))
+
+    z_p = gnn.APPLY[model](pertask_mm, adj, jnp.asarray(h), params)
     ref = gnn.run_reference(model, adj, jnp.asarray(h), params)
     np.testing.assert_allclose(np.asarray(z_b), np.asarray(z_p),
                                rtol=1e-5, atol=1e-5)
@@ -43,7 +54,8 @@ def test_batched_dispatch_matches_pertask_and_reference(model):
 
 def test_batched_dispatch_mixed_queues_o_primitives_calls():
     """A kernel whose plan lands tasks in all three primitives must execute
-    with one pallas launch per primitive, not per task."""
+    its compiled dispatch with one pallas launch per primitive, where the
+    per-task reference takes one per task."""
     rng = np.random.default_rng(1)
     xd = rng.normal(size=(96, 64)).astype(np.float32)
     xd[:32] *= (rng.uniform(size=(32, 64)) < 0.01)
@@ -61,12 +73,16 @@ def test_batched_dispatch_mixed_queues_o_primitives_calls():
     n_tasks = len(plan.stq) + len(plan.dtq)
     assert prims == {"SpDMM", "SpMM", "GEMM"}, prims
 
+    d = eng.dispatch_for(plan, x)
     ops.reset_pallas_call_count()
-    z_b = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd, batched=True)
+    z_b = dispatch_mod.apply_dispatch(d.geom, d.arrays, jnp.asarray(xd),
+                                      jnp.asarray(yd), interpret=True)
     calls_batched = ops.pallas_call_count()
     ops.reset_pallas_call_count()
-    z_p = execute_plan(plan.part, plan.stq, plan.dtq, xd, yd, batched=False)
+    z_p = pertask_reference(plan.part, plan.stq, plan.dtq, xd, yd)
     calls_pertask = ops.pallas_call_count()
+    np.testing.assert_array_equal(
+        np.asarray(eng.execute(plan, x, jnp.asarray(yd))), np.asarray(z_b))
 
     assert calls_batched == len(prims)       # O(primitives)
     assert calls_pertask == n_tasks          # O(tasks)

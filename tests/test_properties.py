@@ -54,15 +54,13 @@ def test_property_sparse_kernels_match_dense(nrb, ncb, nnb, da, dy, seed):
 )
 def test_property_compiled_eager_pertask_bit_identity(M, K, N, tm, tn,
                                                       dx, dy, seed):
-    """Invariant (ISSUE 4): for ANY ragged/non-aligned geometry and operand
-    sparsity, the engine's compiled dispatch is bit-identical to the exact
-    reference of its lowering (SpMM tasks as the SpDMM stripe walk), and the
-    eager batched and per-task paths agree with it within float32 rounding.
-    Misalignable tile sizes (tm=24, tn=12) exercise the decline-and-fall-
-    back route."""
+    """Invariant (ISSUE 4): for ANY ragged geometry and operand sparsity,
+    the engine's compiled dispatch is bit-identical to the exact reference
+    of its lowering (SpMM tasks as the SpDMM stripe walk), and the per-task
+    reference agrees with it within float32 rounding.  A misaligned tile
+    (tn=12 splitting N) is refused with ValueError."""
     from repro.core import DynasparseEngine, SparseCOO
-    from repro.core.scheduler import execute_plan
-    from spmm_reference import stripe_walk_reference
+    from spmm_reference import pertask_reference, stripe_walk_reference
 
     rng = np.random.default_rng(seed)
     xd = (rng.normal(size=(M, K)) *
@@ -75,15 +73,14 @@ def test_property_compiled_eager_pertask_bit_identity(M, K, N, tm, tn,
                   jnp.asarray(xd[r, c]), tag="adjacency")
     eng = DynasparseEngine(tile_m=tm, tile_n=tn, literal=True,
                            interpret=True)
+    if tn % 8 and tn < N:
+        with pytest.raises(ValueError, match="tile_n"):
+            eng.plan(x, jnp.asarray(yd))
+        return
     plan = eng.plan(x, jnp.asarray(yd))
     z_c = np.asarray(eng.execute(plan, x, jnp.asarray(yd)))
-    z_b = np.asarray(execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                                  batched=True, interpret=True))
-    z_p = np.asarray(execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                                  batched=False, interpret=True))
-    np.testing.assert_array_equal(
-        z_c, stripe_walk_reference(plan, xd, yd, interpret=True))
-    np.testing.assert_allclose(z_c, z_b, rtol=1e-4, atol=1e-4)
+    z_p = pertask_reference(plan.part, plan.stq, plan.dtq, xd, yd)
+    np.testing.assert_array_equal(z_c, stripe_walk_reference(plan, xd, yd))
     np.testing.assert_allclose(z_c, z_p, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(z_c, xd @ yd, rtol=2e-4, atol=2e-3)
 
@@ -103,13 +100,13 @@ def test_property_activation_skip_bit_identity(M, K, N, tm, tn, bd, dy, eps,
                                                dtype, capmode, seed):
     """Invariant (ISSUE 5): for ANY ragged geometry, activation block
     pattern, dtype, eps, and capacity within budget, the compiled capacity
-    block-skip route is bit-identical to the eager batched AND per-task
-    paths; a capacity below the need flips the in-program overflow fallback
-    to the plain dense GEMM (bit-identical to that route instead)."""
+    block-skip route is bit-identical to its unfused reference; a
+    capacity below the need flips the in-program overflow fallback to the
+    plain dense GEMM (bit-identical to that route instead)."""
     from repro.core import DynasparseEngine
     from repro.core import dispatch as dispatch_mod
-    from repro.core.scheduler import execute_plan
     from repro.kernels import ops as kops
+    from spmm_reference import activation_reference
 
     if dtype == "bfloat16":
         import ml_dtypes
@@ -131,8 +128,6 @@ def test_property_activation_skip_bit_identity(M, K, N, tm, tn, bd, dy, eps,
         return                                    # dense wins: no route
     need = dispatch_mod.activation_capacity(xd, plan.part, B, eps=eps,
                                             slack=1.0)
-    if need is None:
-        return                                    # misaligned canvas
     cap = {"auto": None, "exact": need, "slack": need + 3,
            "overflow": max(1, need - 1)}[capmode]
     ad = eng.activation_dispatch_for(plan, xd, capacity=cap)
@@ -146,12 +141,8 @@ def test_property_activation_skip_bit_identity(M, K, N, tm, tn, bd, dy, eps,
         np.testing.assert_array_equal(z_a, np.asarray(z_d))
         return
     assert not bool(diag["overflow"])
-    z_b = np.asarray(execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                                  batched=True, interpret=True, eps=eps))
-    z_p = np.asarray(execute_plan(plan.part, plan.stq, plan.dtq, xd, yd,
-                                  batched=False, interpret=True, eps=eps))
-    np.testing.assert_array_equal(z_a, z_b)
-    np.testing.assert_array_equal(z_a, z_p)
+    np.testing.assert_array_equal(
+        z_a, activation_reference(plan, xd, yd, eps=eps))
     if eps == 0.0:
         np.testing.assert_allclose(
             z_a, np.asarray(xd, np.float32) @ yd, rtol=2e-2, atol=2e-2)

@@ -8,7 +8,7 @@ chosen, that each walk is bit for bit its exact unfused reference and the
 two agree within float32 rounding (the per-column sums are the same; on the
 CPU a dot's rounding may follow its width), that the wide walk keeps the
 in-place canvas's other tiles and survives entry lists split across
-launches, and that compiled and eager execution make the same choice.
+launches, and that the engine's dispatch makes the same choice.
 """
 import dataclasses
 import types
@@ -21,10 +21,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import DynasparseEngine, SparseCOO
 from repro.core import dispatch as dispatch_mod
 from repro.core.partition import make_tasks
-from repro.core.scheduler import execute_plan
 from repro.kernels import ops
 from repro.kernels.formats import pack_blockcsr
-from spmm_reference import eps_masked, section_reference, stripe_walk_reference
+from spmm_reference import (eps_masked, pertask_reference, section_reference,
+                            stripe_walk_reference)
 
 # The TPU interpreter starts every VMEM buffer as NaN, as the chip leaves it
 # uninitialized: a wide block the walk failed to cover or resume reads NaN.
@@ -172,7 +172,7 @@ def test_in_place_canvas_keeps_gemm_tiles(route_name):
     np.testing.assert_array_equal(z, stripe_walk_reference(plan, xd, yd))
     np.testing.assert_allclose(z, xd @ yd, rtol=1e-4, atol=1e-4)
     if walks:
-        gemm_rows = np.asarray(execute_plan(part, [], dtq, xd, yd))[:16]
+        gemm_rows = pertask_reference(part, [], dtq, xd, yd)[:16]
         np.testing.assert_array_equal(z[:16], gemm_rows)
 
 
@@ -205,8 +205,8 @@ def _coo_of(xd):
 
 @pytest.mark.parametrize("tn", [8, 16])
 def test_compiled_row_walk_bit_identical_to_eager(tn):
-    """An engine plan with every task on SpDMM: the compiled dispatch and the
-    eager batched path both walk whole rows, and agree bit for bit."""
+    """An engine plan with every task on SpDMM: the engine's dispatch walks
+    whole rows, bit for bit the whole-row walk's unfused reference."""
     xd, yd = _operands(seed=9, M=56, K=48, N=40)
     x = _coo_of(xd)
     eng = DynasparseEngine(tile_m=16, tile_n=tn, literal=True)
@@ -218,6 +218,6 @@ def test_compiled_row_walk_bit_identical_to_eager(tn):
     z_c = np.asarray(eng.execute(plan, x, jnp.asarray(yd)))
     d = eng.dispatch_for(plan, x)
     assert d.geom.sp_rows and d.row_walk_sections == 1
-    z_b = np.asarray(execute_plan(plan.part, plan.stq, [], xd, yd))
-    np.testing.assert_array_equal(z_c, z_b)
+    z_r = section_reference(plan.part, plan.stq, xd, yd, whole_rows=True)
+    np.testing.assert_array_equal(z_c, z_r[:xd.shape[0], :yd.shape[1]])
     np.testing.assert_allclose(z_c, xd @ yd, rtol=1e-4, atol=1e-4)

@@ -142,11 +142,10 @@ _GNN_SHARD_SCRIPT = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import DynasparseEngine
-    from repro.core import scheduler as _scheduler
     from repro.core.primitives import SparseCOO
     from repro.launch.mesh import make_data_mesh
     from repro.serving.cache import SharedPlanCache
-    from spmm_reference import stripe_walk_reference
+    from spmm_reference import pertask_reference, stripe_walk_reference
 
     MESHES = {nd: make_data_mesh(nd) for nd in (1, 4, 8)}
 
@@ -223,20 +222,15 @@ _GNN_SHARD_SCRIPT = textwrap.dedent("""
                 if not (z == z_r).all():
                     out["halo_mismatch"] += 1
             # core property: the sharded compiled executor is bit-identical
-            # to the single-device EAGER executor on the SAME placed plan
-            # (its SpMM tasks as the compiled stripe walk: spmm_reference)
-            key, entry = eng._packed_structure(plan, adj)
-            xd = (eng._ensure_dense(key, entry, adj)
-                  if plan.dtq else None)
-            z_e = stripe_walk_reference(
-                plan, xd, y, eps=eps, block=eng.block,
-                interpret=eng.interpret, packed=entry.stripes)
+            # to the exact unfused reference of the SAME placed plan (its
+            # SpMM tasks as the compiled stripe walk: spmm_reference)
+            xd = np.asarray(adj.todense())
+            z_e = stripe_walk_reference(plan, xd, y, eps=eps,
+                                        block=eng.block)
             if not (z == z_e).all():
                 out["exec_mismatch"] += 1
-            z_s = np.asarray(_scheduler.execute_plan(
-                plan.part, plan.stq, plan.dtq, xd, y, block=eng.block,
-                interpret=eng.interpret, batched=True,
-                packed=entry.stripes, eps=eps))
+            z_s = pertask_reference(plan.part, plan.stq, plan.dtq, xd, y,
+                                    eps=eps, block=eng.block)
             if not np.allclose(z, z_s, rtol=1e-4, atol=1e-4):
                 out["exec_spmm_far"] += 1
             if nd == 1 and not (z == z_ref).all():
@@ -426,10 +420,10 @@ def gnn_shard_results(tmp_path_factory):
 
 
 def test_sharded_executor_bit_identity(gnn_shard_results):
-    """Sharded compiled execute == single-device eager execute of the SAME
+    """Sharded compiled execute == the exact unfused reference of the SAME
     placed plan, bitwise, on meshes of 1/4/8 forced host devices (SpMM
     tasks as the stripe walk the compiled path lowers them to), and the
-    structure-intersecting eager SpMM within float32 rounding."""
+    structure-intersecting per-task reference within float32 rounding."""
     r, _ = gnn_shard_results
     assert r["cases"] >= 8
     assert r["exec_mismatch"] == 0
@@ -439,7 +433,7 @@ def test_sharded_executor_bit_identity(gnn_shard_results):
 def test_halo_spmm_stripe_walk_matches_single_device(gnn_shard_results):
     """The compiled SpMM section, halo-sharded over 4 and 8 devices, is
     bit-identical to the single-device compiled result on stripes wide
-    enough for the stripe walk's rounding to differ from the eager SpMM,
+    enough for the stripe walk's rounding to differ from a pairing SpMM,
     with eps 0 and 0.5, and the halo exchange really moved blocks."""
     r, _ = gnn_shard_results
     assert r["stripe_cases"] == 4

@@ -193,9 +193,9 @@ def test_pack_activation_stripes_compiles(one_chip):
 def test_whole_model_program_compiles(one_chip, monkeypatch):
     """The served path's whole-model program (``CompiledModel.run``) for
     GCN on CO at stacked width 128, compiled for one chip.  The warmup pass
-    that records its kernels runs the plain jnp executor here, so that only
-    the geometry is taken from the CPU; the kernels are traced for the TPU
-    (interpret mode off)."""
+    that records its kernels takes each kernel's route but computes it with
+    the plain jnp executor here, so that only the geometry is taken from
+    the CPU; the kernels are traced for the TPU (interpret mode off)."""
     from repro.core import DynasparseEngine
     from repro.core import primitives as prim
     from repro.core.perfmodel import TPUV5E
@@ -206,9 +206,12 @@ def test_whole_model_program_compiles(one_chip, monkeypatch):
     g = load_graph("CO")
     params = gnn.init_params("GCN", K_FEAT, g.stats.hidden, g.stats.classes)
     engine = DynasparseEngine(TPUV5E, literal=True, calibration="off")
-    monkeypatch.setattr(engine, "execute", lambda plan, x, y: (
-        prim.spdmm_exec(x, y) if isinstance(x, prim.SparseCOO)
-        else prim.gemm_exec(jnp.asarray(x), jnp.asarray(y))))
+    def execute(plan, x, y):
+        engine.last_route = engine.route(plan, x)
+        if isinstance(x, prim.SparseCOO):
+            return prim.spdmm_exec(x, y)
+        return prim.gemm_exec(jnp.asarray(x), jnp.asarray(y))
+    monkeypatch.setattr(engine, "execute", execute)
     h = jnp.concatenate([g.features_dense] * 8, axis=1)
     _, cm = gnn.compile_model("GCN", engine, g.adj, h, params,
                               transport=stacked_transport)
